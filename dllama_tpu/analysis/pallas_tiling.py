@@ -8,8 +8,8 @@ PALLAS-001  a ``pl.BlockSpec`` whose block-shape tuple has an int LITERAL
 Why literals only: symbolic dims (``bk``, ``hd // 2``) come from the tile
 planner, whose outputs the CPU lowering gate (ops.lowering) sweeps against
 every real model shape — a misalignment there fails tests, not this lint.
-A misaligned *literal*, by contrast, is exactly how the BENCH_r02 failure
-shipped: it looks innocent at the call site, lowers nowhere, and no test
+A misaligned *literal*, by contrast, is exactly how the round-2 bench
+failure shipped: it looks innocent at the call site, lowers nowhere, and no test
 exercises it until a TPU does. Mosaic does accept such a block when it
 spans the whole array dim ("equal-to-dim" escape), but whether it does is
 a runtime fact this pass cannot see — so a deliberate whole-array literal

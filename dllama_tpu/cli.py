@@ -547,8 +547,8 @@ def build_parser() -> argparse.ArgumentParser:
                 default=None,
                 metavar="N",
                 help="fused-decode chunk size (default 64): one device "
-                "dispatch per N tokens. Bigger amortizes host round trips "
-                "(tunneled/remote PJRT); smaller tightens streaming burst "
+                "dispatch per N tokens. Bigger amortizes host round trips; "
+                "smaller tightens streaming burst "
                 "granularity — batched SSE rows emit one burst per chunk",
             )
             sp.add_argument(
@@ -612,35 +612,23 @@ def maybe_init_distributed(args) -> int:
 
 
 def load_engine(args):
-    # flash decode + float8 cache is the one flash configuration not yet
-    # hardware-proven: probe the kernel in a SUBPROCESS before this process
-    # touches the backend (TPU runtimes are per-process exclusive), so a
-    # Mosaic rejection downgrades to dense attention up front instead of
-    # crashing the server/chat on its first decode dispatch.
-    if (args.cache_dtype == "f8"
-            and os.environ.get("DLLAMA_FLASH_DECODE", "0") == "1"):
-        from dllama_tpu.ops import flash_decode as _fd
-
-        ok, detail = _fd.probe_kernel(cache="f8")
-        if not ok:
-            print(f"⚠️  flash-decode f8 probe failed ({detail[:200]}); "
-                  "falling back to dense attention (DLLAMA_FLASH_DECODE "
-                  "unset)", file=sys.stderr, flush=True)
-            os.environ.pop("DLLAMA_FLASH_DECODE", None)
-
     import jax
     import jax.numpy as jnp
 
     from dllama_tpu.formats.weights import WeightFileReader
     from dllama_tpu.models import llama
     from dllama_tpu.models.config import ModelConfig
+    from dllama_tpu.runtime import device
     from dllama_tpu.runtime.generate import Engine
     from dllama_tpu.runtime.sampler import SamplerConfig
     from dllama_tpu.tokenizer.bpe import Tokenizer
 
     from dllama_tpu.quants import blocks
 
-    n_tp = args.tp if args.tp > 0 else len(jax.devices())
+    dev = device.device_info()
+    print(f"💡 device: platform={dev['platform']} kind={dev['kind']} "
+          f"count={dev['count']}")
+    n_tp = args.tp if args.tp > 0 else dev["count"]
     t0 = time.time()
     with WeightFileReader(args.model) as reader:
         cfg = ModelConfig.from_spec(reader.spec, dtype=args.dtype)
@@ -1375,14 +1363,6 @@ def run_snapshot(args) -> int:
 
 
 def main(argv=None) -> None:
-    # DLLAMA_PLATFORM=cpu|tpu forces the JAX backend via jax.config — unlike
-    # the JAX_PLATFORMS env var this works even when a sitecustomize has
-    # already imported jax and pinned a different platform
-    platform = os.environ.get("DLLAMA_PLATFORM")
-    if platform:
-        import jax
-
-        jax.config.update("jax_platforms", platform)
     args = build_parser().parse_args(argv)
     if args.mode == "verify":
         # pure host-side file check: no device, no distributed init
@@ -1409,6 +1389,9 @@ def main(argv=None) -> None:
     if args.mode == "snapshot":
         # read-only observer + tarfile: no device, no jax
         raise SystemExit(run_snapshot(args))
+    from dllama_tpu.runtime.device import configure_compile_cache
+
+    print(f"💾 compile cache: {configure_compile_cache()}")
     maybe_init_distributed(args)
     if args.mode == "chat":
         run_chat(args)

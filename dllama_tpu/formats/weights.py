@@ -555,14 +555,26 @@ class ModelWriter:
         self._f = open(path, "wb")
         self._f.write(header)
 
-    def write_next(self, name: str, x: np.ndarray) -> None:
+    def _expect(self, name: str):
         e = self.plan[self._i]
         if name != e.name:
             raise ValueError(f"tensor order violation: expected {e.name!r}, got {name!r}")
+        return e
+
+    def write_next(self, name: str, x: np.ndarray) -> None:
+        e = self._expect(name)
         x = np.asarray(x, dtype=np.float32)
         if x.size != e.d * e.n:
             raise ValueError(f"{e.name}: expected {e.d}x{e.n} values, got shape {x.shape}")
-        raw = blocks.encode_tensor(x.reshape(-1), e.float_type)
+        self.write_next_raw(name, blocks.encode_tensor(x.reshape(-1), e.float_type))
+
+    def write_next_raw(self, name: str, raw: bytes) -> None:
+        """Append a tensor ALREADY encoded in its planned float type
+        (``blocks.encode_tensor``): a caller that writes one tensor into
+        many layers pays the encode once."""
+        e = self._expect(name)
+        if len(raw) != e.nbytes:
+            raise ValueError(f"{e.name}: expected {e.nbytes} encoded bytes, got {len(raw)}")
         self._f.write(raw)
         if self._checksums:
             self._crcs.append(zlib.crc32(raw))

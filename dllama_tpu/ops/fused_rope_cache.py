@@ -34,7 +34,6 @@ import sys
 import jax
 import jax.numpy as jnp
 
-from dllama_tpu import compat
 from dllama_tpu.ops.rope import HALF, INTERLEAVED
 
 
@@ -171,7 +170,7 @@ def _launch(kr, vr, cos, sin, k5, v5, starts, layer, style, interpret):
         # operand 5, v_cache 6, aliased onto outputs 0/1 — the cache is
         # updated in place, untouched rows carried through
         input_output_aliases={5: 0, 6: 1},
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(idx, kr, vr, cos, sin, k5, v5)

@@ -39,8 +39,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dllama_tpu import compat
-
 from dllama_tpu.quants import blocks
 
 QK = blocks.QK  # 32 values per quantization block
@@ -48,11 +46,12 @@ QK = blocks.QK  # 32 values per quantization block
 #: q40 "no-subtract" dequant: the kernel drops the ``- 8`` nibble recentering
 #: (the VPU op the dequant is bound on) and the caller subtracts the exact
 #: correction ``8 * sum_blocks blocksum(x) * delta`` via two small MXU dots
-#: against the scale planes. Measured on v5e (scripts/qkernel_experiments.py,
-#: K=4096 O=11008): 537 GB/s effective vs 380 GB/s for the subtracting
-#: kernel, at ~2x the (still block-quantization-sized) rounding error —
-#: 7.6e-3 vs 3.7e-3 max-rel, both well inside the 2e-2 the q40 format itself
-#: implies. Opt out with DLLAMA_Q40_NOSUB=0 for the bit-conservative kernel.
+#: against the scale planes. Its speed on the chip is not measured (a lead
+#: from a deleted builder log had it ahead of the subtracting kernel on one
+#: shape; ROADMAP S3 settles it). It costs ~2x the (still
+#: block-quantization-sized) rounding error — 7.6e-3 vs 3.7e-3 max-rel, both
+#: well inside the 2e-2 the q40 format itself implies. Opt out with
+#: DLLAMA_Q40_NOSUB=0 for the bit-conservative kernel.
 Q40_NOSUB = os.environ.get("DLLAMA_Q40_NOSUB", "1") != "0"
 
 
@@ -139,10 +138,10 @@ def tile_plan(kind: str, k_padded: int, out_features: int) -> tuple[int, int]:
 
     The O grid is ragged — ``ceil(O / bo)`` blocks with Mosaic masking the
     boundary block's stores — so bo never shrinks to fit an awkward O. This
-    matters enormously for decode throughput: Llama-2-7B's hidden dim 11008
-    only divides by 256, and a (43, 4)-step grid of tiny tiles ran the kernel
-    at ~280 GB/s effective; full 1024-lane tiles reach ~500+ GB/s on the same
-    shape (measured on v5e, scripts/kernel_bench.py). Raggedness is safe on
+    matters for decode throughput: Llama-2-7B's hidden dim 11008 only
+    divides by 256, which would mean a (43, 4)-step grid of tiny tiles where
+    full 1024-lane tiles fit (how much it matters on the chip: not measured,
+    ROADMAP S3). Raggedness is safe on
     the O axis only: each output column depends on exactly its own weight
     column, so boundary-block garbage lands in masked-out columns. The K axis
     by contrast is contracted, so bk MUST divide k_padded exactly (pack_q40 /
@@ -281,7 +280,7 @@ def q80_matmul(x: jnp.ndarray, w: jnp.ndarray, scales: jnp.ndarray,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bt, bo), lambda t_, o, k: (t_, o)),
         out_shape=jax.ShapeDtypeStruct((T, O), jnp.float32),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -346,7 +345,7 @@ def q80_matmul_stacked(x: jnp.ndarray, w: jnp.ndarray, scales: jnp.ndarray,
                           fuse_norm=fused),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, O), jnp.float32),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -449,7 +448,7 @@ def _q40_correction(xp, s_lo, s_hi, layer=None, interpret=False):
     O = s_lo.shape[-1]
     bo = O if O < 128 else min(1024, _pad_up(O, 128))
     bt = min(T, T_BLOCK)
-    params = compat.tpu_compiler_params(
+    params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel"))
     if layer is None:
         return pl.pallas_call(
@@ -558,7 +557,7 @@ def q40_matmul(x: jnp.ndarray, packed: jnp.ndarray, s_lo: jnp.ndarray,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bt, bo), lambda t_, o, k: (t_, o)),
         out_shape=jax.ShapeDtypeStruct((T, O), jnp.float32),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -627,7 +626,7 @@ def q40_matmul_stacked(x: jnp.ndarray, packed: jnp.ndarray, s_lo: jnp.ndarray,
                           nosub=nosub, fuse_norm=fused),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, O), jnp.float32),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -32,8 +32,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-from dllama_tpu import compat
 from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
@@ -61,7 +59,7 @@ def ring_attention_kernel(
     Chunks are laid out in ring order: device i holds sequence positions
     ``[i*Tc, (i+1)*Tc)``.
     """
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     B, Tc, Hkv, G, D = q.shape
 
@@ -153,7 +151,7 @@ def ring_self_attention(
         )
         return out.reshape(*qc.shape[:2], Hq, D).astype(q.dtype)
 
-    mapped = compat.shard_map(
+    mapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -23,8 +23,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from dllama_tpu import compat
-
 
 class RingAxis(str):
     """Marker for a tp axis whose gathers take the ppermute ring schedule.
@@ -53,7 +51,7 @@ def _all_gather_last(x: jnp.ndarray, tp_axis) -> jnp.ndarray:
     if not isinstance(tp_axis, RingAxis):
         return jax.lax.all_gather(x, tp_axis, axis=-1, tiled=True)
     axis = str(tp_axis)
-    tp = compat.axis_size(axis)  # static under shard_map
+    tp = jax.lax.axis_size(axis)  # static under shard_map
     if tp == 1:
         return x
     idx = jax.lax.axis_index(axis)
@@ -145,7 +143,7 @@ def scatter_features(x: jnp.ndarray, tp_axis) -> jnp.ndarray:
     if tp_axis is None:
         return x
     axis = str(tp_axis)
-    tp = compat.axis_size(axis)
+    tp = jax.lax.axis_size(axis)
     if tp == 1:
         return x
     f = x.shape[-1]
@@ -195,7 +193,7 @@ def reduce_scatter_columns(partial: jnp.ndarray, tp_axis,
     if tp_axis is None:
         return partial
     axis = str(tp_axis)
-    tp = compat.axis_size(axis)
+    tp = jax.lax.axis_size(axis)
     x = partial.astype(jnp.float32)
     if tp == 1:
         return x
@@ -238,7 +236,7 @@ def reduce_columns(partial: jnp.ndarray, tp_axis,
     carry the next layer's already-normalized input instead."""
     if tp_axis is None:
         return partial
-    if compat.axis_size(str(tp_axis)) == 1:
+    if jax.lax.axis_size(str(tp_axis)) == 1:
         return partial.astype(jnp.float32)
     return _all_gather_last(
         reduce_scatter_columns(partial, tp_axis, compress), tp_axis)

@@ -27,8 +27,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-from dllama_tpu import compat
 from jax.sharding import PartitionSpec as P
 
 from dllama_tpu.models import llama
@@ -101,7 +99,7 @@ def pipeline_forward_train(
         mask = (idx == S - 1).astype(finished.dtype)
         return jax.lax.psum(finished * mask, pp_axis)
 
-    mapped = compat.shard_map(
+    mapped = jax.shard_map(
         pipelined,
         mesh=mesh,
         in_specs=(P(pp_axis), P(), P(), P()),

@@ -48,14 +48,13 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from dllama_tpu.models.config import ModelConfig
 from dllama_tpu.ops.qmatmul import K_MULTIPLE, QuantTensor, _pad_up
 from dllama_tpu.parallel.mesh import TP
 from dllama_tpu.parallel.sharding import cache_spec, check_tp_compatible
-
-from dllama_tpu.compat import shard_map
 
 
 def has_quant_leaves(params) -> bool:

@@ -300,8 +300,8 @@ class Engine:
             "fuse_rope_cache": "on" if _frc.fuse_enabled() else "off",
         }
         # fused-loop chunk: one host round trip per chunk of tokens. Bigger
-        # chunks amortize dispatch/sync latency (dominant on tunneled or
-        # remote-PJRT setups) at the cost of coarser streaming granularity.
+        # chunks amortize dispatch/sync latency at the cost of coarser
+        # streaming granularity.
         self.decode_chunk = decode_chunk
         fwd = llama.forward
         fwd_b = llama.forward_batched

@@ -44,6 +44,7 @@ from dllama_tpu.analysis.sanitize import guarded_by
 from dllama_tpu.observability import RequestTrace
 from dllama_tpu.obsv import BurnRateEngine, Sampler, TimeSeriesStore
 from dllama_tpu.obsv.timeseries import parse_window
+from dllama_tpu.runtime import device
 from dllama_tpu.runtime.generate import NumericHealthError
 from dllama_tpu.runtime.sampler import SamplerConfig
 from dllama_tpu.serving import kv_transfer
@@ -1637,6 +1638,11 @@ class ServerState:
             "replica_id": self.replica_id,
             "started_at": round(self.started_at, 3),
             "uptime_s": round(time.time() - self.started_at, 1),
+            # what this process runs on and what it had to compile: the one
+            # place a caller that must stay off JAX (a smoke, a supervisor)
+            # learns the device from
+            "device": device.device_info(),
+            "compile_cache": device.compile_cache_counts(),
             "load": info,
             "metrics": snap,
         }

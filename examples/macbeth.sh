@@ -10,7 +10,7 @@
 # kernels/collectives fails the diff.
 #
 # Usage: examples/macbeth.sh [model.m tokenizer.t]
-# Set DLLAMA_PLATFORM=cpu to force the CPU backend (e.g. no TPU attached).
+# Set JAX_PLATFORMS=cpu to force the CPU backend (e.g. no TPU attached).
 #
 # Published-checkpoint mode (network required — this build environment is
 # zero-egress, so it only works where HuggingFace is reachable):

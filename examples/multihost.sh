@@ -45,7 +45,7 @@ if [ ! -f "$MODEL" ]; then
 fi
 
 run_host() {
-  JAX_PLATFORMS=cpu DLLAMA_PLATFORM=cpu python -m dllama_tpu.cli "$2" \
+  JAX_PLATFORMS=cpu python -m dllama_tpu.cli "$2" \
     --model "$MODEL" --tokenizer "$TOKENIZER" \
     --prompt "Tomorrow, and tomorrow" --steps 8 --temperature 0 --seed 1 \
     --coordinator "127.0.0.1:$PORT" --num-hosts 2 --host-id "$1" \

@@ -4,8 +4,9 @@
 # Where the reference boots N worker processes in screen sessions and wires
 # them over TCP (n-workers.sh:1-55), a TPU run is one process whose mesh
 # spans the chips: this sweep re-runs the same generate over tp=1,2,4,8 and
-# prints the per-token time for each. On a machine without a TPU slice it
-# uses 8 virtual CPU devices — same code path, same collectives.
+# prints the per-token time for each. With JAX_PLATFORMS=cpu (a machine
+# without a TPU slice) it uses 8 virtual CPU devices — same code path, same
+# collectives.
 #
 # Usage: examples/n-chips.sh <model.m> <tokenizer.t> [prompt] [steps]
 set -e
@@ -16,10 +17,9 @@ TOKENIZER=${2:?usage: n-chips.sh model.m tokenizer.t [prompt] [steps]}
 PROMPT=${3:-"Hello world"}
 STEPS=${4:-32}
 
-if [ -n "$DLLAMA_PLATFORM" ] || ! timeout 60 python -c 'import jax; assert jax.default_backend() == "tpu"' 2>/dev/null; then
-  export DLLAMA_PLATFORM=${DLLAMA_PLATFORM:-cpu}
+if [ "$JAX_PLATFORMS" = "cpu" ]; then
   export XLA_FLAGS="--xla_force_host_platform_device_count=8 ${XLA_FLAGS}"
-  echo "(no TPU detected: using 8 virtual CPU devices)"
+  echo "(JAX_PLATFORMS=cpu: using 8 virtual CPU devices)"
 fi
 
 for TP in 1 2 4 8; do

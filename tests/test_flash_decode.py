@@ -108,8 +108,8 @@ def test_supports_gate(monkeypatch, capsys):
 def test_f8_cache_matches_oracle():
     """f8_e4m3 cache blocks upcast in the kernel must match the dense oracle
     reading the same f8 slabs — the long-context composition (f8 halves
-    cache bytes, flash skips dead blocks) VERDICT r04 flagged as mutually
-    exclusive."""
+    cache bytes, flash skips dead blocks) that an earlier review flagged as
+    mutually exclusive."""
     S, n_heads, n_kv, hd = 512, 8, 4, 128
     rng = np.random.default_rng(11)
     q = jnp.asarray(rng.standard_normal((1, n_heads, hd)), jnp.bfloat16)
@@ -127,7 +127,7 @@ def test_f8_cache_matches_oracle():
 def test_dense_engine_engages_flash(monkeypatch):
     """A DENSE (bf16/f32-weight) engine must also take the flash path now:
     forward() routes dense weights through the index-scan when the gate
-    engages (VERDICT r04: dense-weight engines never used flash)."""
+    engages (an earlier review: dense-weight engines never used flash)."""
     from dllama_tpu.models import llama
     from dllama_tpu.models.config import ModelConfig
     from dllama_tpu.ops import flash_decode as fd

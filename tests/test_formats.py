@@ -5,7 +5,8 @@ import pytest
 
 from dllama_tpu.formats import tokenizer_file
 from dllama_tpu.formats.spec import ArchType, HiddenAct, ModelSpec, parse_header, write_header
-from dllama_tpu.formats.weights import WeightFileReader, tensor_plan, write_model
+from dllama_tpu.formats.weights import (ModelWriter, WeightFileReader, tensor_plan,
+                                        write_model)
 from dllama_tpu.quants import blocks
 
 
@@ -67,6 +68,32 @@ def test_model_file_roundtrip(tmp_path, wft):
                 np.testing.assert_array_equal(got, want)
             else:
                 assert np.max(np.abs(got - want)) <= tol, e.name
+
+
+def test_writer_takes_pre_encoded_tensors(tmp_path):
+    """write_next_raw appends bytes encoded once by the caller: the file is
+    byte-identical to the one write_next makes (checksums included), and a
+    wrong size or a tensor out of order is refused."""
+    spec = tiny_spec(wft=blocks.Q40)
+    tensors = random_tensors(spec)
+    a, b = str(tmp_path / "a.m"), str(tmp_path / "b.m")
+    write_model(a, spec, tensors)
+    with ModelWriter(b, spec) as w:
+        for e in w.plan:
+            w.write_next_raw(e.name, blocks.encode_tensor(tensors[e.name], e.float_type))
+    with open(a, "rb") as fa, open(b, "rb") as fb:
+        assert fa.read() == fb.read()
+    with WeightFileReader(b) as r:
+        assert r.verify()["ok"]
+
+    w = ModelWriter(str(tmp_path / "c.m"), spec)
+    first = w.plan[0]
+    raw = blocks.encode_tensor(tensors[first.name], first.float_type)
+    with pytest.raises(ValueError, match="encoded bytes"):
+        w.write_next_raw(first.name, raw[:-1])
+    with pytest.raises(ValueError, match="order violation"):
+        w.write_next_raw(w.plan[1].name, raw)
+    w._f.close()
 
 
 def test_moe_grok_plan(tmp_path):

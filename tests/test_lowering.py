@@ -1,12 +1,15 @@
 """Static TPU tiling verifier (ops.lowering) — the CPU gate for Mosaic.
 
-The round-2 bench (BENCH_r02) was the only run to reach a real TPU backend,
-and it failed inside our own kernel: the q40 scale-plane BlockSpec produced
+An early bench round (round 2; its record is deleted) reached a real TPU
+backend and failed inside our own kernel: the q40 scale-plane BlockSpec produced
 a (4, 1024) block against the (172, 4096) array — the last two block dims
 must each be divisible by the (8, 128) min tile or equal to the array dim.
 These tests prove, without a TPU, that every pallas_call in the inventory
 satisfies that contract for every real model shape, and that the verifier
 still *recognizes* the historical failure when fed the legacy plan.
+
+The verifier is static: it passed the flash-decode and rope+cache kernels
+that the v5e compiler refuses. tests/test_chip_compile.py asks the compiler.
 """
 
 import jax.numpy as jnp
@@ -17,7 +20,7 @@ from dllama_tpu.ops.lowering import MODEL_DIMS, SWEEP_T, TilingError
 
 
 # ---------------------------------------------------------------------------
-# The pinned BENCH_r02 regression case
+# The pinned round-2 regression case
 # ---------------------------------------------------------------------------
 
 def test_pinned_bench_r02_shape_passes_for_every_kernel():

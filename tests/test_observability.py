@@ -337,6 +337,12 @@ def test_stats_endpoint_reports_percentiles(engine_bits):
         assert stats["model"] == "tiny-test"
         assert stats["uptime_s"] >= 0.0
         assert "queue_depth" in stats["load"]
+        # the device this process runs on, as JAX reports it (forced CPU
+        # with eight virtual devices here: tests/conftest.py)
+        dev = stats["device"]
+        assert (dev["platform"], dev["count"]) == ("cpu", 8)
+        assert dev["kind"] and len(dev["bytes_in_use"]) == 8
+        assert set(stats["compile_cache"]) == {"dir", "requests", "hits"}
         ttft = stats["metrics"]["dllama_ttft_ms"]
         assert ttft["kind"] == "histogram"
         solo = [v for v in ttft["values"]

@@ -202,7 +202,7 @@ def test_spec_matches_plain_greedy_quantized_moe():
 def test_spec_verify_routes_to_selected_experts(monkeypatch):
     """A spec verify batch (T = draft+1 = 5, T*k = 10 < E = 16) must ROUTE
     to the selected-experts decode path rather than the all-experts dense
-    combine (VERDICT r03 #6: the old T==1 gate streamed every expert's
+    combine (an earlier review: the old T==1 gate streamed every expert's
     planes on exactly the verify steps). What this proves: the gate admits
     the verify shape; _moe_decode_selected's own cap=min(E, T*k) slicing is
     covered by tests/test_moe.py and test_tp_moe_quant.py."""

@@ -414,7 +414,7 @@ def test_trajectory_rows_and_regression_comparator(tmp_path):
         "smoke_decode_ms_per_token", "tpu_unreachable",
         result={"metric": "smoke_decode_ms_per_token"},
         gates={"backend": False},
-        error="backend unreachable: tunnel down", path=path)
+        error="backend unreachable: no TPU found", path=path)
     assert unreachable["regressions"] == []
     assert unreachable["row"]["status"] == "tpu_unreachable"
     assert unreachable["row"]["git_sha"]

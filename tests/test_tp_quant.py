@@ -297,6 +297,7 @@ def _decode_step_hlo(eng):
     return eng._decode_step.func.lower(
         eng.params, eng.rope, cache, jnp.asarray(3, jnp.int32), jnp.int32(0),
         jax.random.PRNGKey(0), jnp.float32(0.0), jnp.float32(0.9),
+        jnp.bool_(False),  # poison: the fault seam's flag, off
     ).compile().as_text()
 
 

@@ -34,9 +34,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from dllama_tpu import faults, observability
-from dllama_tpu.compat import shard_map
 from dllama_tpu.models import llama
 from dllama_tpu.models.config import ModelConfig
 from dllama_tpu.parallel import collectives, quant_tp
