@@ -1,0 +1,18 @@
+"""The whole step's share (%) of the chip's bf16 peak over the traced window:
+FLOPs the model needs for the tokens of the launches the trace counts
+(``shapes.flops_per_token``) / (the trace's seconds x chips x peak)."""
+from common import shapes, traced_work
+
+
+def read(ctx, args):
+    w = traced_work(ctx, args)
+    if w is None or ctx.get("peaks") is None:
+        return None
+    out_tokens = w["decode_steps"] * w["rows"]
+    prompt_tokens = w["prefill_pieces"] * w["mean_piece_tokens"]
+    # a decoded token attends over its row's context, a prompt token over
+    # the part of its prompt before it: half the prompt on average
+    flops = (out_tokens * shapes.flops_per_token(ctx["model"], w["mean_context"])
+             + prompt_tokens * shapes.flops_per_token(ctx["model"], 0.5 * w["mean_prompt"]))
+    return 100.0 * flops / (w["seconds"] * ctx["chips"]
+                            * ctx["peaks"]["bf16_flops_per_s"])
