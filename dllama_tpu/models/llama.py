@@ -565,7 +565,7 @@ def rope_tables(cfg: ModelConfig) -> dict:
 # Forward pass
 # ---------------------------------------------------------------------------
 
-def _norm_proj(x, norm_w, w, layer, eps):
+def _norm_proj(x, norm_w, w, layer, eps, name=None):
     """``rmsnorm(x, norm_w) @ w``. With DLLAMA_FUSE_NORM and a quantized
     ``w``, the norm rides inside the matmul kernel as an x-block epilogue
     (qmatmul.qmatmul_norm — bit-identical, one fewer activation HBM
@@ -573,8 +573,8 @@ def _norm_proj(x, norm_w, w, layer, eps):
     projections call this per projection: fused, the epilogue recomputes
     in-register (the point); unfused, XLA CSEs the repeated rmsnorm."""
     if norm_fusion_engages(w):
-        return qmatmul_norm(x, norm_w, w, layer, eps)
-    return matmul_any(rmsnorm(x, norm_w, eps), w, layer)
+        return qmatmul_norm(x, norm_w, w, layer, eps, name)
+    return matmul_any(rmsnorm(x, norm_w, eps), w, layer, name)
 
 
 def _check_tp_reduce(cfg: ModelConfig, tp_reduce) -> bool:
@@ -613,6 +613,7 @@ def _row_norm_gather(x_s: jnp.ndarray, norm_w, tp_axis, tp_compress: bool,
             ).astype(x_s.dtype)
 
 
+@jax.named_scope("ffn")
 def _dense_ffn_row(cfg: ModelConfig, lp: dict, xn: jnp.ndarray,
                    layer=None) -> jnp.ndarray:
     """Row-parallel FFN half on the ALREADY-NORMALIZED full-width input:
@@ -624,11 +625,12 @@ def _dense_ffn_row(cfg: ModelConfig, lp: dict, xn: jnp.ndarray,
     hidden shard width, so the quant kernel pads the activation to the
     per-shard K itself."""
     act = ACTIVATIONS[cfg.hidden_act]
-    h = (act(matmul_any(xn, lp["w1"], layer))
-         * matmul_any(xn, lp["w3"], layer))
-    return matmul_any(h, lp["w2"], layer).astype(jnp.float32)
+    h = (act(matmul_any(xn, lp["w1"], layer, name="w1"))
+         * matmul_any(xn, lp["w3"], layer, name="w3"))
+    return matmul_any(h, lp["w2"], layer, name="w2").astype(jnp.float32)
 
 
+@jax.named_scope("ffn")
 def _dense_ffn(cfg: ModelConfig, lp: dict, x: jnp.ndarray, norm_w, tp_axis=None,
                tp_compress: bool = False, layer=None) -> jnp.ndarray:
     """FFN half on the RAW (pre-norm) residual ``x``: the ``rms_ffn`` norm is
@@ -636,14 +638,15 @@ def _dense_ffn(cfg: ModelConfig, lp: dict, x: jnp.ndarray, norm_w, tp_axis=None,
     act = ACTIVATIONS[cfg.hidden_act]
     eps = cfg.norm_eps
     if "w13" in lp:  # fused single-kernel up|gate projection (fuse_qkv_ffn)
-        u = _norm_proj(x, norm_w, lp["w13"], layer, eps)
+        u = _norm_proj(x, norm_w, lp["w13"], layer, eps, name="w13")
         half = u.shape[-1] // 2
         h = act(u[..., :half]) * u[..., half:]
-        return matmul_any(h, lp["w2"], layer)
-    h = (act(_norm_proj(x, norm_w, lp["w1"], layer, eps))
-         * _norm_proj(x, norm_w, lp["w3"], layer, eps))
+        return matmul_any(h, lp["w2"], layer, name="w2")
+    h = (act(_norm_proj(x, norm_w, lp["w1"], layer, eps, name="w1"))
+         * _norm_proj(x, norm_w, lp["w3"], layer, eps, name="w3"))
     h = slice_to_in_features(_gather(h, tp_axis, tp_compress), lp["w2"])
-    return _gather(matmul_any(h, lp["w2"], layer), tp_axis, tp_compress)
+    return _gather(matmul_any(h, lp["w2"], layer, name="w2"), tp_axis,
+                   tp_compress)
 
 
 def _ffn_residual(cfg: ModelConfig, lp: dict, x: jnp.ndarray, att_out: jnp.ndarray,
@@ -674,6 +677,7 @@ def _ffn_residual(cfg: ModelConfig, lp: dict, x: jnp.ndarray, att_out: jnp.ndarr
                           layer)
 
 
+@jax.named_scope("attention")
 def _attn_block(cfg: ModelConfig, lp: dict, rope: dict, x, k_cache, v_cache, pos,
                 tp_axis=None, tp_compress: bool = False, layer=None,
                 row_mode: bool = False):
@@ -701,19 +705,19 @@ def _attn_block(cfg: ModelConfig, lp: dict, rope: dict, x, k_cache, v_cache, pos
     eps = cfg.norm_eps
 
     if row_mode:  # pre-normalized input; rms_att was applied by the caller
-        q = matmul_any(x, lp["wq"], layer)
-        k = matmul_any(x, lp["wk"], layer)
-        v = matmul_any(x, lp["wv"], layer)
+        q = matmul_any(x, lp["wq"], layer, name="wq")
+        k = matmul_any(x, lp["wk"], layer, name="wk")
+        v = matmul_any(x, lp["wv"], layer, name="wv")
     elif "wqkv" in lp:  # fused single-kernel projection (fuse_qkv_ffn; no TP)
-        qkv = _norm_proj(x, lp["rms_att"], lp["wqkv"], layer, eps)
+        qkv = _norm_proj(x, lp["rms_att"], lp["wqkv"], layer, eps, name="wqkv")
         d, kv = cfg.dim, cfg.kv_dim
         q = qkv[:, :d]
         k = qkv[:, d : d + kv]
         v = qkv[:, d + kv :]
     else:
-        q = _norm_proj(x, lp["rms_att"], lp["wq"], layer, eps)
-        k = _norm_proj(x, lp["rms_att"], lp["wk"], layer, eps)
-        v = _norm_proj(x, lp["rms_att"], lp["wv"], layer, eps)
+        q = _norm_proj(x, lp["rms_att"], lp["wq"], layer, eps, name="wq")
+        k = _norm_proj(x, lp["rms_att"], lp["wk"], layer, eps, name="wk")
+        v = _norm_proj(x, lp["rms_att"], lp["wv"], layer, eps, name="wv")
     q = q.reshape(T, -1, cfg.head_size)
     k = k.reshape(T, -1, cfg.head_size)
     v = v.reshape(T, -1, cfg.head_size)
@@ -739,12 +743,13 @@ def _attn_block(cfg: ModelConfig, lp: dict, rope: dict, x, k_cache, v_cache, pos
         else:
             k = apply_rope(k, cos, sin, cfg.rope_style)
             zero = jnp.int32(0)
-            k_cache = jax.lax.dynamic_update_slice(
-                k_cache, k.astype(k_cache.dtype)[None],
-                (layer, pos, zero, zero))
-            v_cache = jax.lax.dynamic_update_slice(
-                v_cache, v.astype(v_cache.dtype)[None],
-                (layer, pos, zero, zero))
+            with jax.named_scope("kv_slab_write"):
+                k_cache = jax.lax.dynamic_update_slice(
+                    k_cache, k.astype(k_cache.dtype)[None],
+                    (layer, pos, zero, zero))
+                v_cache = jax.lax.dynamic_update_slice(
+                    v_cache, v.astype(v_cache.dtype)[None],
+                    (layer, pos, zero, zero))
         # DLLAMA_FLASH_DECODE=1: online-softmax kernel reading ONLY the live
         # cache prefix, straight from the stacked [L, S, kv, hd] cache — no
         # per-layer slab materialization, bytes scale with pos not seq_len
@@ -754,16 +759,20 @@ def _attn_block(cfg: ModelConfig, lp: dict, rope: dict, x, k_cache, v_cache, pos
         if flash_decode.engages(T, k_cache.shape[1], k_cache.dtype):
             out = flash_decode.flash_decode_attention(q, k_cache, v_cache, pos, layer)
         else:
-            k_slab = jax.lax.dynamic_index_in_dim(k_cache, layer, 0, keepdims=False)
-            v_slab = jax.lax.dynamic_index_in_dim(v_cache, layer, 0, keepdims=False)
+            with jax.named_scope("kv_slab_read"):
+                k_slab = jax.lax.dynamic_index_in_dim(
+                    k_cache, layer, 0, keepdims=False)
+                v_slab = jax.lax.dynamic_index_in_dim(
+                    v_cache, layer, 0, keepdims=False)
             out = gqa_attention(q, k_slab, v_slab, pos)
     if row_mode:
         # local heads feed the K-sharded wo directly: no head gather, no
         # output gather — the [T, dim] f32 partial rides the ring reduce
-        return (matmul_any(out.reshape(T, -1), lp["wo"], layer)
+        return (matmul_any(out.reshape(T, -1), lp["wo"], layer, name="wo")
                 .astype(jnp.float32), k_cache, v_cache)
     out = _gather(out.reshape(T, -1), tp_axis, tp_compress)  # local heads -> full
-    return _gather(matmul_any(out, lp["wo"], layer), tp_axis, tp_compress), k_cache, v_cache
+    return (_gather(matmul_any(out, lp["wo"], layer, name="wo"), tp_axis,
+                    tp_compress), k_cache, v_cache)
 
 
 def forward(
@@ -891,7 +900,7 @@ def forward(
                              cfg.norm_eps, cfg.dim)
     else:
         x = rmsnorm(x, params["rms_final"], cfg.norm_eps)
-    logits = matmul_any(x, params["wcls"]).astype(jnp.float32)
+    logits = matmul_any(x, params["wcls"], name="wcls").astype(jnp.float32)
     if tp_axis is not None and gather_logits:
         # slice off any lane-alignment vocab padding (zero logits there would
         # beat real negative logits in an argmax) — no-op when unpadded
@@ -914,6 +923,7 @@ def init_batch_cache(cfg: ModelConfig, batch: int, cache_dtype=jnp.float32,
     return {"k": jnp.zeros(shape, cache_dtype), "v": jnp.zeros(shape, cache_dtype)}
 
 
+@jax.named_scope("attention")
 def _attn_block_batched(cfg: ModelConfig, lp: dict, rope: dict, x, k_cache,
                         v_cache, pos, layer=None, tp_axis=None,
                         tp_compress: bool = False, row_mode: bool = False):
@@ -930,17 +940,17 @@ def _attn_block_batched(cfg: ModelConfig, lp: dict, rope: dict, x, k_cache,
     B = x.shape[0]
     eps = cfg.norm_eps
     if row_mode:  # pre-normalized input; rms_att was applied by the caller
-        q = matmul_any(x, lp["wq"], layer)
-        k = matmul_any(x, lp["wk"], layer)
-        v = matmul_any(x, lp["wv"], layer)
+        q = matmul_any(x, lp["wq"], layer, name="wq")
+        k = matmul_any(x, lp["wk"], layer, name="wk")
+        v = matmul_any(x, lp["wv"], layer, name="wv")
     elif "wqkv" in lp:
-        qkv = _norm_proj(x, lp["rms_att"], lp["wqkv"], layer, eps)
+        qkv = _norm_proj(x, lp["rms_att"], lp["wqkv"], layer, eps, name="wqkv")
         d, kv = cfg.dim, cfg.kv_dim
         q, k, v = qkv[:, :d], qkv[:, d : d + kv], qkv[:, d + kv :]
     else:
-        q = _norm_proj(x, lp["rms_att"], lp["wq"], layer, eps)
-        k = _norm_proj(x, lp["rms_att"], lp["wk"], layer, eps)
-        v = _norm_proj(x, lp["rms_att"], lp["wv"], layer, eps)
+        q = _norm_proj(x, lp["rms_att"], lp["wq"], layer, eps, name="wq")
+        k = _norm_proj(x, lp["rms_att"], lp["wk"], layer, eps, name="wk")
+        v = _norm_proj(x, lp["rms_att"], lp["wv"], layer, eps, name="wv")
     q = q.reshape(B, -1, cfg.head_size)
     k = k.reshape(B, -1, cfg.head_size)
     v = v.reshape(B, -1, cfg.head_size)
@@ -979,30 +989,38 @@ def _attn_block_batched(cfg: ModelConfig, lp: dict, rope: dict, x, k_cache,
         if layer is None:
             slab_k, slab_v = k_cache, v_cache
         else:
-            slab_k = jax.lax.dynamic_index_in_dim(k_cache, layer, 0, keepdims=False)
-            slab_v = jax.lax.dynamic_index_in_dim(v_cache, layer, 0, keepdims=False)
+            # the pool cache's dynamic-slice: each layer's [B, ctx] K and V
+            # slab is copied out of the stacked cache, every step
+            with jax.named_scope("kv_slab_read"):
+                slab_k = jax.lax.dynamic_index_in_dim(
+                    k_cache, layer, 0, keepdims=False)
+                slab_v = jax.lax.dynamic_index_in_dim(
+                    v_cache, layer, 0, keepdims=False)
         if not fused_kv:
-            write = jax.vmap(
-                lambda c, kk, p: jax.lax.dynamic_update_slice_in_dim(
-                    c, kk[None].astype(c.dtype), p, axis=0))
-            slab_k = write(slab_k, k, pos)
-            slab_v = write(slab_v, v, pos)
-            if layer is None:
-                k_cache, v_cache = slab_k, slab_v
-            else:
-                zero = (0, 0, 0, 0)
-                k_cache = jax.lax.dynamic_update_slice(k_cache, slab_k[None], (layer, *zero))
-                v_cache = jax.lax.dynamic_update_slice(v_cache, slab_v[None], (layer, *zero))
+            with jax.named_scope("kv_slab_write"):
+                write = jax.vmap(
+                    lambda c, kk, p: jax.lax.dynamic_update_slice_in_dim(
+                        c, kk[None].astype(c.dtype), p, axis=0))
+                slab_k = write(slab_k, k, pos)
+                slab_v = write(slab_v, v, pos)
+                if layer is None:
+                    k_cache, v_cache = slab_k, slab_v
+                else:
+                    zero = (0, 0, 0, 0)
+                    k_cache = jax.lax.dynamic_update_slice(
+                        k_cache, slab_k[None], (layer, *zero))
+                    v_cache = jax.lax.dynamic_update_slice(
+                        v_cache, slab_v[None], (layer, *zero))
 
         out = jax.vmap(
             lambda qb, ks, vs, p: gqa_attention(qb[None], ks, vs, p)[0]
         )(q, slab_k, slab_v, pos)  # [B, local heads, hs]
     if row_mode:  # local heads -> K-sharded wo: no gathers, f32 partials
-        return (matmul_any(out.reshape(B, -1), lp["wo"], layer)
+        return (matmul_any(out.reshape(B, -1), lp["wo"], layer, name="wo")
                 .astype(jnp.float32), k_cache, v_cache)
     out = _gather(out.reshape(B, -1), tp_axis, tp_compress)
-    return (_gather(matmul_any(out, lp["wo"], layer), tp_axis, tp_compress),
-            k_cache, v_cache)
+    return (_gather(matmul_any(out, lp["wo"], layer, name="wo"), tp_axis,
+                    tp_compress), k_cache, v_cache)
 
 
 def forward_batched(
@@ -1094,7 +1112,7 @@ def forward_batched(
                              cfg.norm_eps, cfg.dim)
     else:
         x = rmsnorm(x, params["rms_final"], cfg.norm_eps)
-    logits = matmul_any(x, params["wcls"]).astype(jnp.float32)
+    logits = matmul_any(x, params["wcls"], name="wcls").astype(jnp.float32)
     if tp_axis is not None and gather_logits:
         # slice off lane-alignment vocab padding, exactly like `forward`
         logits = _gather(logits, tp_axis)[..., : cfg.vocab_size]
@@ -1239,7 +1257,7 @@ def forward_batched_overlap(
     new_v = jnp.concatenate([va, vb], axis=1)
     if not row:
         x = rmsnorm(x, params["rms_final"], cfg.norm_eps)
-    logits = matmul_any(x, params["wcls"]).astype(jnp.float32)
+    logits = matmul_any(x, params["wcls"], name="wcls").astype(jnp.float32)
     if tp_axis is not None and gather_logits:
         logits = _gather(logits, tp_axis)[..., : cfg.vocab_size]
     if cfg.logit_scale != 1.0:
@@ -1247,6 +1265,7 @@ def forward_batched_overlap(
     return logits, {"k": new_k, "v": new_v}
 
 
+@jax.named_scope("attention")
 def _verify_layer(cfg: ModelConfig, lp: dict, rope: dict, x, k_cache,
                   v_cache, pos, idx, tp_axis=None, tp_compress: bool = False,
                   row_mode: bool = False, red_compress: bool = False):
@@ -1263,19 +1282,23 @@ def _verify_layer(cfg: ModelConfig, lp: dict, rope: dict, x, k_cache,
         x_s = x.reshape(B * T, x.shape[-1])  # scattered residual rows
         xn = _row_norm_gather(x_s, lp["rms_att"], tp_axis, tp_compress,
                               cfg.norm_eps, cfg.dim)
-        q = matmul_any(xn, lp["wq"], idx)
-        k = matmul_any(xn, lp["wk"], idx)
-        v = matmul_any(xn, lp["wv"], idx)
+        q = matmul_any(xn, lp["wq"], idx, name="wq")
+        k = matmul_any(xn, lp["wk"], idx, name="wk")
+        v = matmul_any(xn, lp["wv"], idx, name="wv")
     elif "wqkv" in lp:
         xf = x.reshape(B * T, cfg.dim)  # raw rows; rmsnorm rides in _norm_proj
-        qkv = _norm_proj(xf, lp["rms_att"], lp["wqkv"], idx, cfg.norm_eps)
+        qkv = _norm_proj(xf, lp["rms_att"], lp["wqkv"], idx, cfg.norm_eps,
+                         name="wqkv")
         d, kv = cfg.dim, cfg.kv_dim
         q, k, v = qkv[:, :d], qkv[:, d : d + kv], qkv[:, d + kv :]
     else:
         xf = x.reshape(B * T, cfg.dim)  # raw rows; rmsnorm rides in _norm_proj
-        q = _norm_proj(xf, lp["rms_att"], lp["wq"], idx, cfg.norm_eps)
-        k = _norm_proj(xf, lp["rms_att"], lp["wk"], idx, cfg.norm_eps)
-        v = _norm_proj(xf, lp["rms_att"], lp["wv"], idx, cfg.norm_eps)
+        q = _norm_proj(xf, lp["rms_att"], lp["wq"], idx, cfg.norm_eps,
+                       name="wq")
+        k = _norm_proj(xf, lp["rms_att"], lp["wk"], idx, cfg.norm_eps,
+                       name="wk")
+        v = _norm_proj(xf, lp["rms_att"], lp["wv"], idx, cfg.norm_eps,
+                       name="wv")
     # head counts derive from the ARRAY shapes: under tp they are the
     # local slices (the reference's MultiHeadAttSlice head split)
     q = q.reshape(B, T, -1, cfg.head_size)
@@ -1315,8 +1338,8 @@ def _verify_layer(cfg: ModelConfig, lp: dict, rope: dict, x, k_cache,
     if row_mode:
         # local heads feed the K-sharded wo directly; the partial rides the
         # ring reduce-scatter and the residual add stays on the shard
-        att_p = matmul_any(out.reshape(B * T, -1), lp["wo"], idx
-                           ).astype(jnp.float32)
+        att_p = matmul_any(out.reshape(B * T, -1), lp["wo"], idx,
+                           name="wo").astype(jnp.float32)
         x_s = x_s + _reduce_scatter(att_p, tp_axis, red_compress
                                     ).astype(x_s.dtype)
         xn = _row_norm_gather(x_s, lp["rms_ffn"], tp_axis, tp_compress,
@@ -1326,7 +1349,8 @@ def _verify_layer(cfg: ModelConfig, lp: dict, rope: dict, x, k_cache,
                                     ).astype(x_s.dtype)
         return x_s.reshape(B, T, -1), k_cache, v_cache
     heads = _gather(out.reshape(B * T, -1), tp_axis, tp_compress)
-    att = _gather(matmul_any(heads, lp["wo"], idx), tp_axis, tp_compress)
+    att = _gather(matmul_any(heads, lp["wo"], idx, name="wo"), tp_axis,
+                  tp_compress)
     x = _ffn_residual(cfg, lp, x.reshape(B * T, cfg.dim),
                       att, tp_axis, tp_compress,
                       layer=idx).reshape(B, T, cfg.dim)
@@ -1394,7 +1418,7 @@ def forward_batched_verify(
     else:
         x = rmsnorm(x, params["rms_final"], cfg.norm_eps)
     logits = matmul_any(x.reshape(B * T, cfg.dim),
-                        params["wcls"]).astype(jnp.float32)
+                        params["wcls"], name="wcls").astype(jnp.float32)
     if tp_axis is not None and gather_logits:
         # slice off lane-alignment vocab padding, exactly like `forward`
         logits = _gather(logits, tp_axis)[..., : cfg.vocab_size]
@@ -1471,7 +1495,7 @@ def forward_batched_verify_overlap(
     if not row:
         x = rmsnorm(x, params["rms_final"], cfg.norm_eps)
     logits = matmul_any(x.reshape(B * T, cfg.dim),
-                        params["wcls"]).astype(jnp.float32)
+                        params["wcls"], name="wcls").astype(jnp.float32)
     if tp_axis is not None and gather_logits:
         # slice off lane-alignment vocab padding, exactly like `forward`
         logits = _gather(logits, tp_axis)[..., : cfg.vocab_size]

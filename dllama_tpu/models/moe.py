@@ -46,6 +46,7 @@ from dllama_tpu.ops.qmatmul import QuantTensor, matmul_any, slice_to_in_features
 from dllama_tpu.parallel.collectives import gather_columns as _gather
 
 
+@jax.named_scope("moe_router")
 def route_topk(cfg: ModelConfig, router_kernel: jnp.ndarray,
                xb: jnp.ndarray) -> tuple:
     """Top-k routing -> (indices [..., k], renormalized weights [..., k]).
@@ -83,12 +84,15 @@ def _flat_experts(qt: QuantTensor) -> QuantTensor:
     )
 
 
-def _expert_up(xb: jnp.ndarray, w, base=None) -> jnp.ndarray:
+def _expert_up(xb: jnp.ndarray, w, base=None,
+               name: str = "expert_up") -> jnp.ndarray:
     """``xb [..., D] x w [E, D, H] -> [..., E, H]``; ``w`` is a dense stack or
     an expert-stacked QuantTensor. Quantized experts run one fused
     dequant-matmul per expert; with ``base`` (= layer * E, the scalar-prefetch
     path) the planes are layer-stacked and indexed in the kernel, otherwise
-    the scan slices the per-layer stack."""
+    the scan slices the per-layer stack. ``name``: which expert projection
+    (``expert_up`` | ``expert_gate`` | ``expert_upgate``), for the kernels'
+    names in a trace."""
     if not isinstance(w, QuantTensor):
         return jnp.einsum("...d,edh->...eh", xb, w)
     lead = xb.shape[:-1]
@@ -99,12 +103,12 @@ def _expert_up(xb: jnp.ndarray, w, base=None) -> jnp.ndarray:
         n_e = w.w.shape[1]
 
         def step(_, e):
-            return None, matmul_any(x2, flat, base + e)
+            return None, matmul_any(x2, flat, base + e, name=name)
 
         _, outs = jax.lax.scan(step, None, jnp.arange(n_e, dtype=jnp.int32))
     else:
         def step(_, qt_e):
-            return None, matmul_any(x2, qt_e)
+            return None, matmul_any(x2, qt_e, name=name)
 
         _, outs = jax.lax.scan(step, None, w)  # [E, N, H]
     return jnp.moveaxis(outs, 0, 1).reshape(*lead, outs.shape[0], outs.shape[-1])
@@ -123,14 +127,14 @@ def _expert_down(h: jnp.ndarray, w, base=None) -> jnp.ndarray:
 
         def step(_, eh):
             e, h_e = eh
-            return None, matmul_any(h_e, flat, base + e)
+            return None, matmul_any(h_e, flat, base + e, name="expert_down")
 
         _, outs = jax.lax.scan(
             step, None, (jnp.arange(E, dtype=jnp.int32), hm))
     else:
         def step(_, eh):
             h_e, qt_e = eh
-            return None, matmul_any(h_e, qt_e)
+            return None, matmul_any(h_e, qt_e, name="expert_down")
 
         _, outs = jax.lax.scan(step, None, (hm, w))  # [E, N, D]
     return jnp.moveaxis(outs, 0, 1).reshape(*lead, E, outs.shape[-1])
@@ -174,11 +178,12 @@ def _moe_decode_selected(cfg: ModelConfig, lp: dict, xb: jnp.ndarray, layer,
     def up_step(_, j):
         idx = base + expert_ids[j]
         if fused:
-            ug = matmul_any(xb, up_flat, idx)
+            ug = matmul_any(xb, up_flat, idx, name="expert_upgate")
             half = ug.shape[-1] // 2
             h = ug[..., :half] * act(ug[..., half:])
         else:
-            h = matmul_any(xb, up_flat, idx) * act(matmul_any(xb, gate_flat, idx))
+            h = (matmul_any(xb, up_flat, idx, name="expert_up")
+                 * act(matmul_any(xb, gate_flat, idx, name="expert_gate")))
         return None, h
 
     _, hs = jax.lax.scan(up_step, None, jnp.arange(cap, dtype=jnp.int32))
@@ -187,7 +192,8 @@ def _moe_decode_selected(cfg: ModelConfig, lp: dict, xb: jnp.ndarray, layer,
     def down_step(acc, jh):
         j, h = jh
         e = expert_ids[j]
-        d = matmul_any(h, down_flat, base + e)  # [T, out_dim]
+        d = matmul_any(h, down_flat, base + e,
+                       name="expert_down")  # [T, out_dim]
         w_e = jax.lax.dynamic_index_in_dim(combine, e, axis=1)  # [T, 1]
         return acc + d * w_e.astype(d.dtype), None
 
@@ -197,6 +203,7 @@ def _moe_decode_selected(cfg: ModelConfig, lp: dict, xb: jnp.ndarray, layer,
     return _gather(acc, tp_axis, tp_compress)
 
 
+@jax.named_scope("moe")
 def moe_ffn(cfg: ModelConfig, lp: dict, xb: jnp.ndarray, layer=None,
             tp_axis=None, tp_compress: bool = False) -> jnp.ndarray:
     """MoE FFN over xb [..., dim] -> [..., dim].
@@ -227,12 +234,12 @@ def moe_ffn(cfg: ModelConfig, lp: dict, xb: jnp.ndarray, layer=None,
     combine = route(cfg, lp["moe_router"], xb).astype(xb.dtype)  # [..., E]
 
     if "moe_upgate" in lp:  # fused up|gate expert stacks (llama.fuse_qkv_ffn)
-        ug = _expert_up(xb, lp["moe_upgate"], base)
+        ug = _expert_up(xb, lp["moe_upgate"], base, "expert_upgate")
         half = ug.shape[-1] // 2
         h = ug[..., :half] * act(ug[..., half:])
     else:
         up = _expert_up(xb, lp["moe_up"], base)
-        gate = _expert_up(xb, lp["moe_gate"], base)
+        gate = _expert_up(xb, lp["moe_gate"], base, "expert_gate")
         h = up * act(gate)
     h = _gather(h, tp_axis, tp_compress)  # [..., E, full hidden] under tp
     h = slice_to_in_features(h, lp["moe_down"])

@@ -19,6 +19,13 @@ Three cooperating pieces, all stdlib-only (matching the repo's no-deps style):
   (JSON Array Format: one event per line, ``]`` intentionally omitted as the
   format allows, loadable by Perfetto and chrome://tracing), and
   ``log_json_line`` prints one structured JSON log line per request.
+
+* ``phase`` / ``tick`` — the one span primitive below the request: every
+  phase boundary of a scheduler tick (``Batcher._serve_continuous``,
+  ``BatchSession``) is one ``with phase(...)`` whose single pair of clock
+  reads feeds a ``jax.profiler`` host span (same clock as the device plane),
+  ``dllama_tick_phase_seconds_total`` and, under ``DLLAMA_TRACE``, the
+  scheduler track. JAX is looked up lazily: this module stays stdlib-only.
 """
 
 from __future__ import annotations
@@ -29,6 +36,7 @@ import itertools
 import json
 import math
 import os
+import sys
 import threading
 import time
 import uuid
@@ -60,6 +68,8 @@ __all__ = [
     "server_timing_header",
     "parse_server_timing",
     "scheduler_trace_event",
+    "phase",
+    "tick",
     "SCHEDULER_TID",
     "LATENCY_BUCKETS_MS",
     "TOKEN_BUCKETS",
@@ -149,6 +159,14 @@ class Counter(_Metric):
         key = self._key(labels)
         with self._lock:
             self._children[key] = self._children.get(key, 0.0) + amount
+
+    def add_keyed(self, amounts: Dict[Tuple[str, ...], float]) -> None:
+        """Add to several children under ONE hold of the lock; each key is
+        the tuple of label values in ``labelnames`` order (what a tick
+        gathered lock-free while it ran)."""
+        with self._lock:
+            for key, amount in amounts.items():
+                self._children[key] = self._children.get(key, 0.0) + amount
 
     def value(self, **labels: object) -> float:
         key = self._key(labels)
@@ -604,6 +622,146 @@ def scheduler_trace_event(name: str, t_a: float, t_b: float,
     }
 
 
+# ---------------------------------------------------------------------------
+# Phase spans of the scheduler tick
+
+_M_PHASE_SECONDS = _DEFAULT.counter(
+    "dllama_tick_phase_seconds_total",
+    "Seconds the scheduler thread spent in each phase of its ticks (side="
+    "device: nothing but a blocked wait on the device; host: everything else)",
+    ("phase", "layer", "side"))
+_M_TICKS = _DEFAULT.counter(
+    "dllama_ticks_total",
+    "Passes of the continuous scheduler loop that launched device work")
+
+_tick_seq = itertools.count(1)
+_tls = threading.local()  # .tick: the tick span open on this thread
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, or None in a process that has not
+    imported JAX (the router): nothing here may be what imports it."""
+    if "jax" not in sys.modules:
+        return None
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
+class phase:
+    """``with phase(name, layer, side, **args) as p:`` — one timed span.
+
+    One pair of clock reads (``p.t0``, ``p.t1``, ``time.monotonic``) feeds
+    every sink: a ``jax.profiler.TraceAnnotation`` while a profiler session
+    is recording (the span then lies in the xplane's ``/host:CPU`` plane, on
+    the device plane's clock), and — inside a ``tick`` on the same thread —
+    ``dllama_tick_phase_seconds_total{phase,layer,side}`` plus, with
+    ``DLLAMA_TRACE`` set, a complete-event on the scheduler track. A leaf
+    carries its tick's number (``tick=`` in the span's arguments); ``args``
+    say what caused it (the request's ``span_id`` on the prefill phases).
+
+    ``side="device"`` is for a phase that is nothing but a blocked wait on
+    the device, and is never merged with host work. Without ``layer``, or
+    outside a tick, the span only annotates (``sse_write`` on a handler
+    thread, ``scheduler_window``). With no profiler session and no
+    ``DLLAMA_TRACE`` a span costs its two clock reads and one dict add. An
+    exception inside the span ends it like any exit and propagates."""
+
+    __slots__ = ("name", "layer", "side", "args", "t0", "t1", "_tick", "_ann")
+
+    def __init__(self, name: str, layer: Optional[str] = None,
+                 side: str = "host", **args: object):
+        self.name = name
+        self.layer = layer
+        self.side = side
+        self.args = args
+        self.t0 = self.t1 = 0.0
+        self._tick: Optional["tick"] = None
+        self._ann = None
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
+
+    def _span_args(self) -> dict:
+        t = self._tick
+        return self.args if t is None else dict(self.args, tick=t.seq)
+
+    def __enter__(self) -> "phase":
+        self._tick = getattr(_tls, "tick", None)
+        cls = _annotation()
+        if cls is not None and cls.is_enabled():
+            self._ann = cls(self.name, **self._span_args())
+            self._ann.__enter__()
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, et, ev, tb) -> bool:
+        self.t1 = time.monotonic()
+        if self._ann is not None:
+            self._ann.__exit__(et, ev, tb)
+        t = self._tick
+        if t is not None and self.layer is not None:
+            t._leaf(self)
+        return False
+
+
+class tick(phase):
+    """The parent span around one pass of the continuous scheduler loop.
+
+    Allocates the pass's sequence number, is the current tick of its thread
+    while open (leaf phases find it there and take its number), gathers
+    their seconds lock-free, and on exit adds them to the counter family
+    under one lock hold, counts the pass in ``dllama_ticks_total`` if any
+    ``side="device"`` phase ran in it (it launched), and writes the pass's
+    events to the ``DLLAMA_TRACE`` file in one batch."""
+
+    __slots__ = ("seq", "_seconds", "_events", "launched")
+
+    def __init__(self):
+        super().__init__("tick")
+        self.seq = 0
+        self.launched = False
+        self._seconds: Dict[Tuple[str, ...], float] = {}
+        self._events: Optional[List[dict]] = None
+
+    def _span_args(self) -> dict:
+        return {"tick": self.seq}
+
+    def _leaf(self, p: phase) -> None:
+        key = (p.name, p.layer, p.side)
+        self._seconds[key] = self._seconds.get(key, 0.0) + (p.t1 - p.t0)
+        if p.side == "device":
+            self.launched = True
+        if self._events is not None:
+            self._events.append(scheduler_trace_event(
+                p.name, p.t0, p.t1, p._span_args()))
+
+    def __enter__(self) -> "tick":
+        self.seq = next(_tick_seq)
+        if trace_path() is not None:
+            self._events = []
+        super().__enter__()
+        self._tick = None  # a tick is nobody's leaf
+        _tls.tick = self
+        return self
+
+    def __exit__(self, et, ev, tb) -> bool:
+        _tls.tick = None
+        super().__exit__(et, ev, tb)
+        if self._seconds:
+            _M_PHASE_SECONDS.add_keyed(self._seconds)
+        if self.launched:
+            _M_TICKS.inc()
+        if self._events is not None:
+            emit_trace_events(
+                [scheduler_trace_event("tick", self.t0, self.t1,
+                                       self._span_args())] + self._events)
+        return False
+
+
 def sanitize_request_id(raw: Optional[str]) -> str:
     """Honor a client X-Request-Id if it is sane, else mint one."""
     if raw:
@@ -910,6 +1068,18 @@ class RequestTrace:
         if self.t_start is None:
             return None
         return (self.t_start - self.t0) * 1e3
+
+    @property
+    def prefill_turn_wait_ms(self) -> Optional[float]:
+        """Admitted (``t_start``) to the start of its first prefill piece:
+        the wait for its turn behind the other rows' pieces, which
+        ``queue_wait_ms`` ends too early to see. 0 for a request admitted
+        without chunked prefill, None for one never admitted."""
+        if self.t_start is None:
+            return None
+        if not self.prefill_chunks:
+            return 0.0
+        return max(0.0, (self.prefill_chunks[0][0] - self.t_start) * 1e3)
 
     @property
     def ttft_ms(self) -> Optional[float]:

@@ -175,6 +175,16 @@ def tile_plan(kind: str, k_padded: int, out_features: int) -> tuple[int, int]:
 # Q80: int8 weights, one f32 scale per 32 input rows
 # ---------------------------------------------------------------------------
 
+def kernel_name(projection: str | None, kind: str) -> str | None:
+    """The name a kernel's custom call carries in the compiled program and
+    so in a profiler trace: ``<projection>_<kind>`` (``wqkv_q40_matmul``,
+    ``w2_q40_corr``). None keeps the enclosing jitted function's name, one
+    for every projection. The kind comes last and ends in a letter because
+    trace reductions strip a trailing instruction number (``w13.44`` would
+    read ``w``): a name must not end in a digit."""
+    return None if projection is None else f"{projection}_{kind}"
+
+
 def _q80_kernel(*refs, acc_dtype, stacked=False, fuse_norm=False):
     from jax.experimental import pallas as pl
 
@@ -235,11 +245,12 @@ def _norm_layer_map(norm_w):
     return lambda idx: 0
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "name"))
 def q80_matmul(x: jnp.ndarray, w: jnp.ndarray, scales: jnp.ndarray,
                interpret: bool | None = None,
                norm_w: jnp.ndarray | None = None,
-               norm_inv: jnp.ndarray | None = None) -> jnp.ndarray:
+               norm_inv: jnp.ndarray | None = None,
+               name: str | None = None) -> jnp.ndarray:
     """``x [T, K] @ dequant(w int8 [K, O], scales [K/32, O]) -> [T, O]`` f32.
 
     ``norm_w``/``norm_inv`` (both or neither): fuse the rmsnorm epilogue
@@ -284,16 +295,18 @@ def q80_matmul(x: jnp.ndarray, w: jnp.ndarray, scales: jnp.ndarray,
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name=kernel_name(name, "q80_matmul"),
     )(*operands)
     return out[:t]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "name"))
 def q80_matmul_stacked(x: jnp.ndarray, w: jnp.ndarray, scales: jnp.ndarray,
                        layer: jnp.ndarray,
                        interpret: bool | None = None,
                        norm_w: jnp.ndarray | None = None,
-                       norm_inv: jnp.ndarray | None = None) -> jnp.ndarray:
+                       norm_inv: jnp.ndarray | None = None,
+                       name: str | None = None) -> jnp.ndarray:
     """Layer-indexed ``x [T, K] @ dequant(w[layer])`` over STACKED planes
     ``w int8 [L, K, O]``, ``scales [L, K/32, O]``, with a traced ``layer``.
 
@@ -349,6 +362,7 @@ def q80_matmul_stacked(x: jnp.ndarray, w: jnp.ndarray, scales: jnp.ndarray,
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name=kernel_name(name, "q80_matmul"),
     )(jnp.asarray(layer, jnp.int32).reshape(1), *operands)
     return out[:t]
 
@@ -434,7 +448,7 @@ def _q40_block_sums(xp: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     return xs[:, 0::2], xs[:, 1::2]  # each [T, K/64]
 
 
-def _q40_correction(xp, s_lo, s_hi, layer=None, interpret=False):
+def _q40_correction(xp, s_lo, s_hi, layer=None, interpret=False, name=None):
     """Run the correction kernel. ``s_lo/s_hi`` are [K/64, O] (or stacked
     [L, K/64, O] with a traced ``layer``); returns [T, O] f32. A Pallas
     kernel — not two jnp dots — so the stacked case steers the layer choice
@@ -464,6 +478,7 @@ def _q40_correction(xp, s_lo, s_hi, layer=None, interpret=False):
             out_shape=jax.ShapeDtypeStruct((T, O), jnp.float32),
             compiler_params=params,
             interpret=interpret,
+            name=name,
         )(xs_lo, xs_hi, s_lo, s_hi)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -482,6 +497,7 @@ def _q40_correction(xp, s_lo, s_hi, layer=None, interpret=False):
         out_shape=jax.ShapeDtypeStruct((T, O), jnp.float32),
         compiler_params=params,
         interpret=interpret,
+        name=name,
     )(jnp.asarray(layer, jnp.int32).reshape(1), xs_lo, xs_hi, s_lo, s_hi)
 
 
@@ -506,12 +522,13 @@ def _q40_normed(xp, norm_w, norm_inv, layer=None):
     return (nw * (xp.astype(jnp.float32) * inv_p)).astype(jnp.bfloat16)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "nosub"))
+@functools.partial(jax.jit, static_argnames=("interpret", "nosub", "name"))
 def q40_matmul(x: jnp.ndarray, packed: jnp.ndarray, s_lo: jnp.ndarray,
                s_hi: jnp.ndarray, interpret: bool | None = None,
                nosub: bool | None = None,
                norm_w: jnp.ndarray | None = None,
-               norm_inv: jnp.ndarray | None = None) -> jnp.ndarray:
+               norm_inv: jnp.ndarray | None = None,
+               name: str | None = None) -> jnp.ndarray:
     """``x [T, K] @ dequant(packed uint8 [K/2, O]) -> [T, O]`` f32.
 
     ``norm_w``/``norm_inv``: fused rmsnorm epilogue (see ``q80_matmul``) —
@@ -561,20 +578,23 @@ def q40_matmul(x: jnp.ndarray, packed: jnp.ndarray, s_lo: jnp.ndarray,
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name=kernel_name(name, "q40_matmul"),
     )(*operands)
     if nosub:
         xn = _q40_normed(xp, norm_w, norm_inv) if fused else xp
-        out = out - _q40_correction(xn, s_lo, s_hi, interpret=interpret)
+        out = out - _q40_correction(xn, s_lo, s_hi, interpret=interpret,
+                                    name=kernel_name(name, "q40_corr"))
     return out[:t]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "nosub"))
+@functools.partial(jax.jit, static_argnames=("interpret", "nosub", "name"))
 def q40_matmul_stacked(x: jnp.ndarray, packed: jnp.ndarray, s_lo: jnp.ndarray,
                        s_hi: jnp.ndarray, layer: jnp.ndarray,
                        interpret: bool | None = None,
                        nosub: bool | None = None,
                        norm_w: jnp.ndarray | None = None,
-                       norm_inv: jnp.ndarray | None = None) -> jnp.ndarray:
+                       norm_inv: jnp.ndarray | None = None,
+                       name: str | None = None) -> jnp.ndarray:
     """Layer-indexed q40 matmul over STACKED planes ``packed uint8 [L, K/2,
     O]`` with a traced ``layer`` — see ``q80_matmul_stacked`` for why the
     layer selection must happen inside the kernel's index_map. ``norm_w``
@@ -630,12 +650,14 @@ def q40_matmul_stacked(x: jnp.ndarray, packed: jnp.ndarray, s_lo: jnp.ndarray,
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name=kernel_name(name, "q40_matmul"),
     )(jnp.asarray(layer, jnp.int32).reshape(1), *operands)
     if nosub:
         xn = (_q40_normed(xp, norm_w, norm_inv, layer=layer) if fused
               else xp)
         out = out - _q40_correction(xn, s_lo, s_hi, layer=layer,
-                                    interpret=interpret)
+                                    interpret=interpret,
+                                    name=kernel_name(name, "q40_corr"))
     return out[:t]
 
 
@@ -677,30 +699,36 @@ class QuantTensor:
         return self.w.shape[-1]
 
 
-def qmatmul(x: jnp.ndarray, qt: QuantTensor, layer=None) -> jnp.ndarray:
+def qmatmul(x: jnp.ndarray, qt: QuantTensor, layer=None,
+            name: str | None = None) -> jnp.ndarray:
     """Dispatch ``x @ dequant(qt)`` to the right fused kernel. Output dtype
     follows ``x`` (the caller's activation dtype), accumulation is f32.
 
     ``layer``: a traced int32 selecting one layer of a layer-STACKED
     QuantTensor (planes with a leading L axis) — the scalar-prefetch path
-    used by the scan-over-layers forward. None = qt is a single matrix."""
+    used by the scan-over-layers forward. None = qt is a single matrix.
+
+    ``name`` (static): which projection this is (``wqkv``, ``w2``,
+    ``expert_down``): the kernel's custom call is named for it
+    (``kernel_name``), so a device trace splits kernel time by projection."""
     if qt.kind == "q40":
         if layer is None:
-            out = q40_matmul(x, qt.w, qt.s, qt.s2)
+            out = q40_matmul(x, qt.w, qt.s, qt.s2, name=name)
         else:
-            out = q40_matmul_stacked(x, qt.w, qt.s, qt.s2, layer)
+            out = q40_matmul_stacked(x, qt.w, qt.s, qt.s2, layer, name=name)
     elif qt.kind == "q80":
         if layer is None:
-            out = q80_matmul(x, qt.w, qt.s)
+            out = q80_matmul(x, qt.w, qt.s, name=name)
         else:
-            out = q80_matmul_stacked(x, qt.w, qt.s, layer)
+            out = q80_matmul_stacked(x, qt.w, qt.s, layer, name=name)
     else:
         raise ValueError(f"unknown QuantTensor kind {qt.kind!r}")
     return out.astype(x.dtype)
 
 
 def qmatmul_norm(x: jnp.ndarray, norm_w: jnp.ndarray, qt: QuantTensor,
-                 layer=None, eps: float = 1e-5) -> jnp.ndarray:
+                 layer=None, eps: float = 1e-5,
+                 name: str | None = None) -> jnp.ndarray:
     """``rmsnorm(x, norm_w) @ dequant(qt)`` with the norm fused into the
     matmul kernel as an x-block epilogue (DLLAMA_FUSE_NORM): the raw
     activation streams into VMEM once and the normalized bf16 tile is
@@ -712,27 +740,30 @@ def qmatmul_norm(x: jnp.ndarray, norm_w: jnp.ndarray, qt: QuantTensor,
     if qt.kind == "q40":
         if layer is None:
             out = q40_matmul(x, qt.w, qt.s, qt.s2, norm_w=norm_w,
-                             norm_inv=inv)
+                             norm_inv=inv, name=name)
         else:
             out = q40_matmul_stacked(x, qt.w, qt.s, qt.s2, layer,
-                                     norm_w=norm_w, norm_inv=inv)
+                                     norm_w=norm_w, norm_inv=inv, name=name)
     elif qt.kind == "q80":
         if layer is None:
-            out = q80_matmul(x, qt.w, qt.s, norm_w=norm_w, norm_inv=inv)
+            out = q80_matmul(x, qt.w, qt.s, norm_w=norm_w, norm_inv=inv,
+                             name=name)
         else:
             out = q80_matmul_stacked(x, qt.w, qt.s, layer, norm_w=norm_w,
-                                     norm_inv=inv)
+                                     norm_inv=inv, name=name)
     else:
         raise ValueError(f"unknown QuantTensor kind {qt.kind!r}")
     return out.astype(x.dtype)
 
 
-def matmul_any(x: jnp.ndarray, w, layer=None) -> jnp.ndarray:
+def matmul_any(x: jnp.ndarray, w, layer=None,
+               name: str | None = None) -> jnp.ndarray:
     """``x @ w`` where w is a plain array or a QuantTensor. ``layer`` selects
     a layer of a stacked QuantTensor (ignored for plain arrays, which the
-    caller indexes itself — XLA fuses a dense dynamic-slice into the dot)."""
+    caller indexes itself — XLA fuses a dense dynamic-slice into the dot).
+    ``name``: the projection, for the kernel's name in a trace (``qmatmul``)."""
     if isinstance(w, QuantTensor):
-        return qmatmul(x, w, layer)
+        return qmatmul(x, w, layer, name)
     return x @ w
 
 

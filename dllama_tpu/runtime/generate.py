@@ -205,6 +205,11 @@ class Engine:
             self._m_prefill_chunk = metrics.histogram(
                 "dllama_prefill_chunk_ms",
                 "Incremental prefill chunk wall time (chunked admission)")
+            self._m_live_rows = metrics.histogram(
+                "dllama_decode_live_rows",
+                "Rows a pooled decode launch advances (live: resident, "
+                "prefilled, not done), one observation per launch",
+                buckets=observability.TOKEN_BUCKETS)
             self._m_migrations = metrics.counter(
                 "dllama_kv_migrations_total",
                 "Pooled rows migrated to the next larger KV bucket")
@@ -248,6 +253,7 @@ class Engine:
         else:
             self._m_prefill = self._m_step = self._m_chunk = None
             self._m_prefill_chunk = self._m_migrations = None
+            self._m_live_rows = None
             self._m_quarantine = None
             self._m_spec_steps = self._m_spec_accepted = None
             self._m_spec_emitted = None
@@ -584,10 +590,11 @@ class Engine:
                     logits, cache = fwd_b(cfg, params, rope, toks, cache,
                                           pos_)
                     logits, ok = _health(logits, poison, ok)
-                    split = jax.vmap(jax.random.split)(keys_)  # [B, 2, 2]
-                    keys_, subs = split[:, 0], split[:, 1]
-                    nxt = jax.vmap(sample_dynamic)(logits, subs, temps, topps
-                                                   ).astype(jnp.int32)
+                    with jax.named_scope("sample"):
+                        split = jax.vmap(jax.random.split)(keys_)  # [B, 2, 2]
+                        keys_, subs = split[:, 0], split[:, 1]
+                        nxt = jax.vmap(sample_dynamic)(
+                            logits, subs, temps, topps).astype(jnp.int32)
                     pos_ = jnp.minimum(pos_ + 1, jnp.int32(cfg.seq_len - 1))
                     return (cache, nxt, pos_, keys_, ok), nxt
 
@@ -639,13 +646,15 @@ class Engine:
 
                 def body(carry, _):
                     arena, toks, pos_, keys_, ok = carry
-                    window = jax.tree.map(gather, arena)
+                    with jax.named_scope("kv_page_gather"):
+                        window = jax.tree.map(gather, arena)
                     logits, window = fwd_b(cfg, params, rope, toks, window, pos_)
                     logits, ok = _health(logits, poison, ok)
-                    split = jax.vmap(jax.random.split)(keys_)
-                    keys_, subs = split[:, 0], split[:, 1]
-                    nxt = jax.vmap(sample_dynamic)(logits, subs, temps, topps
-                                                   ).astype(jnp.int32)
+                    with jax.named_scope("sample"):
+                        split = jax.vmap(jax.random.split)(keys_)
+                        keys_, subs = split[:, 0], split[:, 1]
+                        nxt = jax.vmap(sample_dynamic)(
+                            logits, subs, temps, topps).astype(jnp.int32)
                     wpos = jnp.clip(pos_, 0, W - 1)  # [B] position written
                     blk = wpos // page
                     phys = jnp.take_along_axis(tables, blk[:, None],
@@ -661,7 +670,8 @@ class Engine:
                             in_axes=(1, 0), out_axes=1)(w, off)
                         return a.at[:, phys].set(pg)  # [L, B, page, kv, hd]
 
-                    arena = jax.tree.map(scat, arena, window)
+                    with jax.named_scope("kv_page_scatter"):
+                        arena = jax.tree.map(scat, arena, window)
                     pos_ = jnp.minimum(pos_ + 1, jnp.int32(W - 1))
                     return (arena, nxt, pos_, keys_, ok), nxt
 
@@ -1829,6 +1839,7 @@ class _SlotState:
     finish: Optional[str] = None  # "stop" | "length" | "error" once done
     prefilling: bool = False  # admit_begin()ed, prompt not fully consumed
     prefill_ms: float = 0.0  # accumulated admission-prefill wall time
+    span_id: int = 0  # the request's trace track, on its prefill phase spans
 
 
 class _PendingPrefill:
@@ -2055,6 +2066,10 @@ class BatchSession:
         self.migrations = 0  # rows moved to a larger bucket, this session
         self.decode_ms = 0.0  # cumulative fused-chunk wall time
         self.prefill_ms = 0.0  # cumulative admit-prefill wall time
+        #: (start, end) on time.monotonic of the last prefill_step's piece,
+        #: first phase's start to last phase's end: what the scheduler marks
+        #: on the request's trace (RequestTrace.mark_prefill_chunk)
+        self.piece_span: tuple = (0.0, 0.0)
         # paged-mode telemetry (all stay 0 in slab modes)
         self.prefix_hits = 0  # admits that aliased >= 1 cached page
         self.prefix_misses = 0  # admits with nothing cached to alias
@@ -2409,7 +2424,7 @@ class BatchSession:
 
     def admit_begin(self, prompt_tokens: list, steps: int,
                     sampler: Optional[SamplerConfig] = None,
-                    stop_tokens: tuple = ()) -> int:
+                    stop_tokens: tuple = (), span_id: int = 0) -> int:
         """Reserve a row for the prompt WITHOUT prefilling it: the prompt
         is consumed incrementally by ``prefill_step`` calls, interleaved
         with ``step_chunk``, so resident rows keep emitting tokens while a
@@ -2418,7 +2433,9 @@ class BatchSession:
         chunked prefill runs the same bucketed forwards at the same
         positions into the same slab, and the sampler chain starts from the
         same fresh PRNGKey. 1-token prompts have nothing to prefill and go
-        live immediately."""
+        live immediately. ``span_id`` (the request's ``RequestTrace`` track)
+        rides on the row's prefill phase spans, so a tick's spans and the
+        request's track can be joined."""
         if self._closed:
             raise RuntimeError("batch session is closed")
         if not prompt_tokens:
@@ -2436,8 +2453,10 @@ class BatchSession:
         faults.fire("admit")
         scfg = sampler if sampler is not None else self.eng.sampler_cfg
         if self.paged:
-            return self._admit_begin_paged(list(prompt_tokens), steps, scfg,
-                                           tuple(stop_tokens))
+            handle = self._admit_begin_paged(list(prompt_tokens), steps, scfg,
+                                             tuple(stop_tokens))
+            self._slots[handle].span_id = span_id
+            return handle
         plen = len(prompt_tokens)
         reserved = self._bucket_for(self._need_ctx(plen, steps))
         # place optimistically small: enough for the prompt plus one decode
@@ -2456,7 +2475,7 @@ class BatchSession:
         budget = min(room, steps)
         st = _SlotState(
             room=room, budget=budget, stop_tokens=tuple(stop_tokens),
-            reserved=reserved,
+            reserved=reserved, span_id=span_id,
             done=budget <= 0, finish="length" if budget <= 0 else None)
         self._slots[handle] = st
         self._where[handle] = (pool, row)
@@ -2610,41 +2629,52 @@ class BatchSession:
         if pf is None:
             raise ValueError(f"slot {handle} has no pending prefill")
         st = self._slots[handle]
-        prefix = pf.prompt[:-1]
-        n = budget if budget is not None else self.prefill_chunk
-        if n <= 0:
-            n = len(prefix) - pf.cursor
-        piece = prefix[pf.cursor:pf.cursor + n]
         faults.fire("prefill_chunk")
-        t0 = time.perf_counter()
-        _, pf.cache = self.eng._prefill_piece(pf.cache, piece, pf.cursor)
-        jax.block_until_ready(pf.cache)
-        dt = (time.perf_counter() - t0) * 1000.0
-        self.prefill_ms += dt
-        st.prefill_ms += dt
-        if self.eng._m_prefill_chunk is not None:
-            self.eng._m_prefill_chunk.observe(dt)
-        pf.cursor += len(piece)
-        if self.paged:
-            self._scatter_published(handle, pf)
-        if pf.cursor < len(prefix):
-            return handle, False
-        # prefix complete: land the filled single cache in the row's KV
-        if self.paged:
-            # scatter the staging blocks into freshly allocated arena pages
-            # (the aliased prefix blocks are already in place) and publish
-            # the fully-covered ones to the radix tree
-            self._finish_pages(handle, pf.prompt, staging=pf.cache)
-        else:
-            pool, row = self._where[handle]
-            pool.cache = self.eng._batch_cache_insert(
-                pool.cache, pf.cache, jnp.int32(row))
-        del self._prefills[handle]
-        st.prefilling = False
-        self._go_live(handle, pf.prompt, pf.scfg)
-        if self.eng._m_prefill is not None:
-            self.eng._m_prefill.observe(st.prefill_ms)
-        return handle, True
+        with observability.phase("prefill_dispatch", "engine",
+                                 span_id=st.span_id) as dispatch:
+            prefix = pf.prompt[:-1]
+            n = budget if budget is not None else self.prefill_chunk
+            if n <= 0:
+                n = len(prefix) - pf.cursor
+            piece = prefix[pf.cursor:pf.cursor + n]
+            _, pf.cache = self.eng._prefill_piece(pf.cache, piece, pf.cursor)
+        with observability.phase("prefill_wait", "engine", "device",
+                                 span_id=st.span_id) as wait:
+            jax.block_until_ready(pf.cache)
+        # the piece's wall time IS its two phases: one pair of clock reads
+        dt = (wait.t1 - dispatch.t0) * 1000.0
+        with observability.phase("prefill_land", "engine",
+                                 span_id=st.span_id) as last:
+            self.prefill_ms += dt
+            st.prefill_ms += dt
+            if self.eng._m_prefill_chunk is not None:
+                self.eng._m_prefill_chunk.observe(dt)
+            pf.cursor += len(piece)
+            if self.paged:
+                self._scatter_published(handle, pf)
+            finished = pf.cursor >= len(prefix)
+            if finished:
+                # prefix complete: land the filled single cache in the row's KV
+                if self.paged:
+                    # scatter the staging blocks into freshly allocated arena
+                    # pages (the aliased prefix blocks are already in place)
+                    # and publish the fully-covered ones to the radix tree
+                    self._finish_pages(handle, pf.prompt, staging=pf.cache)
+                else:
+                    pool, row = self._where[handle]
+                    pool.cache = self.eng._batch_cache_insert(
+                        pool.cache, pf.cache, jnp.int32(row))
+                del self._prefills[handle]
+                st.prefilling = False
+        if finished:
+            # its PRNGKey is a device program and a sync of its own
+            with observability.phase("go_live", "engine",
+                                     span_id=st.span_id) as last:
+                self._go_live(handle, pf.prompt, pf.scfg)
+                if self.eng._m_prefill is not None:
+                    self.eng._m_prefill.observe(st.prefill_ms)
+        self.piece_span = (dispatch.t0, last.t1)
+        return handle, finished
 
     def _scatter_published(self, handle: int, pf: _PendingPrefill) -> None:
         """Land the staging cache's newly completed full blocks in their
@@ -2913,47 +2943,59 @@ class BatchSession:
             ctx = todo[0]
             stepped.add(ctx)
             pool = self._pools[ctx]
-            if ctx < S:
-                # migrate rows that would outgrow this slab within the
-                # chunk; rows finishing inside it stay (their writes fit
-                # and nothing reads past them afterwards)
-                for r in range(pool.cap):
-                    h = pool.rows[r]
-                    if h is None:
-                        continue
-                    st = self._slots[h]
-                    if st.done or st.prefilling:
-                        continue
-                    useful = min(self.chunk, st.budget - st.emitted)
-                    p = int(pool.pos[r])
-                    if ((useful >= self.chunk and p + self.chunk >= ctx)
-                            or (useful < self.chunk and p + useful > ctx)):
-                        self._migrate(h)
-            live = [r for r in range(pool.cap)
-                    if pool.rows[r] is not None
-                    and not self._slots[pool.rows[r]].done
-                    and not self._slots[pool.rows[r]].prefilling]
+            with observability.phase("decode_prepare", "engine"):
+                if ctx < S:
+                    # migrate rows that would outgrow this slab within the
+                    # chunk; rows finishing inside it stay (their writes fit
+                    # and nothing reads past them afterwards)
+                    for r in range(pool.cap):
+                        h = pool.rows[r]
+                        if h is None:
+                            continue
+                        st = self._slots[h]
+                        if st.done or st.prefilling:
+                            continue
+                        useful = min(self.chunk, st.budget - st.emitted)
+                        p = int(pool.pos[r])
+                        if ((useful >= self.chunk and p + self.chunk >= ctx)
+                                or (useful < self.chunk
+                                    and p + useful > ctx)):
+                            self._migrate(h)
+                live = [r for r in range(pool.cap)
+                        if pool.rows[r] is not None
+                        and not self._slots[pool.rows[r]].done
+                        and not self._slots[pool.rows[r]].prefilling]
             if not live:
                 continue
-            t1 = time.perf_counter()
-            chunk, pool.cache, keys, ok = self.eng.batch_loop(len(live))(
-                pool.cache, jnp.asarray(pool.tokens),
-                jnp.asarray(pool.pos), jnp.asarray(pool.keys),
-                jnp.asarray(pool.temps), jnp.asarray(pool.topps),
-                self.eng._poison_rows(pool.cap), n_steps=self.chunk)
-            arr = np.asarray(chunk)  # [chunk, cap]
-            okh = np.asarray(ok)  # [cap]
-            pool.tokens = np.array(chunk[-1])  # np.array: writable copies
-            pool.keys = np.array(keys)
-            # mirror the in-program per-row pin across chunk boundaries
-            pool.pos = np.minimum(pool.pos + self.chunk,
-                                  ctx - 1).astype(np.int32)
-            chunk_ms = (time.perf_counter() - t1) * 1000.0
-            self.decode_ms += chunk_ms
-            if self.eng._m_chunk is not None:
-                self.eng._m_chunk.observe(chunk_ms)
-            self._account_chunk(pool, live, arr, okh, fresh)
+            with observability.phase("decode_dispatch", "engine") as dispatch:
+                chunk, pool.cache, keys, ok = self.eng.batch_loop(len(live))(
+                    pool.cache, jnp.asarray(pool.tokens),
+                    jnp.asarray(pool.pos), jnp.asarray(pool.keys),
+                    jnp.asarray(pool.temps), jnp.asarray(pool.topps),
+                    self.eng._poison_rows(pool.cap), n_steps=self.chunk)
+            with observability.phase("decode_wait", "engine", "device"):
+                arr = np.asarray(chunk)  # [chunk, cap]
+            # reads issued after the program ended: the device idles
+            with observability.phase("decode_fetch", "engine") as fetch:
+                okh = np.asarray(ok)  # [cap]
+                pool.tokens = np.array(chunk[-1])  # np.array: writable copies
+                pool.keys = np.array(keys)
+                # mirror the in-program per-row pin across chunk boundaries
+                pool.pos = np.minimum(pool.pos + self.chunk,
+                                      ctx - 1).astype(np.int32)
+            with observability.phase("account", "engine"):
+                self._observe_chunk(dispatch.t0, fetch.t1, len(live))
+                self._account_chunk(pool, live, arr, okh, fresh)
         return fresh
+
+    def _observe_chunk(self, t0: float, t1: float, live: int) -> None:
+        """One decode launch's wall time (its dispatch, wait and fetch
+        phases, from their own clock reads) and the rows it advanced."""
+        chunk_ms = (t1 - t0) * 1000.0
+        self.decode_ms += chunk_ms
+        if self.eng._m_chunk is not None:
+            self.eng._m_chunk.observe(chunk_ms)
+            self.eng._m_live_rows.observe(live)
 
     def _account_chunk(self, pool, live: list, arr, okh, fresh: dict) -> None:
         """Per-row bookkeeping for one fused chunk's output — shared by the
@@ -2999,20 +3041,21 @@ class BatchSession:
         therefore always allocated before dispatch; only the discarded
         post-finish garbage steps ever land on the scratch page."""
         fresh: dict = {}
-        for h, st in list(self._slots.items()):
-            if st.done or st.prefilling:
-                continue
-            g, r = self._where[h]
-            rp = self._rowpages[h]
-            p = int(g.pos[r])
-            needed = min(p + self.chunk + 1, rp.cap_tokens)
-            while len(rp.blocks) < paged_kv.pages_for(needed, self.page):
-                rp.blocks.append(self._page_alloc(rp))
-            nb = self._nb_for(len(rp.blocks))
-            if nb > g.nb:
-                self._regroup(h, nb)
-            else:
-                self._sync_table(h)
+        with observability.phase("decode_prepare", "engine"):
+            for h, st in list(self._slots.items()):
+                if st.done or st.prefilling:
+                    continue
+                g, r = self._where[h]
+                rp = self._rowpages[h]
+                p = int(g.pos[r])
+                needed = min(p + self.chunk + 1, rp.cap_tokens)
+                while len(rp.blocks) < paged_kv.pages_for(needed, self.page):
+                    rp.blocks.append(self._page_alloc(rp))
+                nb = self._nb_for(len(rp.blocks))
+                if nb > g.nb:
+                    self._regroup(h, nb)
+                else:
+                    self._sync_table(h)
         for nb in sorted(self._pgroups):
             g = self._pgroups[nb]
             live = [r for r in range(g.cap)
@@ -3022,24 +3065,24 @@ class BatchSession:
             if not live:
                 continue
             W = nb * self.page
-            t1 = time.perf_counter()
-            chunk, self._arena, keys, ok = self.eng.paged_loop(len(live))(
-                self._arena, jnp.asarray(g.tables),
-                jnp.asarray(g.tokens), jnp.asarray(g.pos),
-                jnp.asarray(g.keys), jnp.asarray(g.temps),
-                jnp.asarray(g.topps), self.eng._poison_rows(g.cap),
-                n_steps=self.chunk)
-            arr = np.asarray(chunk)  # [chunk, cap]
-            okh = np.asarray(ok)  # [cap]
-            g.tokens = np.array(chunk[-1])
-            g.keys = np.array(keys)
-            # mirror the in-program per-row pin across chunk boundaries
-            g.pos = np.minimum(g.pos + self.chunk, W - 1).astype(np.int32)
-            chunk_ms = (time.perf_counter() - t1) * 1000.0
-            self.decode_ms += chunk_ms
-            if self.eng._m_chunk is not None:
-                self.eng._m_chunk.observe(chunk_ms)
-            self._account_chunk(g, live, arr, okh, fresh)
+            with observability.phase("decode_dispatch", "engine") as dispatch:
+                chunk, self._arena, keys, ok = self.eng.paged_loop(len(live))(
+                    self._arena, jnp.asarray(g.tables),
+                    jnp.asarray(g.tokens), jnp.asarray(g.pos),
+                    jnp.asarray(g.keys), jnp.asarray(g.temps),
+                    jnp.asarray(g.topps), self.eng._poison_rows(g.cap),
+                    n_steps=self.chunk)
+            with observability.phase("decode_wait", "engine", "device"):
+                arr = np.asarray(chunk)  # [chunk, cap]
+            with observability.phase("decode_fetch", "engine") as fetch:
+                okh = np.asarray(ok)  # [cap]
+                g.tokens = np.array(chunk[-1])
+                g.keys = np.array(keys)
+                # mirror the in-program per-row pin across chunk boundaries
+                g.pos = np.minimum(g.pos + self.chunk, W - 1).astype(np.int32)
+            with observability.phase("account", "engine"):
+                self._observe_chunk(dispatch.t0, fetch.t1, len(live))
+                self._account_chunk(g, live, arr, okh, fresh)
         return fresh
 
     def cancel(self, slot: int) -> None:

@@ -646,6 +646,208 @@ class Batcher:
         self._resolve_err(s, err)
         return True
 
+    def _reap_admit(self, sess, stop_ids, waiting: list, slot_map: dict,
+                    preempted: list) -> None:
+        """The head of a scheduler tick (its ``reap_admit`` phase): resolve
+        dead waiters and rows, then admit from ``waiting`` into free slots,
+        lane by lane, preempting batch rows for interactive work. The three
+        collections are updated in place: whatever raises here, the caller
+        fails exactly the slots they still hold."""
+        # lifecycle reap, BETWEEN chunks: a cancelled (client gone)
+        # or deadline-expired row is released NOW — its slab goes to
+        # the next waiter this very loop pass — and dead waiters
+        # never occupy a slot at all (a mid-prefill row's half-built
+        # cache is dropped the same way). Parked preempted rows reap
+        # identically: a batch client that gave up while parked
+        # resolves here instead of being pointlessly re-admitted.
+        waiting[:] = [s for s in waiting if not self._reap_slot(s)]
+        preempted[:] = [s for s in preempted if not self._reap_slot(s)]
+        # pressure dropped (no interactive work queued): move every
+        # parked batch row back to the FRONT of the line — resumed
+        # work outranks new batch arrivals (it already paid for its
+        # decoded prefix once)
+        if preempted and not any(s.slo_class == "interactive"
+                                 for s in waiting):
+            waiting[:0] = preempted
+            del preempted[:]
+        for b in list(slot_map):
+            s = slot_map[b]
+            err = s.lifecycle_error()
+            if err is not None:
+                sess.cancel(b)
+                sess.release(b)
+                del slot_map[b]
+                self._resolve_err(s, err)
+        # paged sessions get the actual tokens so admission counts
+        # the radix prefix match (a warm prompt needs fewer pages)
+        while waiting:
+            # per-class lanes: interactive admits first (FIFO
+            # within a lane); a batch waiter additionally honors
+            # its lane's max_resident cap. Import jobs (disagg
+            # migrations, preempted resumes) skip the cap — a
+            # migration refused residency would fail the transfer.
+            resident: dict = {}
+            for sl in slot_map.values():
+                resident[sl.slo_class] = \
+                    resident.get(sl.slo_class, 0) + 1
+            pick = None
+            for lane in SLO_CLASSES:
+                cap = self._class_resident_cap(lane)
+                for i, w in enumerate(waiting):
+                    if w.slo_class != lane:
+                        continue
+                    if (w.kind != "import" and cap
+                            and resident.get(lane, 0) >= cap):
+                        break  # lane at its residency cap (FIFO
+                        #        holds: no later same-lane waiter
+                        #        may jump the capped head)
+                    pick = i
+                    break
+                if pick is not None:
+                    break
+            if pick is None:
+                break  # every lane capped out this tick
+            s = waiting[pick]
+            if s.kind == "import":
+                # migrated row arriving: admit it warm from its
+                # export snapshot NOW — no can_admit wait (a full
+                # pool must fail fast so the router can fall back
+                # to re-prefilling, not queue behind cold prompts).
+                # A preempted row coming back rides the same path,
+                # but a failed RE-admission re-parks it (retry next
+                # tick) instead of failing the client.
+                waiting.pop(pick)
+                resumed = s.preempted
+                if not resumed:
+                    s.mark_start("import")
+                    self._m_path.inc(path="import")
+                try:
+                    b = sess.admit_from_export(s.prompt, s.snap)
+                except Exception as e:  # noqa: BLE001 — this row
+                    if resumed:
+                        self._m_preemptions.inc(outcome="retry")
+                        preempted.append(s)
+                        break  # no room this tick; decode on
+                    self.state._m_kv_imports.inc(outcome="error")
+                    self._fail([s], e)
+                    continue
+                if resumed:
+                    s.preempted = False
+                    self._m_preemptions.inc(outcome="resumed")
+                else:
+                    self.state._m_kv_imports.inc(outcome="ok")
+                    s.tokens = []
+                s.snap = None  # free the page payloads now
+                slot_map[b] = s
+                continue
+            if not sess.can_admit(len(s.prompt), s.steps, s.prompt):
+                # pool full for the highest-priority waiter: an
+                # interactive one reclaims batch residency at this
+                # very chunk boundary and retries immediately
+                if (s.slo_class == "interactive"
+                        and self._preempt_one(sess, slot_map,
+                                              preempted)):
+                    continue
+                break
+            waiting.pop(pick)
+            path = ("prefill" if s.kind == "prefill"
+                    else "continuous")
+            s.mark_start(path)
+            self._m_path.inc(path=path)
+            pre_admit_ms = sess.prefill_ms
+            try:
+                if self.prefill_chunk > 0:
+                    # chunked admission: reserve the row now, feed
+                    # the prompt one prefill_step per tick below —
+                    # resident rows keep decoding in between
+                    b = sess.admit_begin(
+                        s.prompt, s.steps, sampler=s.sampler,
+                        stop_tokens=stop_ids,
+                        span_id=(s.trace.span_id if s.trace is not None
+                                 else 0))
+                else:
+                    b = sess.admit(s.prompt, s.steps,
+                                   sampler=s.sampler,
+                                   stop_tokens=stop_ids)
+            except Exception as e:  # noqa: BLE001 — this row only
+                self._fail([s], e)
+                continue
+            if self.prefill_chunk <= 0:
+                s.mark_prefill(sess.prefill_ms - pre_admit_ms)
+            s.tokens = []
+            slot_map[b] = s
+
+    def _stream_out(self, sess, slot_map: dict, fresh: dict) -> None:
+        """The tail of a scheduler tick (its ``stream_out`` phase): hand
+        every live row's fresh burst to its waiter, release rows that
+        finished, export or checkpoint where the slot asks for it."""
+        for b, burst in fresh.items():
+            s = slot_map[b]
+            s.tokens.extend(burst)
+            if burst:
+                s.mark_token()
+            if s.queue is not None and burst:
+                s.queue.put(burst)
+            if sess.is_done(b):
+                # free the slab NOW — the next waiter admits into
+                # it on this very loop pass
+                quarantined = sess.finish_reason(b) == "error"
+                sess.release(b)
+                del slot_map[b]
+                if quarantined:
+                    # numeric-health quarantine: THIS row's logits
+                    # went non-finite; its waiter gets the typed
+                    # error (500 / finish_reason "error"), siblings
+                    # decode on bit-identically
+                    self._resolve_err(s, NumericHealthError(
+                        "in pooled decode row; row quarantined"))
+                    continue
+                if s.queue is not None:
+                    s.queue.put(None)
+                s.done.set()
+            elif s.kind == "prefill":
+                # first chunk after go-live and the row is NOT done:
+                # migrate now — snapshot its pages + decode state,
+                # free the slot, and hand the snapshot (plus the
+                # chunk's already-emitted tokens) to the exporting
+                # HTTP handler. A faulted/failed export frees the
+                # slot the same way and fails THIS waiter only.
+                try:
+                    snap = sess.export_row(b)
+                except Exception as e:  # noqa: BLE001
+                    self.state._m_kv_exports.inc(outcome="error")
+                    sess.cancel(b)
+                    sess.release(b)
+                    del slot_map[b]
+                    self._fail([s], e)
+                    continue
+                self.state._m_kv_exports.inc(outcome="ok")
+                sess.release(b)
+                del slot_map[b]
+                s.export = snap
+                s.done.set()
+            elif s.ckpt_every > 0 and s.queue is not None and burst:
+                # mid-stream failover checkpoint, taken AT the
+                # chunk boundary (so it lines up with an SSE event
+                # boundary downstream) and pushed THROUGH the
+                # queue: the writer attaches its rendering state
+                # at exactly the point the snapshot describes. The
+                # row stays live — a failed write is a skipped
+                # checkpoint (shorter resume coverage), never a
+                # stream error.
+                s.since_ckpt += len(burst)
+                if s.since_ckpt >= s.ckpt_every:
+                    s.since_ckpt = 0
+                    try:
+                        faults.fire("ckpt_write")
+                        snap = sess.export_row(b, fire_fault=False)
+                    except Exception:  # noqa: BLE001
+                        self.state._m_ckpt_writes.inc(
+                            outcome="error")
+                    else:
+                        self.state._m_ckpt_writes.inc(outcome="ok")
+                        s.queue.put(("ckpt", snap))
+
     def _serve_continuous(self, batch: list) -> None:
         """THE continuous path: open a slot-pool session, admit ``batch``
         into free slots, and between every fused chunk (a) stream each live
@@ -684,224 +886,46 @@ class Batcher:
             with self._lock:
                 self._active_sess = sess
             while waiting or slot_map or preempted:
-                # lifecycle reap, BETWEEN chunks: a cancelled (client gone)
-                # or deadline-expired row is released NOW — its slab goes to
-                # the next waiter this very loop pass — and dead waiters
-                # never occupy a slot at all (a mid-prefill row's half-built
-                # cache is dropped the same way). Parked preempted rows reap
-                # identically: a batch client that gave up while parked
-                # resolves here instead of being pointlessly re-admitted.
-                waiting = [s for s in waiting if not self._reap_slot(s)]
-                preempted = [s for s in preempted if not self._reap_slot(s)]
-                # pressure dropped (no interactive work queued): move every
-                # parked batch row back to the FRONT of the line — resumed
-                # work outranks new batch arrivals (it already paid for its
-                # decoded prefix once)
-                if preempted and not any(s.slo_class == "interactive"
-                                         for s in waiting):
-                    waiting = preempted + waiting
-                    preempted = []
-                for b in list(slot_map):
-                    s = slot_map[b]
-                    err = s.lifecycle_error()
-                    if err is not None:
-                        sess.cancel(b)
-                        sess.release(b)
-                        del slot_map[b]
-                        self._resolve_err(s, err)
-                # paged sessions get the actual tokens so admission counts
-                # the radix prefix match (a warm prompt needs fewer pages)
-                while waiting:
-                    # per-class lanes: interactive admits first (FIFO
-                    # within a lane); a batch waiter additionally honors
-                    # its lane's max_resident cap. Import jobs (disagg
-                    # migrations, preempted resumes) skip the cap — a
-                    # migration refused residency would fail the transfer.
-                    resident: dict = {}
-                    for sl in slot_map.values():
-                        resident[sl.slo_class] = \
-                            resident.get(sl.slo_class, 0) + 1
-                    pick = None
-                    for lane in SLO_CLASSES:
-                        cap = self._class_resident_cap(lane)
-                        for i, w in enumerate(waiting):
-                            if w.slo_class != lane:
-                                continue
-                            if (w.kind != "import" and cap
-                                    and resident.get(lane, 0) >= cap):
-                                break  # lane at its residency cap (FIFO
-                                #        holds: no later same-lane waiter
-                                #        may jump the capped head)
-                            pick = i
-                            break
-                        if pick is not None:
-                            break
-                    if pick is None:
-                        break  # every lane capped out this tick
-                    s = waiting[pick]
-                    if s.kind == "import":
-                        # migrated row arriving: admit it warm from its
-                        # export snapshot NOW — no can_admit wait (a full
-                        # pool must fail fast so the router can fall back
-                        # to re-prefilling, not queue behind cold prompts).
-                        # A preempted row coming back rides the same path,
-                        # but a failed RE-admission re-parks it (retry next
-                        # tick) instead of failing the client.
-                        waiting.pop(pick)
-                        resumed = s.preempted
-                        if not resumed:
-                            s.mark_start("import")
-                            self._m_path.inc(path="import")
-                        try:
-                            b = sess.admit_from_export(s.prompt, s.snap)
-                        except Exception as e:  # noqa: BLE001 — this row
-                            if resumed:
-                                self._m_preemptions.inc(outcome="retry")
-                                preempted.append(s)
-                                break  # no room this tick; decode on
-                            self.state._m_kv_imports.inc(outcome="error")
-                            self._fail([s], e)
-                            continue
-                        if resumed:
-                            s.preempted = False
-                            self._m_preemptions.inc(outcome="resumed")
-                        else:
-                            self.state._m_kv_imports.inc(outcome="ok")
-                            s.tokens = []
-                        s.snap = None  # free the page payloads now
-                        slot_map[b] = s
-                        continue
-                    if not sess.can_admit(len(s.prompt), s.steps, s.prompt):
-                        # pool full for the highest-priority waiter: an
-                        # interactive one reclaims batch residency at this
-                        # very chunk boundary and retries immediately
-                        if (s.slo_class == "interactive"
-                                and self._preempt_one(sess, slot_map,
-                                                      preempted)):
-                            continue
-                        break
-                    waiting.pop(pick)
-                    path = ("prefill" if s.kind == "prefill"
-                            else "continuous")
-                    s.mark_start(path)
-                    self._m_path.inc(path=path)
-                    pre_admit_ms = sess.prefill_ms
-                    try:
-                        if self.prefill_chunk > 0:
-                            # chunked admission: reserve the row now, feed
-                            # the prompt one prefill_step per tick below —
-                            # resident rows keep decoding in between
-                            b = sess.admit_begin(
-                                s.prompt, s.steps, sampler=s.sampler,
-                                stop_tokens=stop_ids)
-                        else:
-                            b = sess.admit(s.prompt, s.steps,
-                                           sampler=s.sampler,
-                                           stop_tokens=stop_ids)
-                    except Exception as e:  # noqa: BLE001 — this row only
-                        self._fail([s], e)
-                        continue
-                    if self.prefill_chunk <= 0:
-                        s.mark_prefill(sess.prefill_ms - pre_admit_ms)
-                    s.tokens = []
-                    slot_map[b] = s
-                # ONE incremental prefill piece per tick (FIFO): the oldest
-                # pending prompt advances by <= prefill_chunk tokens, so
-                # every resident row's inter-token gap is bounded by one
-                # prefill chunk + one decode chunk instead of a whole
-                # monolithic prompt
-                if self.prefill_chunk > 0:
-                    t_pf = time.monotonic()
-                    adv = sess.prefill_step()
-                    if adv is not None:
-                        b, finished = adv
-                        s = slot_map.get(b)
-                        if s is not None:
-                            s.mark_prefill_chunk(t_pf, time.monotonic())
-                            if finished:
-                                s.mark_prefill(sess.prefill_ms_of(b))
-                self._publish_class_stats(waiting, slot_map, preempted)
-                if slot_map:
-                    self._m_occupancy.observe(float(len(slot_map)))
-                    # the black box keeps the in-flight request ids per
-                    # tick: a replica killed mid-decode dumps a ring whose
-                    # last events say exactly whose work died with it
-                    st.flight.record(
-                        "chunk_tick", rows=len(slot_map),
-                        requests=[s.trace.request_id
-                                  for s in slot_map.values()
-                                  if s.trace is not None][:8])
-                for b, burst in sess.step_chunk().items():
-                    s = slot_map[b]
-                    s.tokens.extend(burst)
-                    if burst:
-                        s.mark_token()
-                    if s.queue is not None and burst:
-                        s.queue.put(burst)
-                    if sess.is_done(b):
-                        # free the slab NOW — the next waiter admits into
-                        # it on this very loop pass
-                        quarantined = sess.finish_reason(b) == "error"
-                        sess.release(b)
-                        del slot_map[b]
-                        if quarantined:
-                            # numeric-health quarantine: THIS row's logits
-                            # went non-finite; its waiter gets the typed
-                            # error (500 / finish_reason "error"), siblings
-                            # decode on bit-identically
-                            self._resolve_err(s, NumericHealthError(
-                                "in pooled decode row; row quarantined"))
-                            continue
-                        if s.queue is not None:
-                            s.queue.put(None)
-                        s.done.set()
-                    elif s.kind == "prefill":
-                        # first chunk after go-live and the row is NOT done:
-                        # migrate now — snapshot its pages + decode state,
-                        # free the slot, and hand the snapshot (plus the
-                        # chunk's already-emitted tokens) to the exporting
-                        # HTTP handler. A faulted/failed export frees the
-                        # slot the same way and fails THIS waiter only.
-                        try:
-                            snap = sess.export_row(b)
-                        except Exception as e:  # noqa: BLE001
-                            self.state._m_kv_exports.inc(outcome="error")
-                            sess.cancel(b)
-                            sess.release(b)
-                            del slot_map[b]
-                            self._fail([s], e)
-                            continue
-                        self.state._m_kv_exports.inc(outcome="ok")
-                        sess.release(b)
-                        del slot_map[b]
-                        s.export = snap
-                        s.done.set()
-                    elif s.ckpt_every > 0 and s.queue is not None and burst:
-                        # mid-stream failover checkpoint, taken AT the
-                        # chunk boundary (so it lines up with an SSE event
-                        # boundary downstream) and pushed THROUGH the
-                        # queue: the writer attaches its rendering state
-                        # at exactly the point the snapshot describes. The
-                        # row stays live — a failed write is a skipped
-                        # checkpoint (shorter resume coverage), never a
-                        # stream error.
-                        s.since_ckpt += len(burst)
-                        if s.since_ckpt >= s.ckpt_every:
-                            s.since_ckpt = 0
+                with observability.tick():
+                    with observability.phase("reap_admit", "scheduler"):
+                        self._reap_admit(sess, stop_ids, waiting, slot_map,
+                                         preempted)
+                    # ONE incremental prefill piece per tick (FIFO): the
+                    # oldest pending prompt advances by <= prefill_chunk
+                    # tokens, so every resident row's inter-token gap is
+                    # bounded by one prefill chunk + one decode chunk instead
+                    # of a whole monolithic prompt
+                    adv = (sess.prefill_step() if self.prefill_chunk > 0
+                           else None)
+                    with observability.phase("publish", "scheduler"):
+                        if adv is not None:
+                            b, finished = adv
+                            s = slot_map.get(b)
+                            if s is not None:
+                                s.mark_prefill_chunk(*sess.piece_span)
+                                if finished:
+                                    s.mark_prefill(sess.prefill_ms_of(b))
+                        self._publish_class_stats(waiting, slot_map, preempted)
+                        if slot_map:
+                            self._m_occupancy.observe(float(len(slot_map)))
+                            # the black box keeps the in-flight request ids
+                            # per tick: a replica killed mid-decode dumps a
+                            # ring whose last events say exactly whose work
+                            # died with it
+                            st.flight.record(
+                                "chunk_tick", rows=len(slot_map),
+                                requests=[s.trace.request_id
+                                          for s in slot_map.values()
+                                          if s.trace is not None][:8])
+                    fresh = sess.step_chunk()
+                    with observability.phase("stream_out", "scheduler"):
+                        self._stream_out(sess, slot_map, fresh)
+                    with observability.phase("arrivals", "scheduler"):
+                        while True:  # rolling admission: mid-chunk arrivals
                             try:
-                                faults.fire("ckpt_write")
-                                snap = sess.export_row(b, fire_fault=False)
-                            except Exception:  # noqa: BLE001
-                                self.state._m_ckpt_writes.inc(
-                                    outcome="error")
-                            else:
-                                self.state._m_ckpt_writes.inc(outcome="ok")
-                                s.queue.put(("ckpt", snap))
-                while True:  # rolling admission: drain mid-chunk arrivals
-                    try:
-                        waiting.append(self._arrivals.get_nowait())
-                    except queue_mod.Empty:
-                        break
+                                waiting.append(self._arrivals.get_nowait())
+                            except queue_mod.Empty:
+                                break
         except Exception as e:  # noqa: BLE001 — every waiter gets a 500
             self._fail(list(slot_map.values()) + waiting + preempted, e)
             # a session that threw mid-window is suspect: never keep it
@@ -952,7 +976,6 @@ class Batcher:
             faults.fire("scheduler")
             window = [s for s in window if not self._reap_slot(s)]
             if window:
-                t_win = time.monotonic()
                 # disaggregation jobs (prefill-export / import-admit) and
                 # checkpointing streams exist only in the paged slot pool:
                 # they never route solo or spec. Batch-class rows route
@@ -962,7 +985,12 @@ class Batcher:
                 plain = all(s.kind == "completion" and not s.ckpt_every
                             and s.slo_class == "interactive"
                             for s in window)
-                with self.state.lock:  # the engine serves one pool at a time
+                # one span per routed window on the scheduler track (tid 0);
+                # request tracks (allocated span ids) group right under it.
+                # The engine serves one pool at a time (state.lock).
+                with observability.phase(
+                        "scheduler_window", window=len(window)) as routed, \
+                        self.state.lock:
                     if plain and len(window) == 1 and self._arrivals.empty():
                         self._serve_solo(window[0])
                     elif (plain and len(window) <= self.max_batch
@@ -974,12 +1002,10 @@ class Batcher:
                         self._serve_spec(window)
                     else:
                         self._serve_continuous(window)
-                # one span per routed window on the scheduler track (tid 0);
-                # request tracks (allocated span ids) group right under it
                 observability.emit_trace_events([
                     observability.scheduler_trace_event(
-                        "scheduler_window", t_win, time.monotonic(),
-                        {"window": len(window)})])
+                        "scheduler_window", routed.t0, routed.t1,
+                        routed.args)])
             with self._lock:
                 self._window = []
 
@@ -1287,6 +1313,11 @@ class ServerState:
         self._m_queue_wait = reg.histogram(
             "dllama_queue_wait_ms",
             "Arrival-to-scheduling wait (admission + batching window)")
+        self._m_turn_wait = reg.histogram(
+            "dllama_prefill_turn_wait_ms",
+            "Admission to the start of the request's first prefill piece: "
+            "its wait for a turn behind the other rows' pieces (0 for a "
+            "request admitted without chunked prefill)")
         # per-SLO-class latency series: the workload harness's per-class
         # SLO gates (and `cli top`'s lane view) read these off the
         # federated /metrics/fleet
@@ -1607,6 +1638,8 @@ class ServerState:
             self._m_class_tpot.observe(trace.tpot_ms, slo_class=slo_class)
         if trace.queue_wait_ms is not None:
             self._m_queue_wait.observe(trace.queue_wait_ms)
+        if trace.prefill_turn_wait_ms is not None:
+            self._m_turn_wait.observe(trace.prefill_turn_wait_ms)
         if trace.tokens_in:
             self._m_tokens_in.inc(trace.tokens_in)
             self._m_prompt_hist.observe(float(trace.tokens_in))
@@ -1991,6 +2024,8 @@ class OpenAIHandler(BaseHTTPRequestHandler):
         #: frames excluded, so the count matches what the ROUTER forwards
         #: and the resume splice is pure byte arithmetic
         bytes_emitted = 0
+        #: the request's track, on every socket write's profiler span
+        span = {} if trace is None else {"span_id": trace.span_id}
 
         def emit_frame(frame: bytes, fire: bool = True) -> None:
             nonlocal client_gone, bytes_emitted
@@ -1999,8 +2034,9 @@ class OpenAIHandler(BaseHTTPRequestHandler):
             try:
                 if fire:
                     faults.fire("stream")
-                self.wfile.write(frame)
-                self.wfile.flush()
+                with observability.phase("sse_write", **span):
+                    self.wfile.write(frame)
+                    self.wfile.flush()
                 if fire:  # ckpt control frames are stripped by the
                     #       router, so they never count toward the
                     #       client-visible splice offset
@@ -2333,9 +2369,10 @@ class OpenAIHandler(BaseHTTPRequestHandler):
                 chunk = dict(base, object="chat.completion.chunk",
                              choices=[{"index": 0, "delta": delta,
                                        "finish_reason": finish}])
-                self.wfile.write(b"data: " + json.dumps(chunk).encode()
-                                 + b"\n\n")
-                self.wfile.flush()
+                frame = b"data: " + json.dumps(chunk).encode() + b"\n\n"
+                with observability.phase("sse_write", span_id=trace.span_id):
+                    self.wfile.write(frame)
+                    self.wfile.flush()
             except (BrokenPipeError, ConnectionResetError,
                     faults.FaultInjected):
                 # dead socket: stop decoding at the next token boundary but

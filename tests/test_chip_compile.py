@@ -195,6 +195,102 @@ def test_whole_7b_decode_step_compiles_for_four_chips(four_chips, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# the names the benchmark's trace readers depend on
+# ---------------------------------------------------------------------------
+
+#: small widths: a name does not depend on them, and these compile in seconds
+_NAMED = dict(dim=512, hidden_dim=1024, n_layers=2, n_heads=4, n_kv_heads=2,
+              vocab_size=1024, seq_len=256, head_size=128, kv_dim=256,
+              dtype="bfloat16")
+_DENSE = {"wqkv", "wo", "w13", "w2", "wcls"}
+_SPARSE = {"wqkv", "wo", "expert_upgate", "expert_down", "wcls"}
+
+
+def _kernel_names(compiled) -> list:
+    """The instruction name of every Pallas custom call, without its number
+    (what ``benchmarks/trace_reduce.short_name`` keeps of an ``XLA Ops``
+    event)."""
+    import re
+
+    return [re.sub(r"[.\d]+$", "", line.split(" = ", 1)[0].split("%")[-1])
+            for line in compiled.as_text().splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("arch,projections", [
+    ("llama", _DENSE), ("mixtral", _SPARSE)])
+def test_custom_calls_are_named_by_projection(one_chip, monkeypatch, arch,
+                                              projections, program):
+    """Every q40 kernel of the served step (fused layout, pooled decode and
+    one prefill piece) carries its projection's name, main kernel and
+    correction kernel apart, and none is left under a name two projections
+    share (``q40_matmul_stacked``, the enclosing jitted function's): a
+    device trace then splits kernel time by projection. The names end in a
+    letter, because the reduction strips a trailing number."""
+    monkeypatch.setattr(qmatmul, "_interpret_default", lambda: False)
+    moe = dict(n_experts=8, n_active_experts=2) if arch == "mixtral" else {}
+    cfg = ModelConfig(arch=arch, **_NAMED, **moe)
+    params = jax.eval_shape(
+        lambda k: llama.fuse_qkv_ffn(llama._quant_init(k, cfg, "q40")), _key())
+    rope = jax.eval_shape(lambda: llama.rope_tables(cfg))
+    if program == "decode":
+        rows = 8
+        cache = jax.eval_shape(
+            lambda: llama.init_batch_cache(cfg, rows, jnp.bfloat16))
+        tokens, pos = (_s((rows,), jnp.int32, one_chip),) * 2
+        fwd = llama.forward_batched
+    else:
+        cache = jax.eval_shape(lambda: llama.init_cache(cfg, jnp.bfloat16))
+        tokens, pos = _s((64,), jnp.int32, one_chip), _s((), jnp.int32, one_chip)
+        fwd = llama.forward
+
+    def step(params, rope, cache, tokens, pos):
+        return fwd(cfg, params, rope, tokens, cache, pos)
+
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+        _shapes(params, one_chip), _shapes(rope, one_chip),
+        _shapes(cache, one_chip), tokens, pos).compile()
+    names = _kernel_names(compiled)
+    want = ({p + "_q40_matmul" for p in projections}
+            | {p + "_q40_corr" for p in projections})
+    assert set(names) == want
+    assert not any(n[-1].isdigit() for n in names)
+    text = compiled.as_text()
+    for scope in ("attention", "moe" if moe else "ffn", "kv_slab_read",
+                  "kv_slab_write"):
+        assert f"/{scope}/" in text, scope
+
+
+def test_the_served_programs_keep_the_names_the_readers_match():
+    """``jit__decode_loop_batch`` and ``jit__prefill``: what the three
+    trace readers' ``decode_module`` / ``prefill_module`` patterns
+    (``^jit__decode_loop``, ``^jit__prefill$``) find in ``XLA Modules``.
+    Lowered from a real (tiny) Engine on the CPU: a module's name does not
+    depend on the backend. The sampler's scope is inside the decode loop."""
+    from dllama_tpu.runtime.generate import Engine
+    from dllama_tpu.runtime.sampler import SamplerConfig
+
+    cfg = ModelConfig(arch="llama", dim=64, hidden_dim=96, n_layers=2,
+                      n_heads=4, n_kv_heads=2, vocab_size=128, seq_len=32,
+                      head_size=16, kv_dim=32)
+    eng = Engine(cfg, llama.random_params(cfg, seed=0),
+                 SamplerConfig(temperature=0.0, seed=0))
+    rows = 2
+    decode = eng._decode_loop_batch.func.lower(
+        eng.params, eng.rope, llama.init_batch_cache(cfg, rows),
+        jnp.zeros(rows, jnp.int32), jnp.zeros(rows, jnp.int32),
+        jnp.zeros((rows, 2), jnp.uint32), jnp.zeros(rows, jnp.float32),
+        jnp.ones(rows, jnp.float32), jnp.zeros(rows, jnp.bool_), n_steps=2)
+    prefill = eng._prefill.func.lower(
+        eng.params, eng.rope, llama.init_cache(cfg),
+        jnp.zeros(8, jnp.int32), 3, jnp.int32(0))
+    assert "module @jit__decode_loop_batch " in decode.as_text()
+    assert "module @jit__prefill " in prefill.as_text()
+    assert '"sample/' in decode.as_text(debug_info=True)
+
+
+# ---------------------------------------------------------------------------
 # the two opt-in attention kernels: refused today. Strict xfails, so the PR
 # that repairs a kernel finds its test waiting (and failing as XPASS until
 # the marker goes).
