@@ -923,6 +923,38 @@ def init_batch_cache(cfg: ModelConfig, batch: int, cache_dtype=jnp.float32,
     return {"k": jnp.zeros(shape, cache_dtype), "v": jnp.zeros(shape, cache_dtype)}
 
 
+def _write_kv_rows(k_cache, v_cache, k, v, layer, pos):
+    """Land each sequence's new K/V rows in the stacked ``[L, B, S, kv, hd]``
+    caches: ``k``/``v`` are ``[B, T, kv, hd]`` and sequence b's T rows go to
+    ``(layer, b, pos[b]..pos[b]+T)``. One scatter a cache, which XLA runs in
+    place on the donated scan carry: the compiled step writes ``B*T*kv*hd``
+    elements a layer and copies no slab out or back. The start clamps as
+    ``dynamic_update_slice`` clamps, to ``S - T``: a row stepped at
+    ``pos >= S`` lands in the last slot, where free rows pin.
+
+    Keep it a scatter: ``B`` unrolled ``dynamic_update_slice``s, or one
+    vmapped over the row axis, make the v5e compiler carry the whole cache
+    in another layout and turn it there and back around every launch
+    (PERF.md, PR 25)."""
+    B, T = k.shape[:2]
+    rows = jnp.arange(B, dtype=jnp.int32)[:, None]
+    cols = (jnp.clip(pos, 0, k_cache.shape[2] - T)[:, None]
+            + jnp.arange(T, dtype=jnp.int32))
+    with jax.named_scope("kv_slab_write"):
+        return (k_cache.at[layer, rows, cols].set(k.astype(k_cache.dtype)),
+                v_cache.at[layer, rows, cols].set(v.astype(v_cache.dtype)))
+
+
+def _layer_slabs(k_cache, v_cache, layer):
+    """The layer's ``[B, S, kv, hd]`` K and V out of the stacked caches, to
+    be read only: on the v5e this is the one pass over the slab's bytes that
+    full-context attention needs (the slice is staged for the score and value
+    contractions, which then read no HBM again)."""
+    with jax.named_scope("kv_slab_read"):
+        return (jax.lax.dynamic_index_in_dim(k_cache, layer, 0, keepdims=False),
+                jax.lax.dynamic_index_in_dim(v_cache, layer, 0, keepdims=False))
+
+
 @jax.named_scope("attention")
 def _attn_block_batched(cfg: ModelConfig, lp: dict, rope: dict, x, k_cache,
                         v_cache, pos, layer=None, tp_axis=None,
@@ -932,7 +964,10 @@ def _attn_block_batched(cfg: ModelConfig, lp: dict, rope: dict, x, k_cache,
     matmuls (identical to a T=B prefill row block — the quant kernels need
     no batching rule); only rope/cache/attention are per-row, via gather and
     vmap over the pure-jnp attention. Caches are [L, B, S, kv, hd] under the
-    layer scan (``layer`` given) or this layer's [B, S, kv, hd] slab.
+    layer scan (``layer`` given) or this layer's [B, S, kv, hd] slab. Either
+    way the step's B rows of K and V are written where they live, in the
+    scan's donated carry, and attention then reads the layer's slab: no slab
+    is copied out, updated and written back (``_write_kv_rows``).
     ``tp_axis`` (inside shard_map): local heads + kv-shard cache, activation
     gathers after the head concat and the wo matmul, exactly `_attn_block`.
     ``row_mode``: pre-normalized input, K-sharded wo, f32 partial output —
@@ -970,48 +1005,28 @@ def _attn_block_batched(cfg: ModelConfig, lp: dict, rope: dict, x, k_cache,
     else:
         k = apply_rope(k, cos, sin, cfg.rope_style)
 
+    # this step's rows go where they live, in the scan's donated carry,
+    # before whichever attention reads them (write-before-attend)
+    if layer is None:
+        # dense xs-scan: the carry IS this layer's slab
+        with jax.named_scope("kv_slab_write"):
+            write = jax.vmap(
+                lambda c, kk, p: jax.lax.dynamic_update_slice_in_dim(
+                    c, kk[None].astype(c.dtype), p, axis=0))
+            k_cache, v_cache = write(k_cache, k, pos), write(v_cache, v, pos)
+    elif not fused_kv:
+        # layer scan: the stacked cache rides the carry
+        k_cache, v_cache = _write_kv_rows(
+            k_cache, v_cache, k[:, None], v[:, None], layer, pos)
+
     if (layer is not None
             and flash_decode.engages(1, k_cache.shape[2], k_cache.dtype)):
-        # flash path: scatter this step's K/V straight into the stacked
-        # [L, B, S, kv, hd] cache (no slab round-trip at all) and read each
-        # row's OWN live prefix in the kernel. The write position clamps to
-        # the last slot so a row stepped at pos >= seq_len leaves the same
-        # cache contents as the dense path's dynamic_update_slice (which
-        # clamps), instead of the scatter silently dropping the row.
-        if not fused_kv:
-            rows = jnp.arange(B, dtype=jnp.int32)
-            wpos = jnp.clip(pos, 0, k_cache.shape[2] - 1)
-            k_cache = k_cache.at[layer, rows, wpos].set(k.astype(k_cache.dtype))
-            v_cache = v_cache.at[layer, rows, wpos].set(v.astype(v_cache.dtype))
+        # the kernel reads each row's OWN live prefix from the stacked cache
         out = flash_decode.flash_decode_attention_batched(
             q, k_cache, v_cache, pos, layer)  # [B, local heads, hs]
     else:
-        if layer is None:
-            slab_k, slab_v = k_cache, v_cache
-        else:
-            # the pool cache's dynamic-slice: each layer's [B, ctx] K and V
-            # slab is copied out of the stacked cache, every step
-            with jax.named_scope("kv_slab_read"):
-                slab_k = jax.lax.dynamic_index_in_dim(
-                    k_cache, layer, 0, keepdims=False)
-                slab_v = jax.lax.dynamic_index_in_dim(
-                    v_cache, layer, 0, keepdims=False)
-        if not fused_kv:
-            with jax.named_scope("kv_slab_write"):
-                write = jax.vmap(
-                    lambda c, kk, p: jax.lax.dynamic_update_slice_in_dim(
-                        c, kk[None].astype(c.dtype), p, axis=0))
-                slab_k = write(slab_k, k, pos)
-                slab_v = write(slab_v, v, pos)
-                if layer is None:
-                    k_cache, v_cache = slab_k, slab_v
-                else:
-                    zero = (0, 0, 0, 0)
-                    k_cache = jax.lax.dynamic_update_slice(
-                        k_cache, slab_k[None], (layer, *zero))
-                    v_cache = jax.lax.dynamic_update_slice(
-                        v_cache, slab_v[None], (layer, *zero))
-
+        slab_k, slab_v = ((k_cache, v_cache) if layer is None
+                          else _layer_slabs(k_cache, v_cache, layer))
         out = jax.vmap(
             lambda qb, ks, vs, p: gqa_attention(qb[None], ks, vs, p)[0]
         )(q, slab_k, slab_v, pos)  # [B, local heads, hs]
@@ -1319,21 +1334,11 @@ def _verify_layer(cfg: ModelConfig, lp: dict, rope: dict, x, k_cache,
         # to the apply_rope + per-row slab writes below
         k_cache, v_cache = fused_rope_cache.rope_cache_update_verify(
             k, v, cos, sin, k_cache, v_cache, pos, idx, cfg.rope_style)
-        slab_k = jax.lax.dynamic_index_in_dim(k_cache, idx, 0, keepdims=False)
-        slab_v = jax.lax.dynamic_index_in_dim(v_cache, idx, 0, keepdims=False)
     else:
         k = apply_rope(k, cos, sin, cfg.rope_style)
-        slab_k = jax.lax.dynamic_index_in_dim(k_cache, idx, 0, keepdims=False)
-        slab_v = jax.lax.dynamic_index_in_dim(v_cache, idx, 0, keepdims=False)
-        write = jax.vmap(
-            lambda c, kk, p: jax.lax.dynamic_update_slice_in_dim(
-                c, kk.astype(c.dtype), p, axis=0))
-        slab_k = write(slab_k, k, pos)
-        slab_v = write(slab_v, v, pos)
-        zero = (0, 0, 0, 0)
-        k_cache = jax.lax.dynamic_update_slice(k_cache, slab_k[None], (idx, *zero))
-        v_cache = jax.lax.dynamic_update_slice(v_cache, slab_v[None], (idx, *zero))
+        k_cache, v_cache = _write_kv_rows(k_cache, v_cache, k, v, idx, pos)
 
+    slab_k, slab_v = _layer_slabs(k_cache, v_cache, idx)
     out = jax.vmap(gqa_attention)(q, slab_k, slab_v, pos)  # [B, T, H, hd]
     if row_mode:
         # local heads feed the K-sharded wo directly; the partial rides the

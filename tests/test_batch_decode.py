@@ -212,3 +212,136 @@ def test_batched_samplers_wrong_length_rejected():
     with pytest.raises(ValueError):
         eng.generate_batch(PROMPTS, steps=4,
                            samplers=[SamplerConfig(temperature=0.0)])
+
+
+# ---------------------------------------------------------------------------
+# PR 25: the pooled step writes its K/V rows into the stacked cache in place.
+# The form it had before, the layer's slab copied out, updated and written
+# back, stays here as the oracle: same values into the same slots.
+# ---------------------------------------------------------------------------
+
+def _round_trip_write(k_cache, v_cache, k, v, layer, pos):
+    """``llama._write_kv_rows`` as the slab round trip it replaced: copy the
+    layer's [B, S, kv, hd] slab out of the stacked cache, write each
+    sequence's T rows into the copy (a vmapped ``dynamic_update_slice``: its
+    clamp is the contract), write the whole slab back."""
+    import jax
+
+    def one(cache, new):
+        slab = jax.lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)
+        slab = jax.vmap(
+            lambda c, n, p: jax.lax.dynamic_update_slice_in_dim(
+                c, n.astype(c.dtype), p, axis=0))(slab, new, pos)
+        return jax.lax.dynamic_update_slice(cache, slab[None],
+                                            (layer, 0, 0, 0, 0))
+
+    return one(k_cache, k), one(v_cache, v)
+
+
+def _pool_positions(rows: int, ctx: int) -> np.ndarray:
+    """Row 0 walks off the slab's end inside the chunk (``ctx - 3`` onwards:
+    the write clamps to ``ctx - 1``); of the others, every third is a free
+    row pinned at ``ctx - 1``, one starts past the end, the rest are live at
+    positions of their own."""
+    pos = np.full((rows,), ctx - 1, np.int32)
+    pos[0] = ctx - 3
+    for b in range(1, rows):
+        if b % 3 == 1:
+            pos[b] = 2 + 3 * b
+        elif b % 3 == 2:
+            pos[b] = ctx + 5 if b == 2 else 1 + b
+    return pos
+
+
+_F8 = "float8_e4m3fn"
+_POOL_CASES = [
+    # arch, cache dtype, rows, tp
+    ("llama", "float32", 4, 0), ("llama", "bfloat16", 4, 0),
+    ("llama", _F8, 4, 0), ("llama", "float32", 1, 0),
+    ("llama", "bfloat16", 8, 0), ("mixtral", "float32", 4, 0),
+    ("mixtral", "bfloat16", 8, 0), ("mixtral", _F8, 1, 0),
+    ("llama", "float32", 4, 2), ("llama", "bfloat16", 8, 2),
+    ("mixtral", "float32", 4, 2),
+]
+
+
+@pytest.mark.parametrize("arch,cache_dtype,rows,tp", _POOL_CASES, ids=[
+    f"{a}-{d}-B{b}" + (f"-tp{t}" if t else "") for a, d, b, t in _POOL_CASES])
+def test_pooled_steps_leave_the_cache_the_round_trip_left(
+        monkeypatch, arch, cache_dtype, rows, tp):
+    """Six pooled steps (``Engine._decode_loop_batch`` over a bucket slab
+    shorter than the model's context, the quantized layer scan, so the
+    stacked cache rides the carry) through the in-place write and through
+    the round trip: the whole stacked cache, every layer, row and slot, and
+    the tokens are bit for bit the same."""
+    import jax
+    import jax.numpy as jnp
+
+    from dllama_tpu.parallel.mesh import tp_mesh
+
+    cfg = CFG if arch == "llama" else MOE_CFG
+    params = llama.quantize_params(
+        llama.random_params(cfg, seed=4, dtype=np.float32), "q40")
+    ctx, steps = 32, 6
+    pos = _pool_positions(rows, ctx)
+    rng = np.random.default_rng(5)
+    fill = {n: rng.standard_normal(
+        (cfg.n_layers, rows, ctx, cfg.n_kv_heads, cfg.head_size)
+    ).astype(np.float32) for n in ("k", "v")}
+
+    def run():
+        eng = Engine(cfg, params, SamplerConfig(temperature=0.0),
+                     cache_dtype=jnp.dtype(cache_dtype),
+                     mesh=tp_mesh(tp) if tp else None)
+        cache = {n: jax.device_put(jnp.asarray(fill[n]).astype(a.dtype),
+                                   a.sharding)
+                 for n, a in eng._bucket_cache_init(rows, ctx).items()}
+        out, cache, _, ok = eng._decode_loop_batch(
+            cache, jnp.arange(3, 3 + rows, dtype=jnp.int32),
+            jnp.asarray(pos), jnp.zeros((rows, 2), jnp.uint32),
+            jnp.zeros(rows, jnp.float32), jnp.ones(rows, jnp.float32),
+            jnp.zeros(rows, jnp.bool_), n_steps=steps)
+        assert bool(np.all(np.asarray(ok)))
+        return np.asarray(out), {n: np.asarray(a.astype(jnp.float32))
+                                 for n, a in cache.items()}
+
+    toks, cache = run()
+    monkeypatch.setattr(llama, "_write_kv_rows", _round_trip_write)
+    want_toks, want_cache = run()
+    np.testing.assert_array_equal(toks, want_toks)
+    for n in ("k", "v"):
+        np.testing.assert_array_equal(cache[n], want_cache[n])
+        # the step did write: the slots it owns differ from what was there
+        was = np.asarray(jnp.asarray(fill[n]).astype(jnp.dtype(cache_dtype))
+                         .astype(jnp.float32))
+        assert (cache[n][:, 0, ctx - 3:] != was[:, 0, ctx - 3:]).any()
+        # and nothing but: a free row's slots below ctx - 1 are untouched
+        free = [b for b in range(rows) if pos[b] == ctx - 1]
+        for b in free:
+            np.testing.assert_array_equal(cache[n][:, b, :ctx - 1],
+                                          was[:, b, :ctx - 1])
+
+
+@pytest.mark.parametrize("cache_dtype", ["float32", "bfloat16", _F8])
+@pytest.mark.parametrize("T", [1, 3])
+def test_write_kv_rows_clamps_like_the_slab_update(cache_dtype, T):
+    """The helper alone, T rows a sequence (T > 1 is the speculative verify
+    step): starts below, at and past ``S - T`` land where the vmapped
+    ``dynamic_update_slice`` put them, in every layer index."""
+    import jax.numpy as jnp
+
+    L, B, S, kv, hd = 3, 5, 16, 2, 8
+    rng = np.random.default_rng(6)
+    dt = jnp.dtype(cache_dtype)
+    caches = [jnp.asarray(rng.standard_normal((L, B, S, kv, hd)),
+                          jnp.float32).astype(dt) for _ in range(2)]
+    new = [jnp.asarray(rng.standard_normal((B, T, kv, hd)), jnp.float32)
+           for _ in range(2)]
+    pos = jnp.asarray([0, S - T - 1, S - T, S - 1, S + 7], jnp.int32)
+    for layer in range(L):
+        got = llama._write_kv_rows(*caches, *new, jnp.int32(layer), pos)
+        want = _round_trip_write(*caches, *new, jnp.int32(layer), pos)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(
+                np.asarray(g.astype(jnp.float32)),
+                np.asarray(w.astype(jnp.float32)))

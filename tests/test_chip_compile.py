@@ -16,6 +16,7 @@ Nothing here touches ``jax.devices()``: the backend stays the forced CPU.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -195,6 +196,101 @@ def test_whole_7b_decode_step_compiles_for_four_chips(four_chips, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# the pooled decode chunk of the benchmark's Mistral cells: what the K/V write
+# and the slab read compile to (PR 25)
+# ---------------------------------------------------------------------------
+
+#: Mistral-7B-v0.3 (benchmarks/configs/mistral-7b-v0.3-q40.json): 8 KV heads
+CFG_MISTRAL = ModelConfig(
+    arch="llama", dim=4096, hidden_dim=14336, n_layers=32, n_heads=32,
+    n_kv_heads=8, vocab_size=32768, seq_len=4096, head_size=128,
+    kv_dim=1024, dtype="bfloat16",
+)
+_HLO_LINE = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = \(?(\w+)\[([\d,]*)\](\S*) ([\w\-]+)\((.*)$")
+
+
+def _instructions(text: str) -> list:
+    """(name, elements, layout, opcode, rest of the line) of every HLO
+    instruction whose result is one array."""
+    out = []
+    for line in text.splitlines():
+        m = _HLO_LINE.match(line)
+        if m:
+            name, _, dims, layout, op, rest = m.groups()
+            n = int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+            out.append((name, n, f"[{dims}]{layout}", op, rest))
+    return out
+
+
+def test_pooled_decode_chunk_writes_rows_not_slabs(one_chip, monkeypatch):
+    """``Engine._decode_loop_batch``'s body (8 steps of ``forward_batched``,
+    the per-row sampler, the position clamp; the cache donated and carried)
+    at the Mistral cells' shapes: capacity 8, bucket 1024, bf16. Under
+    ``kv_slab_write`` the optimised program holds one scatter a cache, whose
+    update is the step's ``B x kv x hd`` rows and which runs in place on the
+    carry, and no ``dynamic-update-slice`` or ``copy`` of a slab (the parent
+    of PR 25 had both: each layer's slab copied out, updated and written
+    back). In place also means few temporaries: 0.269 GB here, with nothing
+    the size of the 1.07 GB cache among them; B unrolled
+    ``dynamic_update_slice``s read 1.075 GB, the whole cache carried in
+    another layout. What the read side became is printed (``pytest -s``)."""
+    from dllama_tpu.runtime.sampler import sample_dynamic
+
+    monkeypatch.setattr(qmatmul, "_interpret_default", lambda: False)
+    cfg, rows, ctx, steps = CFG_MISTRAL, 8, 1024, 8
+    params = jax.eval_shape(
+        lambda k: llama.fuse_qkv_ffn(llama._quant_init(k, cfg, "q40")), _key())
+    rope = jax.eval_shape(lambda: llama.rope_tables(cfg))
+    cache = jax.eval_shape(
+        lambda: llama.init_batch_cache(cfg, rows, jnp.bfloat16, seq_len=ctx))
+
+    def chunk(params, rope, cache, tokens, pos, keys, temps, topps):
+        def body(carry, _):
+            cache, toks, pos_, keys_ = carry
+            logits, cache = llama.forward_batched(cfg, params, rope, toks,
+                                                  cache, pos_)
+            split = jax.vmap(jax.random.split)(keys_)
+            nxt = jax.vmap(sample_dynamic)(
+                logits, split[:, 1], temps, topps).astype(jnp.int32)
+            pos_ = jnp.minimum(pos_ + 1, jnp.int32(cfg.seq_len - 1))
+            return (cache, nxt, pos_, split[:, 0]), nxt
+
+        (cache, *_), out = jax.lax.scan(
+            body, (cache, tokens, pos, keys), length=steps)
+        return out, cache
+
+    ints, floats = (_s((rows,), jnp.int32, one_chip),
+                    _s((rows,), jnp.float32, one_chip))
+    compiled = jax.jit(chunk, donate_argnums=(2,)).lower(
+        _shapes(params, one_chip), _shapes(rope, one_chip),
+        _shapes(cache, one_chip), ints, ints,
+        _s((rows, 2), jnp.uint32, one_chip), floats, floats).compile()
+    assert _has_kernel(compiled)
+
+    instructions = _instructions(compiled.as_text())
+    size = {name: n for name, n, *_ in instructions}
+    slab = rows * ctx * cfg.n_kv_heads * cfg.head_size
+    step_rows = rows * cfg.n_kv_heads * cfg.head_size
+    written = [i for i in instructions if "kv_slab_write" in i[4]]
+    scatters = [i for i in written if i[3] == "scatter"]
+    assert len(scatters) == 2, scatters  # K and V
+    for name, n, _, _, rest in scatters:
+        assert n == cfg.n_layers * slab, name  # the stacked cache itself
+        operands = re.findall(r"%([\w.\-]+)", rest.split(")", 1)[0])
+        assert size[operands[2]] == step_rows, (name, operands)
+    moved = [i[:4] for i in written if i[1] >= slab and i[3] in (
+        "copy", "dynamic-update-slice", "dynamic-slice", "transpose")]
+    assert not moved, moved
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 2 * cfg.n_layers * slab * 2  # donated
+    assert m.temp_size_in_bytes < 0.4e9, m
+    for name, n, shape, op, _ in (i for i in instructions
+                                  if "kv_slab_read" in i[4] and i[1] >= slab):
+        print(f"kv_slab_read: {name} = {shape} {op}")
+
+
+# ---------------------------------------------------------------------------
 # the names the benchmark's trace readers depend on
 # ---------------------------------------------------------------------------
 
@@ -210,8 +306,6 @@ def _kernel_names(compiled) -> list:
     """The instruction name of every Pallas custom call, without its number
     (what ``benchmarks/trace_reduce.short_name`` keeps of an ``XLA Ops``
     event)."""
-    import re
-
     return [re.sub(r"[.\d]+$", "", line.split(" = ", 1)[0].split("%")[-1])
             for line in compiled.as_text().splitlines()
             if 'custom_call_target="tpu_custom_call"' in line]
