@@ -67,6 +67,12 @@ def kv_bytes_per_position(model: dict, cache_bytes: int = 2) -> int:
     return 2 * d["L"] * d["KV"] * cache_bytes
 
 
+def kv_read_bytes(model: dict, context: float, cache_bytes: int = 2) -> float:
+    """Keys and values that one row's decode step reads at ``context`` live
+    positions, all layers: every layer attends over the whole context."""
+    return context * kv_bytes_per_position(model, cache_bytes)
+
+
 def experts_needed(model: dict, rows: float) -> float:
     """Experts of a layer that ``rows`` token rows need, each choosing k of
     E: 1 for a dense FFN; for sparse experts the expected number of distinct

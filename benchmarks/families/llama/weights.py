@@ -15,8 +15,8 @@ scale 0, so that their logit is 0 and greedy decoding never emits them: every
 request runs to its ``max_tokens`` and the response text spells its ids.
 
 The planes are plain arrays in nested dicts ({"w", "s", "s2"} a matrix): the
-reference takes them as they are, and ``launcher.py`` wraps each matrix in the
-program's ``QuantTensor`` for the ``Engine``.
+reference takes them as they are, and the family's ``wrap_planes`` wraps each
+matrix in the program's ``QuantTensor`` for the ``Engine``.
 """
 
 from __future__ import annotations
@@ -24,7 +24,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-import shapes
+from . import shapes
 
 N_FIXED_PIECES = 259  # ids below this never win: see the docstring
 Q40_K_MULTIPLE = 512  # the packed K is padded to this, as the program packs it

@@ -2,7 +2,7 @@
 
 A gap is how far a served token's reference logit lies below the reference's
 best at its position, in standard deviations of that position's logits
-(``reference.compare``). Standard library only: ``run.py`` decides with it.
+(the family's ``compare``). Standard library only: ``run.py`` decides with it.
 
 A rule is data: ``benchmarks/correct/<cell>.json`` where a cell has one of its
 own, else the ``correct`` group of the cell's configuration. Each entry names
@@ -16,15 +16,14 @@ a statistic of the gaps and its limit:
 
 ``share_of`` is the mean gap (each gap counted up to ``cap``) as a share of
 the same mean of the tokens that the reference in lower precision (the
-control, ``reference.CONTROL``) puts first at the same positions: how much of
+control, the precision below the configuration's) puts first at the same positions: how much of
 the precision that the step down would lose the path has lost already. How
 far rounding moves a token differs from one seed's weights to the next, for
 the program and the control alike, and the share takes that out.
-``where_zero`` keeps to the positions where another stand-in (the witness,
-``reference.WITNESS``: the reference in bfloat16, the precision the
-configurations state) still puts the reference's best first: where bfloat16
-itself cannot decide, the path is not asked to. It and a
-quantile overlook a few tokens by construction; the maximum, or the count of
+``where_zero`` keeps to the positions where another stand-in (the witness:
+the reference in the precision the configurations state, bfloat16) still
+puts the reference's best first: where bfloat16 itself cannot decide, the
+path is not asked to. It and a quantile overlook a few tokens by construction; the maximum, or the count of
 tokens far below the reference's best, is what a few made-up tokens fail. A
 cell's rule holds one of each kind.
 """

@@ -4,12 +4,14 @@ weights made from ``--seed``, with the profiler and the reference beside it.
 Started by ``run.py`` (which never imports JAX). It builds what
 ``api_server.serve()`` builds, ``configure_compile_cache()`` first, then
 ``create_server(ServerState(engine, tok, cfg, ...))``, with one substitution:
-where ``cli.load_engine`` reads a weight file, the planes come from
-``weights.make_planes`` (one jitted init program, already in the fused layout)
-and go to ``Engine(cfg, params, SamplerConfig(temperature=0),
-cache_dtype=bfloat16, mesh=None)`` with ``cfg.dtype = "bfloat16"``: the TPU
-defaults of ``load_engine``. The tokenizer is the ``[id]`` vocabulary at the
-configuration's vocabulary size, built in memory.
+where ``cli.load_engine`` reads a weight file, the planes come from the
+configuration's family (``families.load``: one jitted init program, already
+in the layout the program serves) and go to ``Engine(cfg, params,
+SamplerConfig(temperature=0), cache_dtype=bfloat16, mesh=None)`` with
+``cfg.dtype = "bfloat16"``: the TPU defaults of ``load_engine``. The family
+is all this file knows of the model: its configuration, weights and
+reference. The tokenizer is the ``[id]`` vocabulary at the program's
+vocabulary size, built in memory.
 
 It talks to its parent in lines: it reads one JSON command a line on stdin
 and answers with one line ``@@ {json}`` on stdout (everything else the program
@@ -40,100 +42,6 @@ ROOT = os.path.dirname(HERE)
 def say(obj: dict) -> None:
     sys.__stdout__.write("@@ " + json.dumps(obj) + "\n")
     sys.__stdout__.flush()
-
-
-def model_config(model: dict, server: dict):
-    from dllama_tpu.models.config import ModelConfig
-
-    hd = int(model.get("head_dim")
-             or model["hidden_size"] // model["num_attention_heads"])
-    return ModelConfig(
-        arch=model["arch"], dim=int(model["hidden_size"]),
-        hidden_dim=int(model["intermediate_size"]),
-        n_layers=int(model["num_hidden_layers"]),
-        n_heads=int(model["num_attention_heads"]),
-        n_kv_heads=int(model["num_key_value_heads"]),
-        vocab_size=int(model["vocab_size"]),
-        seq_len=int(model["max_position_embeddings"]),
-        head_size=hd, kv_dim=int(model["num_key_value_heads"]) * hd,
-        n_experts=int(model.get("num_local_experts", 0)),
-        n_active_experts=int(model.get("num_experts_per_tok", 0)),
-        hidden_act=model.get("hidden_act", "silu"),
-        rope_theta=float(model["rope_theta"]),
-        norm_eps=float(model["rms_norm_eps"]),
-        dtype=server.get("dtype", "bfloat16"))
-
-
-def wrap_planes(planes: dict, model: dict) -> dict:
-    """The planes as the program's parameter tree: each {"w","s","s2"}
-    becomes a ``QuantTensor`` (a view: no copy)."""
-    from dllama_tpu.ops.qmatmul import QuantTensor
-
-    import weights
-
-    def leaf(name, v):
-        if isinstance(v, dict) and set(v) == {"w", "s", "s2"}:
-            return QuantTensor(w=v["w"], s=v["s"], s2=v["s2"], kind="q40",
-                               k_logical=weights.logical_k(name, model))
-        return v
-
-    out = {k: leaf(k, v) for k, v in planes.items() if k != "layers"}
-    out["layers"] = {k: leaf(k, v) for k, v in planes["layers"].items()}
-    return out
-
-
-def make_sharded_params(model: dict, cfg, n_tp: int, seed: int):
-    """``tp > 1``: the planes in the unfused layout, lane-aligned as
-    ``quant_tp.prepare_quant_params`` aligns them, made by one jitted program
-    under ``out_shardings`` from ``quant_tp.quant_param_specs``: no device
-    ever holds a whole matrix (a whole Mixtral cannot be made on one device
-    first). -> (params, mesh)"""
-    import jax
-    from jax.sharding import NamedSharding
-
-    from dllama_tpu.parallel import quant_tp
-    from dllama_tpu.parallel.mesh import tp_mesh
-
-    import weights
-
-    mesh = tp_mesh(n_tp)
-    dims = weights.dims_of(model)
-
-    def init(key):
-        planes = weights._init(key, dims, fused=False)
-        return quant_tp.prepare_quant_params(wrap_planes(planes, model), cfg, n_tp)
-
-    key = weights.seed_key(seed)
-    specs = quant_tp.quant_param_specs(jax.eval_shape(init, key), cfg, n_tp)
-    shardings = jax.tree.map(lambda spec: NamedSharding(mesh, spec), specs)
-    return jax.jit(init, out_shardings=shardings)(key), mesh
-
-
-def unwrap_params(params: dict, model: dict) -> dict:
-    """The program's parameter tree back as plain planes at their logical
-    widths (the padding that lane alignment added is cut off)."""
-    from dllama_tpu.ops.qmatmul import QuantTensor
-
-    import shapes
-    import weights
-
-    d = shapes.dims(model)
-    width = {"wq": d["D"], "wk": d["KV"], "wv": d["KV"], "wo": d["D"],
-             "w1": d["H"], "w3": d["H"], "w2": d["D"], "moe_up": d["H"],
-             "moe_gate": d["H"], "moe_down": d["D"], "wcls": d["V"]}
-
-    def leaf(name, v):
-        if not isinstance(v, QuantTensor):
-            return v
-        kp = weights._pad_up(weights.logical_k(name, model),
-                             weights.Q40_K_MULTIPLE)
-        o = width[name]
-        return {"w": v.w[..., :kp // 2, :o], "s": v.s[..., :kp // 64, :o],
-                "s2": v.s2[..., :kp // 64, :o]}
-
-    out = {k: leaf(k, v) for k, v in params.items() if k != "layers"}
-    out["layers"] = {k: leaf(k, v) for k, v in params["layers"].items()}
-    return out
 
 
 def id_tokenizer(vocab_size: int):
@@ -208,7 +116,7 @@ def main(argv=None) -> int:
         conf = json.load(f)
     with open(os.path.join(HERE, "peaks.json")) as f:
         peaks = json.load(f)["device_kinds"]
-    model, server = conf, conf["server"]
+    server = conf["server"]
     n_tp = int(conf.get("tp", 1))
 
     t0 = time.monotonic()
@@ -233,19 +141,19 @@ def main(argv=None) -> int:
     from dllama_tpu.serving.api_server import (ServerState, create_server,
                                                drain_and_shutdown)
 
-    import reference
+    import families
     import trace_reduce
-    import weights
 
+    family = families.load(conf)
     cache_dir = configure_compile_cache()
     t_jax = time.monotonic()
-    cfg = model_config(model, server)
+    cfg = family.model_config(conf, server)
     if n_tp > 1:
-        params, mesh = make_sharded_params(model, cfg, n_tp, args.seed)
-        planes = None  # the reference gets them fused, after the window
+        params, mesh = family.make_sharded_params(conf, cfg, n_tp, args.seed)
+        planes = None  # the reference gets them after the window
     else:
-        planes = weights.make_planes(model, args.seed)
-        params, mesh = wrap_planes(planes, model), None
+        planes = family.make_planes(conf, args.seed)
+        params, mesh = family.wrap_planes(planes, conf), None
     jax.block_until_ready(params)
     t_planes = time.monotonic()
     engine = Engine(cfg, params, SamplerConfig(temperature=0.0, seed=0),
@@ -313,13 +221,11 @@ def main(argv=None) -> int:
             t1 = time.monotonic()
             res = {}
             if planes is None:
-                planes = weights.fuse_planes(unwrap_params(params, model))
+                planes = family.planes_of(params, conf)
                 del params
             if cmd.get("samples"):
-                modes = {"control": reference.CONTROL, "witness": reference.WITNESS}
-                res = reference.compare(
-                    planes, model, cmd["samples"],
-                    stand_ins={n: modes[n] for n in cmd.get("stand_ins", ())})
+                res = family.compare(planes, conf, cmd["samples"],
+                                     cmd.get("stand_ins", ()))
             out.update({"finished": True, "drained": drained,
                         "memory_peak_bytes": peak, "compare": res,
                         "reference_seconds": time.monotonic() - t1})

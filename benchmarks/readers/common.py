@@ -4,8 +4,9 @@ A reader is a module with ``read(ctx, args)`` that returns a number, or None
 where it finds nothing to read. ``ctx`` holds what ``run.py`` gathered: the
 scrapes at the window's edges (``edge0``, ``edge1``) and around the trace
 (``trace_edges``), the trace's reduction (``trace``), the client's numbers
-(``client``, ``results``), the configuration (``model``, ``server``), the mix,
-the peaks of the device and the peak memory. ``run.py`` (and the tests'
+(``client``, ``results``), the configuration (``model``, ``server``) and its
+family (``family``: the counts a share of a peak divides by), the mix, the
+peaks of the device and the peak memory. ``run.py`` (and the tests'
 ``conftest.py``) put ``benchmarks/`` and ``benchmarks/readers/`` on the path.
 """
 
@@ -14,18 +15,20 @@ from __future__ import annotations
 import re
 
 import promtext
-import shapes
 
 
 def launches(trace: dict, pattern: str) -> dict:
-    """Launches, seconds and custom-call seconds of the traced programs
-    whose name matches ``pattern``."""
+    """Launches, seconds and custom-call seconds (all together, and by the
+    call's short name) of the traced programs whose name matches ``pattern``."""
     out = {"launches": 0.0, "seconds": 0.0, "custom_call_s": 0.0}
+    calls: dict = {}
     for name, m in (trace.get("modules") or {}).items():
         if re.search(pattern, name):
             for k in out:
                 out[k] += m[k]
-    return out
+            for call, s in (m.get("custom_calls") or {}).items():
+                calls[call] = calls.get(call, 0.0) + s
+    return dict(out, custom_calls=calls)
 
 
 def traced_work(ctx: dict, args: dict):
@@ -62,9 +65,13 @@ def traced_work(ctx: dict, args: dict):
     mean_piece = sum(n_pre) / sum(-(-n // piece) for n in n_pre)
     mean_ctx = sum(r.request.prompt_tokens + 0.5 * sum(k for _, k in r.bursts)
                    for r in done) / len(done)
+    calls = dict(dec["custom_calls"])
+    for call, s in pre["custom_calls"].items():
+        calls[call] = calls.get(call, 0.0) + s
     return {"decode_steps": dec["launches"] * chunk,
             "prefill_pieces": pre["launches"], "rows": max(rows, 1.0),
             "mean_piece_tokens": mean_piece,
             "mean_prompt": sum(n_pre) / len(n_pre), "mean_context": mean_ctx,
             "custom_call_s": dec["custom_call_s"] + pre["custom_call_s"],
+            "custom_calls": calls,
             "seconds": tr["window_s"]}

@@ -1,7 +1,7 @@
 """The whole step's share (%) of the chip's bf16 peak over the traced window:
-FLOPs the model needs for the tokens of the launches the trace counts
-(``shapes.flops_per_token``) / (the trace's seconds x chips x peak)."""
-from common import shapes, traced_work
+FLOPs the model needs for the tokens of the launches the trace counts (the
+family's ``flops_per_token``) / (the trace's seconds x chips x peak)."""
+from common import traced_work
 
 
 def read(ctx, args):
@@ -12,7 +12,8 @@ def read(ctx, args):
     prompt_tokens = w["prefill_pieces"] * w["mean_piece_tokens"]
     # a decoded token attends over its row's context, a prompt token over
     # the part of its prompt before it: half the prompt on average
-    flops = (out_tokens * shapes.flops_per_token(ctx["model"], w["mean_context"])
-             + prompt_tokens * shapes.flops_per_token(ctx["model"], 0.5 * w["mean_prompt"]))
+    flops_per_token, m = ctx["family"].flops_per_token, ctx["model"]
+    flops = (out_tokens * flops_per_token(m, w["mean_context"])
+             + prompt_tokens * flops_per_token(m, 0.5 * w["mean_prompt"]))
     return 100.0 * flops / (w["seconds"] * ctx["chips"]
                             * ctx["peaks"]["bf16_flops_per_s"])

@@ -4,14 +4,16 @@
     python3 benchmarks/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 This process never imports JAX. It reads the cell from ``BENCHMARK.json``, the
-configuration from ``benchmarks/configs/<configuration>.json``, the traffic mix
+configuration from ``benchmarks/configs/<configuration>.json`` (whose
+``family`` names the one package that knows the model:
+``benchmarks/families/<family>/``, see ``families.load``), the traffic mix
 from ``benchmarks/traffic/<traffic>.json`` and each metric from
 ``benchmarks/end_to_end/<metric>.json`` or ``benchmarks/layer_metrics/<metric>.json``
 (a ``reader``, which names a module under ``benchmarks/readers/``, and its
 ``args``; everything else about a metric is said once, in ``BENCHMARK.json``).
 The rule that decides ``correct`` is ``benchmarks/correct/<cell>.json`` where
-the cell has one, else the configuration's ``correct`` group. A new cell, mix
-or metric is new files and one new entry, never an edit here.
+the cell has one, else the configuration's ``correct`` group. A new cell, mix,
+metric or architecture is new files and one new entry, never an edit here.
 
 It starts one child (``launcher.py``: the server as ``cli serve`` builds it,
 on weights made from the seed, holding the chip), sends the mix's fixed warm
@@ -20,9 +22,9 @@ then measures for ``--seconds`` seconds. ``setup_s`` runs from this process's
 start to the window's first instant. After the window the requests sent in it
 run to their end under unchanged load, the child stops the server, reads the
 peak memory, frees the program's state and holds a seeded sample of the
-finished requests (the longest among them) to the plain reference
-(``reference.py``). The last line of stdout is the result; the last lines of
-stderr are the numbers compared, each beside its limit.
+finished requests (the longest among them) to the family's plain reference.
+The last line of stdout is the result; the last lines of stderr are the
+numbers compared, each beside its limit.
 
 Exit code 3 and no result: no accelerator, fewer chips than the cell asks
 for, an unknown ``device_kind``, or a directory without the program.
@@ -49,11 +51,13 @@ ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 sys.path.insert(0, os.path.join(HERE, "readers"))
 
+import families  # noqa: E402
 import gapstats  # noqa: E402
 import loadgen  # noqa: E402
 import promtext  # noqa: E402
 
 READY_TIMEOUT_S = 1100.0
+TICK_PHASES = "dllama_tick_phase_seconds_total"
 FINISH_TIMEOUT_S = 300.0
 
 
@@ -216,6 +220,10 @@ def scrape(port: int) -> dict:
 def run(args) -> int:
     bench = load_json(args.benchmark)
     cell, conf_path, conf, mix = cell_files(bench, args.workload)
+    try:  # before any device work
+        family = families.load(conf)
+    except ValueError as e:
+        raise Failure(str(e)) from None
     peaks_all = load_json(os.path.join(HERE, "peaks.json"))["device_kinds"]
     scratch = os.path.join(HERE, ".scratch", args.workload)
     shutil.rmtree(scratch, ignore_errors=True)
@@ -225,12 +233,14 @@ def run(args) -> int:
     child = Child(conf_path, args.seed, int(cell["chips"]), trace_dir, args.rehearse, args.fault,
                   os.path.join(scratch, "child.log"))
     try:
-        return _drive(args, bench, cell, conf, mix, peaks_all, child, scratch)
+        return _drive(args, bench, cell, conf, family, mix, peaks_all, child,
+                      scratch)
     finally:
         child.close()
 
 
-def _drive(args, bench, cell, conf, mix, peaks_all, child, scratch) -> int:
+def _drive(args, bench, cell, conf, family, mix, peaks_all, child,
+           scratch) -> int:
     ready = child.answer(READY_TIMEOUT_S)
     port, device = ready["port"], ready["device"]
     peaks = peaks_all.get(device["kind"])
@@ -329,10 +339,12 @@ def _drive(args, bench, cell, conf, mix, peaks_all, child, scratch) -> int:
     ctx = {
         "edge0": edge0, "edge1": edge1, "trace_edges": trace_edges,
         "trace": fin.get("trace"), "client": stats, "results": results,
-        "window": (t0, t1), "model": conf, "server": conf["server"],
+        "window": (t0, t1), "model": conf, "family": family,
+        "server": conf["server"],
         "mix": mix, "peaks": peaks, "chips": int(cell["chips"]),
         "memory_peak_bytes": fin["memory_peak_bytes"],
         "lateness_s": loop.lateness_s, "setup_s": setup_s,
+        "sample": sample,
     }
     return report(args, bench, cell, conf, ctx, stats, device, fin, bad_text,
                   count_gap)
@@ -410,6 +422,19 @@ def report(args, bench, cell, conf, ctx, stats, device, fin, bad_text,
             1000.0 * loadgen.pct(ctx["lateness_s"], 0.99)
             if ctx["lateness_s"] else None),
         "compare_extra": extra,
+        # programs the serving process asked the compiler for inside the
+        # window (a traced run prints it as ``engine.compiles_in_window``):
+        # an untraced run whose tail or rate reads far off shows here why
+        "compiles_in_window": (
+            ctx["edge1"]["stats"]["compile_cache"]["requests"]
+            - ctx["edge0"]["stats"]["compile_cache"]["requests"]),
+        # and the seconds the scheduler thread spent in each phase of its
+        # tick over the window (``dllama_tick_phase_seconds_total``): a
+        # stall of seconds sits in one of them
+        "tick_phase_s": {
+            labels: round(promtext.delta(ctx["edge0"], ctx["edge1"],
+                                         TICK_PHASES, labels), 4)
+            for name, labels, _ in ctx["edge1"]["prom"] if name == TICK_PHASES},
         # every request of the window, for whoever asks where a tail sits:
         # [output tokens, bursts, ms from send to first, ms first to last]
         "requests": [
@@ -431,6 +456,10 @@ def report(args, bench, cell, conf, ctx, stats, device, fin, bad_text,
         line["info"]["gaps"] = dict(
             {who: [round(g, 4) for g in v] for who, v in others.items()},
             program=[round(g, 4) for g in gaps])
+        # and whose they are: [prompt tokens, served ids] of each sampled
+        # request, in the gaps' order
+        line["info"]["sample"] = [[len(x["prompt"]), x["served"]]
+                                  for x in ctx["sample"]]
     line["compared"] = compared
     for name, v, lim in checks:
         print(f"compared {name} = {v} (limit {lim})", file=sys.stderr)

@@ -1,11 +1,13 @@
 """Every data file loads and keeps to what the driver accepts."""
 import glob
 import json
+import math
 import os
 import re
 
 import pytest
 
+import families
 from conftest import BENCH, ROOT
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -108,16 +110,42 @@ def test_config_file(path):
     conf = load(path)
     assert NAME.match(conf["name"])
     assert os.path.basename(path) == conf["name"] + ".json"
-    for key in ("hidden_size", "intermediate_size", "num_hidden_layers",
-                "num_attention_heads", "num_key_value_heads", "vocab_size",
-                "max_position_embeddings", "rope_theta", "rms_norm_eps"):
-        assert key in conf, key
+    assert NAME.match(conf["family"])  # the model's own keys are its family's
     assert line_ok(conf["source"])
     assert isinstance(conf["reduced"], list) and isinstance(conf["assumed"], dict)
     for key in ("session_cache", "batch_window_ms", "batch_max", "batch_chunk"):
         assert key in conf["server"]
     assert rule_ok(conf["correct"])
     assert conf["chips"] in (1, 4) and conf["weights"] == "q40"
+
+
+def test_every_accepted_configuration_is_among_the_files(bench):
+    files = {os.path.relpath(p, ROOT) for p in glob.glob(os.path.join(BENCH, "configs", "*.json"))}
+    assert {c["file"] for c in bench["configs"]} <= files
+
+
+@pytest.mark.parametrize("path", sorted(glob.glob(os.path.join(BENCH, "configs", "*.json"))))
+def test_a_configurations_family_loads_and_counts(path):
+    """Every configuration of BENCHMARK.json and every tiny one: its family
+    loads with all of its functions, and each count is finite and positive
+    at the rows and contexts a reader may hand it."""
+    conf = load(path)
+    fam = families.load(conf)
+    assert all(callable(getattr(fam, f)) for f in families.REQUIRED)
+    if conf["tp"] > 1:
+        assert all(callable(getattr(fam, f)) for f in families.SHARDING)
+    peaks = load(os.path.join(BENCH, "peaks.json"))["device_kinds"]["TPU v5 lite"]
+    counts = [fam.resident_bytes(conf)]
+    for rows in (1, 8):
+        counts += [fam.plane_bytes_per_launch(conf, rows),
+                   fam.launch_least_seconds(conf, rows, peaks)]
+    for context in (1, 1024):
+        counts += [fam.flops_per_token(conf, context), fam.kv_read_bytes(conf, context)]
+    assert all(math.isfinite(v) and v > 0 for v in counts), counts
+    reads = [fam.kv_read_bytes(conf, c) for c in (0, 1, 2, 127, 128, 129, 1024, 4096, 65536)]
+    assert reads == sorted(reads)  # never falls as the context grows
+    assert fam.plane_bytes_per_launch(conf, 8) >= fam.plane_bytes_per_launch(conf, 1)
+    assert fam.flops_per_token(conf, 1024) > fam.flops_per_token(conf, 1)
 
 
 @pytest.mark.parametrize("path", sorted(glob.glob(os.path.join(BENCH, "traffic", "*.json"))))

@@ -1,5 +1,6 @@
-"""The plain reference against the program at a tiny size on the CPU, and the
-control: the reference in the lower precision comes out as not correct."""
+"""The ``llama`` family's plain reference against the program at a tiny size
+on the CPU, and the control: the reference in the lower precision comes out
+as not correct."""
 import json
 import os
 
@@ -16,7 +17,7 @@ def conf(name):
 
 @pytest.fixture(scope="module", params=["tiny-rehearsal", "tiny-dense"])
 def model_and_planes(request):
-    import weights
+    from families.llama import weights
 
     c = conf(request.param)
     return c, weights.make_planes(c, seed=2 ** 31 + 77)
@@ -25,7 +26,7 @@ def model_and_planes(request):
 def test_planes_are_the_same_for_a_seed_and_never_win_a_fixed_piece(model_and_planes):
     import jax
 
-    import weights
+    from families.llama import weights
 
     c, planes = model_and_planes
     again = weights.make_planes(c, seed=2 ** 31 + 77)
@@ -38,12 +39,12 @@ def test_planes_are_the_same_for_a_seed_and_never_win_a_fixed_piece(model_and_pl
 
 
 def test_dequant_restates_the_programs_layout(model_and_planes):
-    import launcher
-    import reference
+    import families
+    from families.llama import reference
     from dllama_tpu.ops import qmatmul
 
     c, planes = model_and_planes
-    params = launcher.wrap_planes(planes, c)
+    params = families.load(c).wrap_planes(planes, c)
     for name in ("wqkv", "wo"):
         want = qmatmul.dequantize(params["layers"][name])
         got = np.asarray(reference.dequant_q40(planes["layers"][name],
@@ -54,13 +55,14 @@ def test_dequant_restates_the_programs_layout(model_and_planes):
 def test_reference_agrees_with_the_programs_forward(model_and_planes):
     import jax.numpy as jnp
 
-    import launcher
-    import reference
+    import families
+    from families.llama import reference
     from dllama_tpu.models import llama
 
     c, planes = model_and_planes
-    cfg = launcher.model_config(c, c["server"])
-    params = llama.fuse_qkv_ffn(launcher.wrap_planes(planes, c))
+    fam = families.load(c)
+    cfg = fam.model_config(c, c["server"])
+    params = llama.fuse_qkv_ffn(fam.wrap_planes(planes, c))
     rng = np.random.default_rng(0)
     seq = rng.integers(259, c["vocab_size"], size=40).tolist()
     logits, _ = llama.forward(cfg, params, llama.rope_tables(cfg),
@@ -78,8 +80,8 @@ def test_control_in_lower_precision_comes_out_not_correct(model_and_planes):
     correct under the configuration's rule, on each of three seeds) and by
     the reference in bfloat16 (the witness: correct)."""
     import gapstats
-    import reference
-    import weights
+    from families.llama import reference
+    from families.llama import weights
 
     c, _ = model_and_planes
     rule = c["correct"]

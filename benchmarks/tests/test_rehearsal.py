@@ -110,6 +110,10 @@ def test_the_control_goes_through_the_same_rule_and_is_printed():
     assert stood_in["control"]["compared"]["gap_vs_control_ratio"]["value"] == 1.0
     assert stood_in["control"]["correct"] is False
     assert stood_in["witness"]["correct"] is True
+    # every gap is printed with whose it is: [prompt tokens, served ids]
+    gaps, sample = line["info"]["gaps"], line["info"]["sample"]
+    assert set(gaps) == {"control", "witness", "program"}
+    assert sum(len(ids) for _, ids in sample) == len(gaps["program"])
     err = p.stderr.strip().splitlines()
     assert any(l.startswith("control correct = ") for l in err)
     assert err[-1] == "correct = True"  # the program's own lines come last
@@ -166,7 +170,9 @@ def test_a_cell_a_mix_and_a_metric_are_new_files_and_one_entry_each(tmp_path):
                                "traffic": "tiny-two", "chips": 1, "why": "new"})
     bench["per_layer"].append(metric)
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
-    p = run(["--workload", "tiny-new.two", "--seed", "4", "--seconds", "2",
+    # 4 s: in a fresh directory nothing is cached, and a request of the cold
+    # run can outlast a 2 s window, which then holds none that was sent in it
+    p = run(["--workload", "tiny-new.two", "--seed", "4", "--seconds", "4",
              "--trace", "1", "--rehearse"], cwd=str(tmp_path),
             script=str(b / "run.py"))
     line = last_line(p)

@@ -75,9 +75,26 @@ def test_launches_bound_the_window_and_own_their_custom_calls():
     assert r["window_s"] == pytest.approx(6.1) and r["busy_s"] == 4.5
     assert r["custom_call_s"] == 4.0
     assert r["modules"]["jit__decode_loop_batch"] == {
-        "launches": 1, "seconds": 3.0, "custom_call_s": 2.0}
+        "launches": 1, "seconds": 3.0, "custom_call_s": 2.0,
+        "custom_calls": {"q40_matmul [custom-call]": 2.0}}
     assert r["modules"]["jit__prefill"]["custom_call_s"] == 2.0
     assert r["modules"]["jit_add"]["launches"] == 1
+
+
+def test_every_custom_call_of_a_launch_is_kept_by_its_short_name():
+    """Two kinds of kernel in one launch: each has its seconds under its own
+    name (as ``device_ops`` prints it), and together they are the program's
+    ``custom_call_s``; two chips give their mean."""
+    attn = Q40.replace("%q40_matmul.7", "%window_attention.3")
+    ops = [[Q40, 1e9, 1e9], [attn, 2e9, 0.5e9], [Q40, 2.5e9, 1e9],
+           ["%fusion.1 = f32[8] fusion(...)", 3.5e9, 0.25e9]]
+    mods = [["jit__decode_loop_batch(123)", 1e9, 3e9]]
+    rec = {f"/device:TPU:{i}": {"XLA Ops": ops, "XLA Modules": mods}
+           for i in range(2)}
+    m = trace_reduce.reduce(rec)["modules"]["jit__decode_loop_batch"]
+    assert m["custom_calls"] == {"q40_matmul [custom-call]": 2.0,
+                                 "window_attention [custom-call]": 0.5}
+    assert m["custom_call_s"] == 2.5 and m["launches"] == 1
 
 
 def test_readers_divide_the_trace_launches_by_the_trace_seconds():
@@ -89,14 +106,22 @@ def test_readers_divide_the_trace_launches_by_the_trace_seconds():
     res = loadgen.Result(rq)
     res.status, res.done, res.bursts = 200, True, [(10.2, 8), (10.6, 8)]
     edge = lambda t, n: {"t": t, "prom": [("dllama_decode_chunk_ms_count", "", n)]}
-    model = {"hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
+    import families
+
+    model = {"name": "made-up", "family": "llama",
+             "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
              "num_attention_heads": 4, "num_key_value_heads": 2, "vocab_size": 512}
     trace = {"window_s": 2.0, "busy_s": 1.5, "modules": {
-        "jit__decode_loop_batch": {"launches": 4, "seconds": 1.0, "custom_call_s": 0.5},
-        "jit__prefill": {"launches": 2, "seconds": 0.3, "custom_call_s": 0.25},
-        "jit__prefill_other": {"launches": 9, "seconds": 9.0, "custom_call_s": 9.0}}}
+        "jit__decode_loop_batch": {"launches": 4, "seconds": 1.0, "custom_call_s": 0.5,
+                                   "custom_calls": {"w13_q40_matmul [custom-call]": 0.3,
+                                                    "window_attention [custom-call]": 0.2}},
+        "jit__prefill": {"launches": 2, "seconds": 0.3, "custom_call_s": 0.25,
+                         "custom_calls": {"w13_q40_matmul [custom-call]": 0.25}},
+        "jit__prefill_other": {"launches": 9, "seconds": 9.0, "custom_call_s": 9.0,
+                               "custom_calls": {"w13_q40_matmul [custom-call]": 9.0}}}}
     ctx = {"trace": trace, "trace_edges": (edge(10.0, 3), edge(11.0, 5)),
-           "results": [res], "model": model, "chips": 1,
+           "results": [res], "model": model, "family": families.load(model),
+           "chips": 1,
            "server": {"batch_chunk": 8, "batch_max": 8},
            "peaks": {"bf16_flops_per_s": 1e9, "hbm_bytes_per_s": 1e6}}
     args = {"decode_module": "^jit__decode_loop", "prefill_module": "^jit__prefill$"}
@@ -104,11 +129,20 @@ def test_readers_divide_the_trace_launches_by_the_trace_seconds():
     w = common.traced_work(ctx, args)
     assert w["decode_steps"] == 32 and w["prefill_pieces"] == 2
     assert w["rows"] == 1.0 and w["seconds"] == 2.0 and w["custom_call_s"] == 0.75
-    shapes = importlib.import_module("shapes")
-    roof = importlib.import_module("trace_custom_call_roofline").read(ctx, args)
-    least = (32 * shapes.launch_least_seconds(model, 1.0, ctx["peaks"])
-             + 2 * shapes.launch_least_seconds(model, 64.0, ctx["peaks"]))
-    assert roof == pytest.approx(100.0 * least / 0.75)
+    assert w["custom_calls"] == {"w13_q40_matmul [custom-call]": 0.55,
+                                 "window_attention [custom-call]": 0.2}
+    fam = ctx["family"]
+    roofline = importlib.import_module("trace_custom_call_roofline")
+    least = (32 * fam.launch_least_seconds(model, 1.0, ctx["peaks"])
+             + 2 * fam.launch_least_seconds(model, 64.0, ctx["peaks"]))
+    assert roofline.read(ctx, args) == pytest.approx(100.0 * least / 0.75)
+    # a kernel's own share: the calls its pattern names, under the family's
+    # function that its metric file names; no such call, nothing to read
+    own = dict(args, names="^w13_", least="launch_least_seconds")
+    assert roofline.read(ctx, own) == pytest.approx(100.0 * least / 0.55)
+    assert roofline.read(ctx, dict(args, names="_q40_|^window_")) == \
+        pytest.approx(100.0 * least / 0.75)
+    assert roofline.read(ctx, dict(args, names="^flash_")) is None
     share = importlib.import_module("bytes_share").read(ctx, args)
     assert share > 0
     # a trace whose programs carry other names: nothing to read, never 0

@@ -105,7 +105,9 @@ def reduce(record: dict,
            custom_call: str = r"custom-call|custom_call|pallas|mosaic") -> dict | None:
     """Busy seconds (mean over the chips), the window, the heaviest
     operations, the longest idle gaps, and per program (``modules``) its
-    launches, their seconds and the seconds of custom calls inside them.
+    launches, their seconds and the seconds of custom calls inside them:
+    all together (``custom_call_s``) and each by its short name
+    (``custom_calls``), so that a kernel can have a share of its own.
 
     The window runs from the first recorded launch's start to the last
     one's end, or, in a trace without ``XLA Modules``, from the first to the
@@ -135,7 +137,8 @@ def reduce(record: dict,
         starts = [s for s, _, _ in whole]
         for s, e, n in whole:
             m = modules.setdefault(n, {"launches": 0, "seconds": 0.0,
-                                       "custom_call_s": 0.0})
+                                       "custom_call_s": 0.0,
+                                       "custom_calls": {}})
             m["launches"] += 1
             m["seconds"] += (e - s) / 1e9
         clipped = [(max(s, w0), min(e, w1), n) for s, e, n in ops
@@ -149,7 +152,9 @@ def reduce(record: dict,
                 custom_s += e - s
                 i = bisect.bisect_right(starts, s) - 1
                 if i >= 0 and s < whole[i][1]:
-                    modules[whole[i][2]]["custom_call_s"] += (e - s) / 1e9
+                    m, took = modules[whole[i][2]], (e - s) / 1e9
+                    m["custom_call_s"] += took
+                    m["custom_calls"][key] = m["custom_calls"].get(key, 0.0) + took
         edge = w0
         for s, e in merged:
             if s > edge:
@@ -162,6 +167,8 @@ def reduce(record: dict,
         m["launches"] /= n_chips
         m["seconds"] /= n_chips
         m["custom_call_s"] /= n_chips
+        m["custom_calls"] = {k: v / n_chips
+                             for k, v in m["custom_calls"].items()}
     idle = sum(g1 - g0 for g0, g1 in gaps)
     return {
         "busy_s": sum(busy_all) / n_chips / 1e9,
