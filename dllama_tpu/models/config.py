@@ -24,7 +24,7 @@ def resolve_dtype(name: str | None, default: str) -> jnp.dtype:
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
-    arch: str  # "llama" | "grok1" | "mixtral"
+    arch: str  # "llama" | "grok1" | "mixtral" | "mimo_v2" (needs a layer_plan)
     dim: int
     hidden_dim: int
     n_layers: int
@@ -48,6 +48,31 @@ class ModelConfig:
     post_norms: bool | None = None
     norm_eps: float = 1e-5
     dtype: str = "float32"
+    # --- layers of different kinds (all unset for a uniform model) ---------
+    # ``layer_plan``: one (attention kind, FFN kind) pair a layer, attention
+    # "full" | "window", FFN "dense" | "moe". With a plan the parameters are
+    # one stack a kind (``plan_kinds``), the forward scans runs of like
+    # layers (``plan_runs``) and the cache is a stack a attention kind. The
+    # fields below say what the kinds differ in; ``n_kv_heads`` /
+    # ``rope_theta`` / ``hidden_dim`` are then the full layers' KV heads and
+    # theta and the EXPERTS' width (a dense layer's width is its planes').
+    layer_plan: tuple | None = None
+    n_kv_heads_window: int = 0  # KV heads of the window layers
+    v_head_size: int = 0  # value heads' width (0: head_size)
+    rope_dim: int = 0  # leading dims of a head that rotate (0: head_size)
+    rope_theta_window: float = 0.0
+    window: int = 0  # a window layer's query i sees keys i - window < j <= i
+    window_sink: bool = False  # one learned score a head in the denominator
+    value_scale: float = 1.0  # v <- value_scale * v after its projection
+    # "softmax": softmax over all experts, the top k renormalised;
+    # "sigmoid_bias": sigmoid scores, the top k of score + bias chosen, the
+    # chosen experts' unbiased scores renormalised
+    router: str = "softmax"
+    # the experts this process holds of the ``n_experts`` the router scores:
+    # [expert_first, expert_first + expert_count); 0 = all of them. An expert
+    # layer then returns its held experts' part of the sum
+    expert_first: int = 0
+    expert_count: int = 0
 
     def __post_init__(self):
         # Arch-implied semantics, resolved from None sentinels: the Grok
@@ -63,7 +88,7 @@ class ModelConfig:
         if self.rope_style is None:
             object.__setattr__(
                 self, "rope_style",
-                rope_ops.HALF if self.arch in ("grok1", "mixtral")
+                rope_ops.HALF if self.arch in ("grok1", "mixtral", "mimo_v2")
                 else rope_ops.INTERLEAVED)
         if self.embedding_scale is None:
             object.__setattr__(
@@ -73,6 +98,25 @@ class ModelConfig:
                 self, "logit_scale", GROK_LOGIT_SCALE if grok else 1.0)
         if self.post_norms is None:
             object.__setattr__(self, "post_norms", grok)
+        if self.layer_plan is not None:
+            plan = tuple(tuple(k) for k in self.layer_plan)
+            object.__setattr__(self, "layer_plan", plan)
+            bad = [k for k in plan if len(k) != 2
+                   or k[0] not in ("full", "window")
+                   or k[1] not in ("dense", "moe")]
+            if bad or len(plan) != self.n_layers:
+                raise ValueError(
+                    f"layer_plan needs n_layers={self.n_layers} pairs of "
+                    f"(full|window, dense|moe), got {len(plan)} with {bad}")
+            if self.plan_count("window") and self.window < 1:
+                raise ValueError(
+                    f"window layers need a window, got {self.window}")
+        if self.router not in ("softmax", "sigmoid_bias"):
+            raise ValueError(f"unknown router {self.router!r}")
+        if not 0 <= self.expert_first <= self.n_experts - self.expert_count:
+            raise ValueError(
+                f"held experts [{self.expert_first}, +{self.expert_count}) "
+                f"lie outside the {self.n_experts} the router scores")
 
     @property
     def jax_dtype(self):
@@ -81,6 +125,72 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def n_experts_held(self) -> int:
+        """Experts in this process's stacks (all of them unless cut)."""
+        return self.expert_count or self.n_experts
+
+    @property
+    def v_size(self) -> int:
+        return self.v_head_size or self.head_size
+
+    @property
+    def ring_slots(self) -> int:
+        """Slots of a window layer's ring: position p lives in slot
+        ``p % ring_slots``. A forward over T tokens writes its T keys before
+        it attends, so the ring must hold the window of the FIRST query
+        beside them, ``window + T - 1`` slots: twice the window takes a
+        piece of up to ``window + 1`` tokens."""
+        return 2 * self.window
+
+    @property
+    def max_prefill_piece(self) -> int:
+        """The longest forward a window layer's ring takes (0: any)."""
+        if not self.layer_plan or not self.window:
+            return 0
+        return self.ring_slots - self.window + 1
+
+    @property
+    def plan_kinds(self) -> tuple:
+        """The distinct (attention, FFN) kinds, in order of first use: one
+        parameter stack each, named ``attention_ffn``."""
+        return tuple(dict.fromkeys(self.layer_plan or ()))
+
+    @property
+    def plan_runs(self) -> tuple:
+        """Runs of like layers: (kind, first index in the kind's parameter
+        stack, first index in the attention kind's cache stack, count)."""
+        runs: list = []
+        seen_kind: dict = {}
+        seen_att: dict = {}
+        for kind in self.layer_plan or ():
+            p, c = seen_kind.get(kind, 0), seen_att.get(kind[0], 0)
+            if runs and runs[-1][0] == kind:
+                runs[-1][3] += 1
+            else:
+                runs.append([kind, p, c, 1])
+            seen_kind[kind], seen_att[kind[0]] = p + 1, c + 1
+        return tuple(tuple(r) for r in runs)
+
+    def plan_count(self, attention: str = None, ffn: str = None) -> int:
+        return sum(1 for a, f in self.layer_plan or ()
+                   if attention in (None, a) and ffn in (None, f))
+
+    def plan_text(self) -> str:
+        """The plan in a line: ``F.D W.E*4 F.E ...``."""
+        return " ".join(
+            f"{k[0][0].upper()}.{'D' if k[1] == 'dense' else 'E'}"
+            + (f"*{n}" if n > 1 else "") for k, _, _, n in self.plan_runs)
+
+    def refuse_for_plan(self, what: str) -> None:
+        """One line for what is not built for layers of different kinds."""
+        if self.layer_plan:
+            raise ValueError(
+                f"{what} is not built for a model whose layers differ in kind "
+                f"(layer plan {self.plan_text()}): serve it with --tp 1 on "
+                f"the slab pool, without --kv-pages and --spec-draft "
+                f"(ROADMAP.md R5)")
 
     @classmethod
     def from_spec(cls, spec: ModelSpec, dtype: str = "float32") -> "ModelConfig":

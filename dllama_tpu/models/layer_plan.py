@@ -1,0 +1,282 @@
+"""The forward pass of a model whose layers differ in kind.
+
+``ModelConfig.layer_plan`` gives every layer an attention kind ("full": the
+whole context, or "window": a sliding window with its own KV head count,
+rotary base and a sink) and an FFN kind ("dense", or "moe": routed experts,
+of which this process may hold a share). ``models.llama``'s entry points
+(``forward``, ``forward_batched``, ``init_cache``, ``init_batch_cache``,
+``rope_tables``) hand over to this module when the configuration has a plan;
+a uniform model never comes here.
+
+Parameters: ``params["layers"]`` holds one stack a kind, ``"full_dense"``,
+``"window_moe"``, ... (``cfg.plan_kinds``), each laid out as a uniform
+model's ``layers`` (``wqkv`` or ``wq wk wv``, ``wo``, ``rms_att``,
+``rms_ffn``; ``w13``/``w2`` or ``w1 w3 w2``; ``moe_router``, ``moe_bias``,
+``moe_upgate`` or ``moe_up moe_gate``, ``moe_down``) plus ``sink`` [n, heads]
+on window kinds. The forward walks ``cfg.plan_runs``: a ``lax.scan`` over
+each run of like layers, indexing the kind's stack (quantized planes stay
+stacked and the kernels' scalar prefetch picks the layer, as in
+``llama.forward``).
+
+The cache is a small tree by attention kind: ``k``/``v`` are the full
+layers' ``[Lf, (B,) S, kv, hd]`` / ``[.., v_hd]`` stacks, and ``wk``/``wv``
+the window layers' RINGS ``[Lw, (B,) R, kv_w, hd]``: position ``p`` lives in
+slot ``p % R`` (``cfg.ring_slots``), so a window layer's cache does not grow
+with the context. A ring is the same in a solo cache, a staging cache and a
+pool's slab, whatever the slab's context, so the engine's tree-mapped copies
+(insert, migrate, grow) move a ring whole.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from dllama_tpu.models import llama
+from dllama_tpu.models.config import ModelConfig
+from dllama_tpu.models.moe import moe_ffn, pick_counts, route_topk
+from dllama_tpu.ops.attention import gqa_attention
+from dllama_tpu.ops.norms import rmsnorm
+from dllama_tpu.ops.qmatmul import QuantTensor, matmul_any
+from dllama_tpu.ops.rope import apply_rope, rope_table
+
+#: cache leaves and rope tables of each attention kind
+CACHE_KEYS = {"full": ("k", "v"), "window": ("wk", "wv")}
+ROPE_KEYS = {"full": ("cos", "sin"), "window": ("wcos", "wsin")}
+
+
+def kind_name(kind: tuple) -> str:
+    return f"{kind[0]}_{kind[1]}"
+
+
+def _kv_heads(cfg: ModelConfig, att: str) -> int:
+    return cfg.n_kv_heads_window if att == "window" else cfg.n_kv_heads
+
+
+def _cache_shapes(cfg: ModelConfig, lead: tuple, seq_len: int) -> dict:
+    out = {}
+    for att, (kk, vk) in CACHE_KEYS.items():
+        n = cfg.plan_count(att)
+        if not n:
+            continue
+        slots = cfg.ring_slots if att == "window" else seq_len
+        head = (n, *lead, slots, _kv_heads(cfg, att))
+        out[kk] = (*head, cfg.head_size)
+        out[vk] = (*head, cfg.v_size)
+    return out
+
+
+def init_cache(cfg: ModelConfig, cache_dtype=jnp.float32) -> dict:
+    return {k: jnp.zeros(s, cache_dtype)
+            for k, s in _cache_shapes(cfg, (), cfg.seq_len).items()}
+
+
+def init_batch_cache(cfg: ModelConfig, batch: int, cache_dtype=jnp.float32,
+                     seq_len: int = None) -> dict:
+    S = cfg.seq_len if seq_len is None else seq_len
+    return {k: jnp.zeros(s, cache_dtype)
+            for k, s in _cache_shapes(cfg, (batch,), S).items()}
+
+
+def kv_resident_bytes(cache: dict) -> dict:
+    """Bytes of a cache tree by attention kind: {"full", "window"}."""
+    return {att: sum(cache[k].nbytes for k in keys if k in cache)
+            for att, keys in CACHE_KEYS.items()}
+
+
+def rope_tables(cfg: ModelConfig) -> dict:
+    """One pair of tables an attention kind, over the rotating dimensions."""
+    rd = cfg.rope_dim or cfg.head_size
+    out = {}
+    for att, (ck, sk) in ROPE_KEYS.items():
+        if cfg.plan_count(att):
+            theta = cfg.rope_theta_window if att == "window" else cfg.rope_theta
+            cos, sin = rope_table(cfg.seq_len, rd, theta)
+            out[ck], out[sk] = jnp.asarray(cos), jnp.asarray(sin)
+    return out
+
+
+def _rope(cfg: ModelConfig, x, cos, sin):
+    """Rotate the first ``rope_dim`` dimensions of every head; the rest pass."""
+    rd = cfg.rope_dim or cfg.head_size
+    if rd == x.shape[-1]:
+        return apply_rope(x, cos, sin, cfg.rope_style)
+    return jnp.concatenate(
+        [apply_rope(x[..., :rd], cos, sin, cfg.rope_style), x[..., rd:]],
+        axis=-1)
+
+
+def _qkv(cfg: ModelConfig, att: str, lp: dict, x, layer):
+    """x [N, dim] -> q [N, heads, hd], k [N, kv, hd], v [N, kv, v_hd], the
+    values already scaled."""
+    N, eps = x.shape[0], cfg.norm_eps
+    qd = cfg.n_heads * cfg.head_size
+    kd = _kv_heads(cfg, att) * cfg.head_size
+    if "wqkv" in lp:
+        qkv = llama._norm_proj(x, lp["rms_att"], lp["wqkv"], layer, eps,
+                               name="wqkv")
+        q, k, v = qkv[:, :qd], qkv[:, qd:qd + kd], qkv[:, qd + kd:]
+    else:
+        q, k, v = (llama._norm_proj(x, lp["rms_att"], lp[n], layer, eps,
+                                    name=n) for n in ("wq", "wk", "wv"))
+    v = v.reshape(N, -1, cfg.v_size)
+    if cfg.value_scale != 1.0:
+        v = v * jnp.asarray(cfg.value_scale, v.dtype)
+    return (q.reshape(N, -1, cfg.head_size), k.reshape(N, -1, cfg.head_size),
+            v)
+
+
+def _attend(cfg: ModelConfig, att: str, lp: dict):
+    """The attention of one sequence for this kind, under its own scope."""
+    window = cfg.window if att == "window" else 0
+    sink = lp.get("sink") if cfg.window_sink else None
+
+    def attend(q, k_slab, v_slab, pos):
+        with jax.named_scope(f"attention_{att}"):
+            return gqa_attention(q, k_slab, v_slab, pos, window=window,
+                                 sink=sink)
+
+    return attend
+
+
+@jax.named_scope("attention")
+def _attn_block(cfg: ModelConfig, att: str, lp: dict, rope: dict, x, cache,
+                pos, layer, cidx):
+    """One sequence's attention sub-block: x [T, dim] at positions
+    ``pos..pos+T``; ``cache`` is the whole tree, of which layer ``cidx`` of
+    this kind's stacks is written and read. -> (output [T, dim], cache)"""
+    T = x.shape[0]
+    kk, vk = CACHE_KEYS[att]
+    ck, sk = ROPE_KEYS[att]
+    q, k, v = _qkv(cfg, att, lp, x, layer)
+    cos = jax.lax.dynamic_slice_in_dim(rope[ck], pos, T)[:, None, :]
+    sin = jax.lax.dynamic_slice_in_dim(rope[sk], pos, T)[:, None, :]
+    q, k = _rope(cfg, q, cos, sin), _rope(cfg, k, cos, sin)
+    k_cache, v_cache = cache[kk], cache[vk]
+    if att == "window":
+        if T > cfg.max_prefill_piece:
+            raise ValueError(
+                f"a forward over {T} tokens does not fit the window layers' "
+                f"ring ({cfg.ring_slots} slots, window {cfg.window}): at "
+                f"most {cfg.max_prefill_piece} tokens a piece")
+        slots = jnp.mod(pos + jnp.arange(T, dtype=jnp.int32),
+                        k_cache.shape[1])
+        with jax.named_scope("kv_ring_write"):
+            k_cache = k_cache.at[cidx, slots].set(k.astype(k_cache.dtype))
+            v_cache = v_cache.at[cidx, slots].set(v.astype(v_cache.dtype))
+    else:
+        zero = jnp.int32(0)
+        with jax.named_scope("kv_slab_write"):
+            k_cache = jax.lax.dynamic_update_slice(
+                k_cache, k.astype(k_cache.dtype)[None], (cidx, pos, zero, zero))
+            v_cache = jax.lax.dynamic_update_slice(
+                v_cache, v.astype(v_cache.dtype)[None], (cidx, pos, zero, zero))
+    with jax.named_scope("kv_slab_read"):
+        k_slab = jax.lax.dynamic_index_in_dim(k_cache, cidx, 0, keepdims=False)
+        v_slab = jax.lax.dynamic_index_in_dim(v_cache, cidx, 0, keepdims=False)
+    out = _attend(cfg, att, lp)(q, k_slab, v_slab, pos)
+    out = matmul_any(out.reshape(T, -1), lp["wo"], layer, name="wo")
+    return out, dict(cache, **{kk: k_cache, vk: v_cache})
+
+
+@jax.named_scope("attention")
+def _attn_block_batched(cfg: ModelConfig, att: str, lp: dict, rope: dict, x,
+                        cache, pos, layer, cidx):
+    """B independent sequences, one token each: x [B, dim], pos [B]; the
+    caches carry the row axis after the layer axis."""
+    B = x.shape[0]
+    kk, vk = CACHE_KEYS[att]
+    ck, sk = ROPE_KEYS[att]
+    q, k, v = _qkv(cfg, att, lp, x, layer)
+    cos = rope[ck][pos][:, None, :]
+    sin = rope[sk][pos][:, None, :]
+    q, k = _rope(cfg, q, cos, sin), _rope(cfg, k, cos, sin)
+    k_cache, v_cache = cache[kk], cache[vk]
+    if att == "window":
+        rows = jnp.arange(B, dtype=jnp.int32)
+        slots = jnp.mod(pos, k_cache.shape[2])
+        with jax.named_scope("kv_ring_write"):
+            k_cache = k_cache.at[cidx, rows, slots].set(k.astype(k_cache.dtype))
+            v_cache = v_cache.at[cidx, rows, slots].set(v.astype(v_cache.dtype))
+    else:
+        k_cache, v_cache = llama._write_kv_rows(
+            k_cache, v_cache, k[:, None], v[:, None], cidx, pos)
+    slab_k, slab_v = llama._layer_slabs(k_cache, v_cache, cidx)
+    attend = _attend(cfg, att, lp)
+    out = jax.vmap(lambda qb, ks, vs, p: attend(qb[None], ks, vs, p)[0])(
+        q, slab_k, slab_v, pos)
+    out = matmul_any(out.reshape(B, -1), lp["wo"], layer, name="wo")
+    return out, dict(cache, **{kk: k_cache, vk: v_cache})
+
+
+def _ffn(cfg: ModelConfig, ffn: str, lp: dict, x, layer, live):
+    """The FFN half on the residual after attention -> (x, picks or None)."""
+    if ffn == "dense":
+        return x + llama._dense_ffn(cfg, lp, x, lp["rms_ffn"], layer=layer), None
+    xb = rmsnorm(x, lp["rms_ffn"], cfg.norm_eps)
+    picks = None
+    if live is not None:
+        # the same product as inside moe_ffn: the compiler keeps one
+        topi, _ = route_topk(cfg, lp["moe_router"], xb, lp.get("moe_bias"))
+        picks = pick_counts(cfg, topi, live)
+    return x + moe_ffn(cfg, lp, xb, layer), picks
+
+
+def _run_layers(cfg: ModelConfig, params: dict, rope: dict, x, cache: dict,
+                pos, attn_block, live=None):
+    """Every run of like layers in turn. -> (x, cache, picks [3] or None)"""
+    picks = None if live is None else jnp.zeros((3,), jnp.int32)
+    for kind, p0, c0, count in cfg.plan_runs:
+        stack = params["layers"][kind_name(kind)]
+
+        def step(carry, i, kind=kind, stack=stack, p0=p0, c0=c0):
+            x, cache, picks = carry
+            idx = jnp.int32(p0) + i
+            lp = {name: (leaf if isinstance(leaf, QuantTensor)
+                         else jax.lax.dynamic_index_in_dim(
+                             leaf, idx, 0, keepdims=False))
+                  for name, leaf in stack.items()}
+            att_out, cache = attn_block(cfg, kind[0], lp, rope, x, cache, pos,
+                                        idx, jnp.int32(c0) + i)
+            x, got = _ffn(cfg, kind[1], lp, x + att_out, idx, live)
+            if got is not None:
+                picks = picks + got
+            return (x, cache, picks), None
+
+        if count == 1:
+            (x, cache, picks), _ = step((x, cache, picks), jnp.int32(0))
+        else:
+            (x, cache, picks), _ = jax.lax.scan(
+                step, (x, cache, picks), jnp.arange(count, dtype=jnp.int32))
+    return x, cache, picks
+
+
+def _logits(cfg: ModelConfig, params: dict, x):
+    x = rmsnorm(x, params["rms_final"], cfg.norm_eps)
+    logits = matmul_any(x, params["wcls"], name="wcls").astype(jnp.float32)
+    return logits * cfg.logit_scale if cfg.logit_scale != 1.0 else logits
+
+
+def forward(cfg: ModelConfig, params: dict, rope: dict, tokens, cache: dict,
+            pos, last_pos=None) -> tuple:
+    """T tokens of one sequence from ``pos`` -> (logits [T, vocab] f32, or
+    [1, vocab] at row ``last_pos``; the new cache tree)."""
+    x = llama.embed(cfg, params, tokens)
+    x, cache, _ = _run_layers(cfg, params, rope, x, cache, pos, _attn_block)
+    if last_pos is not None:
+        x = jax.lax.dynamic_slice_in_dim(x, last_pos, 1, axis=0)
+    return _logits(cfg, params, x), cache
+
+
+def forward_batched(cfg: ModelConfig, params: dict, rope: dict, tokens,
+                    cache: dict, pos, live=None) -> tuple:
+    """One decode step for B independent sequences -> (logits [B, vocab],
+    cache), and with ``live`` [B] (bool: the rows that are decoding) a third
+    value, int32 [3], summed over the expert layers: the live rows' picks
+    that fell on held experts, all their picks, and the distinct held
+    experts they picked (``moe.pick_counts``)."""
+    x = llama.embed(cfg, params, tokens)
+    x, cache, picks = _run_layers(cfg, params, rope, x, cache, pos,
+                                  _attn_block_batched, live)
+    logits = _logits(cfg, params, x)
+    return (logits, cache) if live is None else (logits, cache, picks)

@@ -551,12 +551,21 @@ def device_random_params(
 
 
 def init_cache(cfg: ModelConfig, cache_dtype=jnp.float32) -> dict:
-    """Fixed-size per-layer KV cache [L, seq_len, n_kv_heads, head_size]."""
+    """Fixed-size per-layer KV cache [L, seq_len, n_kv_heads, head_size]
+    (a layer plan: one stack an attention kind, ``models.layer_plan``)."""
+    if cfg.layer_plan:
+        from dllama_tpu.models import layer_plan
+
+        return layer_plan.init_cache(cfg, cache_dtype)
     shape = (cfg.n_layers, cfg.seq_len, cfg.n_kv_heads, cfg.head_size)
     return {"k": jnp.zeros(shape, cache_dtype), "v": jnp.zeros(shape, cache_dtype)}
 
 
 def rope_tables(cfg: ModelConfig) -> dict:
+    if cfg.layer_plan:
+        from dllama_tpu.models import layer_plan
+
+        return layer_plan.rope_tables(cfg)
     cos, sin = rope_table(cfg.seq_len, cfg.head_size, cfg.rope_theta)
     return {"cos": jnp.asarray(cos), "sin": jnp.asarray(sin)}
 
@@ -819,7 +828,18 @@ def forward(
     'q80'), and the fused norm+reduce epilogue folds residual-add + rmsnorm
     into the scattered shard so the one gather per sub-block carries the
     next matmul's already-normalized input. Quantized shard_map path only.
+
+    A configuration with a ``layer_plan`` (layers of different kinds) runs
+    ``models.layer_plan.forward``: runs of like layers, a cache tree by
+    attention kind; single device only.
     """
+    if cfg.layer_plan:
+        from dllama_tpu.models import layer_plan
+
+        if tp_axis is not None:
+            cfg.refuse_for_plan("the tensor-parallel forward (--tp > 1)")
+        return layer_plan.forward(cfg, params, rope, tokens, cache, pos,
+                                  last_pos=last_pos)
     x = embed(cfg, params, tokens)
     layers = params["layers"]
     quant_scan = any(isinstance(v, QuantTensor) for v in layers.values())
@@ -918,6 +938,10 @@ def init_batch_cache(cfg: ModelConfig, batch: int, cache_dtype=jnp.float32,
     ``cfg.seq_len``) — the bucketed slot pools allocate short-context slabs
     for short rows; attention masks by ``pos``, so a slab shorter than the
     model context is exact as long as every row's pos stays inside it."""
+    if cfg.layer_plan:
+        from dllama_tpu.models import layer_plan
+
+        return layer_plan.init_batch_cache(cfg, batch, cache_dtype, seq_len)
     S = cfg.seq_len if seq_len is None else seq_len
     shape = (cfg.n_layers, batch, S, cfg.n_kv_heads, cfg.head_size)
     return {"k": jnp.zeros(shape, cache_dtype), "v": jnp.zeros(shape, cache_dtype)}
@@ -1050,6 +1074,7 @@ def forward_batched(
     tp_compress: bool = False,
     allow_flash: bool = True,
     tp_reduce=None,
+    live=None,
 ) -> tuple:
     """One decode step for B independent sequences -> (logits [B, vocab], cache).
 
@@ -1064,7 +1089,17 @@ def forward_batched(
     ``allow_flash=False``: caller runs under pjit with sharded dense params
     (see ``forward``) — pin the dense xs-scan.
     ``tp_reduce``: the row-parallel wo/w2 reduce path, see ``forward``.
+    ``live`` [B] bool (a ``layer_plan`` only, see ``forward``): the rows
+    that are decoding; a third value then counts what they routed to
+    (``layer_plan.forward_batched``).
     """
+    if cfg.layer_plan:
+        from dllama_tpu.models import layer_plan
+
+        if tp_axis is not None:
+            cfg.refuse_for_plan("the tensor-parallel forward (--tp > 1)")
+        return layer_plan.forward_batched(cfg, params, rope, tokens, cache,
+                                          pos, live=live)
     x = embed(cfg, params, tokens)
     layers = params["layers"]
     quant_scan = any(isinstance(v, QuantTensor) for v in layers.values())
@@ -1204,6 +1239,7 @@ def forward_batched_overlap(
     boundaries the ring gathers do. Row mode is NOT bit-identical to the
     monolithic gather path (split-K reassociation); it IS the same math as
     the non-overlap row-parallel step, microbatch-split exactly."""
+    cfg.refuse_for_plan("the microbatch-overlap forward (--tp-overlap)")
     B = tokens.shape[0]
     h = _check_overlap_split(cfg, B)
     ga = _overlap_axis(tp_axis, ring)
@@ -1390,6 +1426,7 @@ def forward_batched_verify(
     (quant-TP, parallel.quant_tp.make_tp_verify_batched) — local heads +
     kv-shard caches, the same activation gathers as ``forward_batched``.
     """
+    cfg.refuse_for_plan("the speculative verify step (--spec-draft)")
     B, T = tokens.shape
     x = embed(cfg, params, tokens)  # [B, T, dim]
     layers = params["layers"]
@@ -1454,6 +1491,7 @@ def forward_batched_verify_overlap(
     weights stream once per layer. ``tp_reduce`` composes the same way as
     in ``forward_batched_overlap``: each half runs the row-parallel
     ``_verify_layer`` against the ring axis."""
+    cfg.refuse_for_plan("the speculative verify step (--spec-draft)")
     B, T = tokens.shape
     h = _check_overlap_split(cfg, B)
     ga = _overlap_axis(tp_axis, ring)
@@ -1534,6 +1572,7 @@ def forward_train(
     The sequence axis of ``tokens`` must be sharded over ``sp_axis`` in ring
     order (plain ``P(..., "sp")`` contiguous chunks).
     """
+    cfg.refuse_for_plan("forward_train")
     use_ring = mesh is not None and mesh.shape.get(sp_axis, 1) > 1
     T = tokens.shape[1]
     x = embed(cfg, params, tokens)

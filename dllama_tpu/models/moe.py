@@ -48,8 +48,14 @@ from dllama_tpu.parallel.collectives import gather_columns as _gather
 
 @jax.named_scope("moe_router")
 def route_topk(cfg: ModelConfig, router_kernel: jnp.ndarray,
-               xb: jnp.ndarray) -> tuple:
+               xb: jnp.ndarray, bias: jnp.ndarray | None = None) -> tuple:
     """Top-k routing -> (indices [..., k], renormalized weights [..., k]).
+
+    ``cfg.router`` "sigmoid_bias" (with ``bias`` [E], the per-expert
+    correction): the scores are sigmoids, the bias only CHOOSES (top k of
+    score + bias) and the chosen experts' unbiased scores weigh,
+    renormalized to sum 1. The indices are over all ``cfg.n_experts`` the
+    router scores, whichever of them this process holds.
 
     Router math runs in f32 like the reference (router matmul outputs F32,
     `/root/reference/src/grok1-tasks.cpp:56-60`); selected probabilities are
@@ -58,17 +64,44 @@ def route_topk(cfg: ModelConfig, router_kernel: jnp.ndarray,
     agree exactly or decode would diverge from prefill on the same weights.
     """
     logits = xb.astype(jnp.float32) @ router_kernel.astype(jnp.float32)  # [..., E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    topv, topi = jax.lax.top_k(probs, cfg.n_active_experts)
+    if cfg.router == "sigmoid_bias":
+        scores = jax.nn.sigmoid(logits)
+        _, topi = jax.lax.top_k(scores + bias.astype(jnp.float32),
+                                cfg.n_active_experts)
+        topv = jnp.take_along_axis(scores, topi, axis=-1)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        topv, topi = jax.lax.top_k(probs, cfg.n_active_experts)
     weights = topv / topv.sum(axis=-1, keepdims=True)  # renormalize over selected
     return topi, weights
 
 
-def route(cfg: ModelConfig, router_kernel: jnp.ndarray, xb: jnp.ndarray) -> jnp.ndarray:
-    """Top-k routing -> dense combine weights [..., E] (zeros off the top-k)."""
-    topi, weights = route_topk(cfg, router_kernel, xb)
+def route(cfg: ModelConfig, router_kernel: jnp.ndarray, xb: jnp.ndarray,
+          bias: jnp.ndarray | None = None) -> jnp.ndarray:
+    """Top-k routing -> dense combine weights [..., E] (zeros off the top-k)
+    over the experts this process holds: a row that chose none of them has a
+    row of zeros, and a row's weights sum to 1 only over ALL its picks."""
+    topi, weights = route_topk(cfg, router_kernel, xb, bias)
     one_hot = jax.nn.one_hot(topi, cfg.n_experts, dtype=jnp.float32)  # [..., k, E]
-    return jnp.einsum("...ke,...k->...e", one_hot, weights.astype(jnp.float32))
+    combine = jnp.einsum("...ke,...k->...e", one_hot, weights.astype(jnp.float32))
+    if cfg.n_experts_held != cfg.n_experts:
+        combine = combine[..., cfg.expert_first:
+                          cfg.expert_first + cfg.expert_count]
+    return combine
+
+
+def pick_counts(cfg: ModelConfig, topi: jnp.ndarray,
+                live: jnp.ndarray) -> jnp.ndarray:
+    """What the ``live`` rows [T] (bool) chose, from ``route_topk``'s indices
+    [T, k] -> int32 [3]: their picks that fell on held experts, all their
+    picks, and the distinct held experts they picked."""
+    held = ((topi >= cfg.expert_first)
+            & (topi < cfg.expert_first + cfg.n_experts_held)
+            & live[:, None])
+    hot = jax.nn.one_hot(topi - cfg.expert_first, cfg.n_experts_held,
+                         dtype=jnp.bool_) & held[..., None]
+    return jnp.stack([held.sum(), live.sum() * topi.shape[-1],
+                      hot.any(axis=(0, 1)).sum()]).astype(jnp.int32)
 
 
 def _flat_experts(qt: QuantTensor) -> QuantTensor:
@@ -159,10 +192,11 @@ def _moe_decode_selected(cfg: ModelConfig, lp: dict, xb: jnp.ndarray, layer,
     2 collectives per MoE FFN, like the dense FFN's pair.
     """
     act = ACTIVATIONS[cfg.hidden_act]
-    E, k = cfg.n_experts, cfg.n_active_experts
+    E, k = cfg.n_experts_held, cfg.n_active_experts
     T = xb.shape[0]
     cap = min(E, T * k)
-    combine = route(cfg, lp["moe_router"], xb)  # [T, E] f32, zero off top-k
+    # [T, E] f32, zero off top-k
+    combine = route(cfg, lp["moe_router"], xb, lp.get("moe_bias"))
     # every expert any row selected has a positive combine weight somewhere,
     # and there are at most T*k of them — the top `cap` column-maxima cover
     # the whole union (extra slots carry zero weight and contribute nothing)
@@ -210,7 +244,11 @@ def moe_ffn(cfg: ModelConfig, lp: dict, xb: jnp.ndarray, layer=None,
 
     lp holds: moe_router [dim, E], moe_up/moe_gate [E, dim, hidden],
     moe_down [E, hidden, dim] — each expert stack a dense array or a
-    quantized (QuantTensor) stack. With ``layer`` (the scalar-prefetch scan),
+    quantized (QuantTensor) stack — and, for the "sigmoid_bias" router,
+    moe_bias [E]. Where the process holds a share of the experts
+    (``cfg.expert_count``) the stacks hold those, the router still scores
+    all E, and the result is the held experts' PART of the sum: what expert
+    parallelism adds up across processes. With ``layer`` (the scalar-prefetch scan),
     quantized stacks carry a leading layer axis and dense leaves arrive
     already layer-indexed. ``tp_axis`` (inside shard_map, quantized TP):
     expert stacks are output shards; the hidden activation is gathered
@@ -222,7 +260,7 @@ def moe_ffn(cfg: ModelConfig, lp: dict, xb: jnp.ndarray, layer=None,
         isinstance(lp.get(n), QuantTensor) for n in up_names + ("moe_down",)
     )
     if (layer is not None and quant_experts and xb.ndim == 2
-            and xb.shape[0] * cfg.n_active_experts < cfg.n_experts):
+            and xb.shape[0] * cfg.n_active_experts < cfg.n_experts_held):
         return _moe_decode_selected(cfg, lp, xb, layer, tp_axis, tp_compress)
 
     # Under the layer scan, EVERY QuantTensor stack is layer-stacked and needs
@@ -230,8 +268,9 @@ def moe_ffn(cfg: ModelConfig, lp: dict, xb: jnp.ndarray, layer=None,
     # hidden_dim % 64 != 0 load fallback), which arrives already layer-indexed
     # and ignores base. A global quant_experts gate here would feed a 4D
     # [L, E, ...] stack into the per-expert slicing scan below.
-    base = layer * cfg.n_experts if layer is not None else None
-    combine = route(cfg, lp["moe_router"], xb).astype(xb.dtype)  # [..., E]
+    base = layer * cfg.n_experts_held if layer is not None else None
+    combine = route(cfg, lp["moe_router"], xb,
+                    lp.get("moe_bias")).astype(xb.dtype)  # [..., E]
 
     if "moe_upgate" in lp:  # fused up|gate expert stacks (llama.fuse_qkv_ffn)
         ug = _expert_up(xb, lp["moe_upgate"], base, "expert_upgate")

@@ -8,6 +8,14 @@ MXU-friendly einsum, and prefill processes T query positions at once under a
 causal mask — numerically identical, shapes static for XLA.
 
 Softmax runs in f32 whatever the activation dtype (the reference is all-f32).
+
+Layers of a model with a layer plan add three things, all off by default (a
+uniform model's call traces to what it did): value heads narrower than the
+query/key heads (the output takes the values' width), a sliding window over
+a RING cache (``window``: slot ``s`` holds the latest position ``p <= pos +
+T - 1`` with ``p % S == s``, and query ``i`` sees ``i - window < p <= i``),
+and a sink (one learned score a head that joins the softmax's denominator
+and carries no value).
 """
 
 from __future__ import annotations
@@ -18,10 +26,12 @@ import jax.numpy as jnp
 def gqa_attention(
     q: jnp.ndarray,  # [T, n_heads, head_size]
     k_cache: jnp.ndarray,  # [S, n_kv_heads, head_size]
-    v_cache: jnp.ndarray,  # [S, n_kv_heads, head_size]
+    v_cache: jnp.ndarray,  # [S, n_kv_heads, v_head_size]
     pos: jnp.ndarray,  # scalar int32: position of q[0] in the sequence
+    window: int = 0,  # > 0: the caches are rings of S slots, see above
+    sink: jnp.ndarray | None = None,  # [n_heads] f32
 ) -> jnp.ndarray:
-    """Masked GQA attention. Returns [T, n_heads, head_size].
+    """Masked GQA attention. Returns [T, n_heads, v_head_size].
 
     The cache must already contain this step's K/V at positions pos..pos+T-1.
     Query t attends to cache positions <= pos + t; everything later is masked.
@@ -38,11 +48,25 @@ def gqa_attention(
 
     key_idx = jnp.arange(S, dtype=jnp.int32)[None, :]  # [1, S]
     query_pos = pos + jnp.arange(T, dtype=jnp.int32)[:, None]  # [T, 1]
-    mask = key_idx <= query_pos  # [T, S]
+    if window:
+        # the position each ring slot holds: the latest one written into it
+        last = pos + (T - 1)
+        key_idx = last - jnp.mod(last - key_idx, S)
+        mask = ((key_idx <= query_pos) & (key_idx > query_pos - window)
+                & (key_idx >= 0))
+    else:
+        mask = key_idx <= query_pos  # [T, S]
     scores = jnp.where(mask[:, None, None, :], scores, jnp.float32(-1e30))
 
-    att = jnp.exp(scores - scores.max(axis=-1, keepdims=True))
-    att = att / att.sum(axis=-1, keepdims=True)
+    top = scores.max(axis=-1, keepdims=True)
+    if sink is not None:
+        sk = sink.astype(jnp.float32).reshape(1, n_kv_heads, group, 1)
+        top = jnp.maximum(top, sk)
+    att = jnp.exp(scores - top)
+    den = att.sum(axis=-1, keepdims=True)
+    if sink is not None:
+        den = den + jnp.exp(sk - top)
+    att = att / den
 
     out = jnp.einsum("tkgs,skh->tkgh", att, vf)
-    return out.reshape(T, n_heads, head_size).astype(q.dtype)
+    return out.reshape(T, n_heads, vf.shape[-1]).astype(q.dtype)

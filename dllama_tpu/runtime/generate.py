@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from dllama_tpu import faults, observability
-from dllama_tpu.models import llama
+from dllama_tpu.models import layer_plan, llama
 from dllama_tpu.models.config import ModelConfig
 from dllama_tpu.runtime import paged_kv
 from dllama_tpu.runtime.sampler import SamplerConfig, sample_dynamic
@@ -250,7 +250,31 @@ class Engine:
                 "dllama_tp_reduce_chunks_total",
                 "Decode/verify dispatches served by the row-parallel "
                 "(K-sharded wo/w2, ring reduce-scatter) TP programs")
+            # a model with a layer plan only (models/layer_plan.py): what the
+            # pooled decode step's live rows routed to, and the pools' KV
+            self._m_moe_picks = metrics.counter(
+                "dllama_moe_picks_total",
+                "Expert picks of the pooled decode step's live rows, by "
+                "whether the picked expert is held here (held=\"1\") or on "
+                "another chip of the deployment (held=\"0\")",
+                labelnames=("held",))
+            self._m_moe_active = metrics.counter(
+                "dllama_moe_active_experts_total",
+                "Distinct held experts the live rows picked, summed over "
+                "expert layers and decode steps")
+            self._m_moe_layer_steps = metrics.counter(
+                "dllama_moe_layer_steps_total",
+                "Expert layers x decode steps the two counters above were "
+                "summed over (a chunk adds its steps x the expert layers)")
+            self._m_kv_resident = metrics.gauge(
+                "dllama_kv_resident_bytes",
+                "Bytes of the slot pools' KV caches by attention kind "
+                "(kind=\"full\": grows with the slab's context; "
+                "kind=\"window\": rings, the same at any context)",
+                labelnames=("kind",))
         else:
+            self._m_moe_picks = self._m_moe_active = None
+            self._m_moe_layer_steps = self._m_kv_resident = None
             self._m_prefill = self._m_step = self._m_chunk = None
             self._m_prefill_chunk = self._m_migrations = None
             self._m_live_rows = None
@@ -264,6 +288,8 @@ class Engine:
         self.cfg = cfg
         self.sampler_cfg = sampler_cfg
         self.mesh = mesh
+        if mesh is not None:
+            cfg.refuse_for_plan("a tensor-parallel engine (--tp > 1)")
         self.numeric_checks = numeric_checks
         self._tp_compress = tp_compress
         #: machine-visible wire/overlap resolution (served on /stats):
@@ -560,6 +586,8 @@ class Engine:
             batched forward — called twice under tp_overlap (monolithic
             fwd_b and the microbatch-overlap variant) so both programs run
             the byte-identical scan/sampler/watchdog body."""
+            if cfg.layer_plan:
+                return _make_decode_loop_batch_plan()
 
             @partial(jax.jit, donate_argnums=(2,),
                      static_argnames=("n_steps",))
@@ -607,6 +635,53 @@ class Engine:
                 return out, cache, keys, ok  # out [n_steps, B], ok [B]
 
             return _decode_loop_batch
+
+        def _make_decode_loop_batch_plan():
+            """The pooled decode program of a model with a layer plan: the
+            same steps as above, and beside the tokens what the ``live`` rows
+            [B] routed to, summed over the chunk (``moe.pick_counts``: int32
+            [3]). Its own program, so that a uniform model's keeps its
+            fingerprint; the name stays (``jit__decode_loop_batch``)."""
+
+            @partial(jax.jit, donate_argnums=(2,),
+                     static_argnames=("n_steps",))
+            def _decode_loop_batch(params, rope, cache, tokens, pos, keys,
+                                   temps, topps, poison, live, n_steps):
+                def body(carry, _):
+                    cache, toks, pos_, keys_, ok, picks = carry
+                    logits, cache, got = llama.forward_batched(
+                        cfg, params, rope, toks, cache, pos_, live=live)
+                    logits, ok = _health(logits, poison, ok)
+                    with jax.named_scope("sample"):
+                        split = jax.vmap(jax.random.split)(keys_)  # [B, 2, 2]
+                        keys_, subs = split[:, 0], split[:, 1]
+                        nxt = jax.vmap(sample_dynamic)(
+                            logits, subs, temps, topps).astype(jnp.int32)
+                    pos_ = jnp.minimum(pos_ + 1, jnp.int32(cfg.seq_len - 1))
+                    return (cache, nxt, pos_, keys_, ok, picks + got), nxt
+
+                (cache, toks, pos, keys, ok, picks), out = jax.lax.scan(
+                    body,
+                    (cache, tokens, pos, keys,
+                     jnp.ones(tokens.shape, jnp.bool_),
+                     jnp.zeros((3,), jnp.int32)),
+                    length=n_steps,
+                )
+                return out, cache, keys, ok, picks
+
+            def run(params, rope, cache, tokens, pos, keys, temps, topps,
+                    poison, n_steps, live=None):
+                """A caller that names no live rows (a fixed batch) counts
+                all of them and gets the four values of a uniform model's
+                program; the slot pool names them and gets the picks too."""
+                mask = (jnp.ones(tokens.shape, jnp.bool_) if live is None
+                        else live)
+                out = _decode_loop_batch(params, rope, cache, tokens, pos,
+                                         keys, temps, topps, poison, mask,
+                                         n_steps=n_steps)
+                return out[:4] if live is None else out
+
+            return run
 
         def _make_decode_loop_paged(fwd_b):
             """The paged twin of _make_decode_loop_batch — same
@@ -1078,6 +1153,9 @@ class Engine:
         faults.fire("prefill")
         if chunk is not None and chunk < 1:
             raise ValueError(f"prefill chunk must be >= 1, got {chunk}")
+        if self.prefill_piece_cap:
+            chunk = min(chunk or self.prefill_piece_cap,
+                        self.prefill_piece_cap)
         if chunk is None or chunk >= len(tokens):
             return self._prefill_piece(cache, tokens, pos)
         logits = None
@@ -1086,6 +1164,14 @@ class Engine:
             logits, cache = self._prefill_piece(cache, tokens[i:i + chunk],
                                                 pos + i)
         return logits, cache
+
+    @property
+    def prefill_piece_cap(self) -> int:
+        """The most tokens one prefill forward may take (0: any). A window
+        layer's ring bounds it (``ModelConfig.max_prefill_piece``); a piece
+        is padded to a bucket, so the cap is the largest bucket under it."""
+        cap = self.cfg.max_prefill_piece
+        return max((b for b in PREFILL_BUCKETS if b <= cap), default=cap)
 
     def _prefill_piece(self, cache: dict, tokens, pos: int) -> tuple:
         """One bucketed prefill forward (validated by the callers)."""
@@ -1535,6 +1621,7 @@ class Engine:
         advancing — its emissions are already taken, and its (per-row) cache
         slab can't affect other rows.
         """
+        self.cfg.refuse_for_plan("speculative decoding (--spec-draft)")
         if not prompts or any(not p for p in prompts):
             raise ValueError("generate_batch_spec needs non-empty prompts")
         if not self.supports_batch_spec:
@@ -1717,6 +1804,8 @@ class Engine:
                 "generate_spec needs at least one token to feed — an empty "
                 "prompt requires a session with a pending_token"
             )
+        # a rejected draft's K/V would overwrite ring slots still in a window
+        self.cfg.refuse_for_plan("speculative decoding (--spec-draft)")
         steps = min(steps, self.cfg.seq_len - pos - len(prompt_tokens))
 
         t0 = time.perf_counter()
@@ -2023,6 +2112,9 @@ class BatchSession:
         self.max_batch = max_batch
         self.chunk = chunk
         self.paged = kv_pages > 0
+        if self.paged:
+            eng.cfg.refuse_for_plan("the paged KV pool (--kv-pages), and "
+                                    "with it export_row / KV transfer")
         self.bucket_kv = bool(bucket_kv) and not self.paged
         self.prefill_chunk = max(0, int(prefill_chunk))
         S = eng.cfg.seq_len
@@ -2102,6 +2194,7 @@ class BatchSession:
             # the classic resident slab, pre-allocated so the pool never
             # grows and handles stay the historical slot indices 0..B-1
             self._pools[S] = _BucketPool(eng, S, max_batch)
+            self._publish_kv_resident()
 
     # -- introspection ----------------------------------------------------
     @property
@@ -2386,12 +2479,29 @@ class BatchSession:
         pool = self._pools.get(ctx)
         if pool is None:
             pool = self._pools[ctx] = _BucketPool(self.eng, ctx, 1)
+            self._publish_kv_resident()
         for r in range(pool.cap):
             if pool.rows[r] is None:
                 return pool, r
         r = pool.cap
         pool.grow(self.eng)
+        self._publish_kv_resident()
         return pool, r
+
+    def kv_resident_bytes(self) -> dict:
+        """Bytes of the slot pools' caches by attention kind: a uniform
+        model's are all ``full``; a window layer's rings (``window``) are
+        the same whatever the pools' contexts."""
+        out = {"full": 0, "window": 0}
+        for pool in self._pools.values():
+            for kind, n in layer_plan.kv_resident_bytes(pool.cache).items():
+                out[kind] += n
+        return out
+
+    def _publish_kv_resident(self) -> None:
+        if self.eng._m_kv_resident is not None:
+            for kind, n in self.kv_resident_bytes().items():
+                self.eng._m_kv_resident.set(n, kind=kind)
 
     # -- lifecycle --------------------------------------------------------
     def admit(self, prompt_tokens: list, steps: int,
@@ -2636,6 +2746,8 @@ class BatchSession:
             n = budget if budget is not None else self.prefill_chunk
             if n <= 0:
                 n = len(prefix) - pf.cursor
+            if self.eng.prefill_piece_cap:
+                n = min(n, self.eng.prefill_piece_cap)
             piece = prefix[pf.cursor:pf.cursor + n]
             _, pf.cache = self.eng._prefill_piece(pf.cache, piece, pf.cursor)
         with observability.phase("prefill_wait", "engine", "device",
@@ -2968,16 +3080,25 @@ class BatchSession:
             if not live:
                 continue
             with observability.phase("decode_dispatch", "engine") as dispatch:
-                chunk, pool.cache, keys, ok = self.eng.batch_loop(len(live))(
+                plan = {}
+                if self.eng.cfg.layer_plan:
+                    # its program also counts what the live rows routed to
+                    mask = np.zeros((pool.cap,), np.bool_)
+                    mask[live] = True
+                    plan["live"] = jnp.asarray(mask)
+                chunk, pool.cache, keys, ok, *picks = self.eng.batch_loop(
+                    len(live))(
                     pool.cache, jnp.asarray(pool.tokens),
                     jnp.asarray(pool.pos), jnp.asarray(pool.keys),
                     jnp.asarray(pool.temps), jnp.asarray(pool.topps),
-                    self.eng._poison_rows(pool.cap), n_steps=self.chunk)
+                    self.eng._poison_rows(pool.cap), n_steps=self.chunk,
+                    **plan)
             with observability.phase("decode_wait", "engine", "device"):
                 arr = np.asarray(chunk)  # [chunk, cap]
             # reads issued after the program ended: the device idles
             with observability.phase("decode_fetch", "engine") as fetch:
                 okh = np.asarray(ok)  # [cap]
+                picked = np.asarray(picks[0]) if picks else None
                 pool.tokens = np.array(chunk[-1])  # np.array: writable copies
                 pool.keys = np.array(keys)
                 # mirror the in-program per-row pin across chunk boundaries
@@ -2986,7 +3107,23 @@ class BatchSession:
             with observability.phase("account", "engine"):
                 self._observe_chunk(dispatch.t0, fetch.t1, len(live))
                 self._account_chunk(pool, live, arr, okh, fresh)
+                if picked is not None:
+                    self._account_picks(picked)
         return fresh
+
+    def _account_picks(self, picked) -> None:
+        """One chunk's routing of a model with expert layers of which a
+        share is held (``moe.pick_counts``, summed over the chunk): picks on
+        held experts, all picks, distinct held experts a layer-step."""
+        eng = self.eng
+        if eng._m_moe_picks is None:
+            return
+        held, total, active = (int(v) for v in picked)
+        eng._m_moe_picks.inc(held, held="1")
+        eng._m_moe_picks.inc(total - held, held="0")
+        eng._m_moe_active.inc(active)
+        eng._m_moe_layer_steps.inc(
+            self.chunk * eng.cfg.plan_count(ffn="moe"))
 
     def _observe_chunk(self, t0: float, t1: float, live: int) -> None:
         """One decode launch's wall time (its dispatch, wait and fetch

@@ -1254,9 +1254,17 @@ class ServerState:
         self.default_sampler = default_sampler
         self.default_seed = default_seed
         self.spec_draft = spec_draft
+        # layers of different kinds: refused at start-up, in one line each
+        if spec_draft > 0:
+            cfg.refuse_for_plan("speculative decoding (--spec-draft)")
+        if kv_pages > 0:
+            cfg.refuse_for_plan("the paged KV pool (--kv-pages), and with it "
+                                "export_row / KV transfer")
         if role not in ("prefill", "decode", "both"):
             raise ValueError(
                 f"role must be prefill/decode/both, got {role!r}")
+        if role != "both":
+            cfg.refuse_for_plan(f"a {role} replica (KV transfer)")
         self.role = role
         self.ckpt_interval = max(0, int(ckpt_interval))
         self.session_cache = max(1, session_cache)

@@ -291,6 +291,51 @@ def test_pooled_decode_chunk_writes_rows_not_slabs(one_chip, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# a model whose layers differ in kind, at its published widths: 192-wide q/k
+# and 128-wide v heads, 2048-wide experts, a 16384-wide dense layer, a
+# ragged 13568- / 14848-column wqkv (the benchmark's third configuration)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("program", ["forward T=64", "forward_batched"])
+def test_layer_plan_programs_compile_at_published_widths(one_chip,
+                                                         monkeypatch, program):
+    """The prefill piece and the pooled decode step of
+    ``benchmarks/configs/mimo-v2-flash-d13-e32-q40.json``, as the compile
+    rehearsal (``benchmarks/rehearse_compile.py``) takes them from the
+    family's ``rehearsal``: whatever the v5e compiler refuses of them is
+    found here, not on the chip. Both fit one chip beside their weights."""
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(os.path.join(root, "benchmarks"))
+    import families
+
+    with open(os.path.join(
+            root, "benchmarks/configs/mimo-v2-flash-d13-e32-q40.json")) as f:
+        conf = json.load(f)
+    interpret = qmatmul._interpret_default
+    try:
+        listed = families.load(conf).rehearsal(conf)
+        name, fn, args, static = next(
+            r for r in listed if r[0].startswith(program))
+        compiled = fn.lower(*_shapes(args, one_chip), **static).compile()
+    finally:
+        qmatmul._interpret_default = interpret  # rehearsal() sets it
+    assert _has_kernel(compiled)
+    kernels = set(_kernel_names(compiled))
+    assert {"wqkv_q40_matmul", "wo_q40_matmul", "w13_q40_matmul",
+            "w2_q40_matmul", "expert_upgate_q40_matmul",
+            "expert_down_q40_matmul", "wcls_q40_matmul"} <= kernels, kernels
+    text = compiled.as_text()
+    for scope in ("attention_full", "attention_window", "kv_ring_write",
+                  "moe_router"):
+        assert scope in text, scope
+    m = compiled.memory_analysis()
+    assert (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes) < HBM_BYTES
+
+
+# ---------------------------------------------------------------------------
 # the names the benchmark's trace readers depend on
 # ---------------------------------------------------------------------------
 
