@@ -310,12 +310,17 @@ def build_parser() -> argparse.ArgumentParser:
                 default=-1,
                 metavar="N",
                 help="prompt tokens consumed per scheduler tick during "
-                "pooled admission: a long prompt no longer stalls resident "
-                "rows for its whole prefill — the pool keeps decoding "
-                "between N-token pieces, and each piece is bit-identical "
-                "to monolithic prefill; -1 = auto (batch-chunk x "
-                "batch-max, one decode-chunk's worth of compute), "
-                "0 = monolithic",
+                "pooled admission. A uniform model on one device with slab "
+                "pools carries them INSIDE the decode chunk: each of its "
+                "batch-chunk steps takes N / batch-chunk tokens of the "
+                "oldest waiting prompt beside its decode rows (N is rounded "
+                "down to a multiple of batch-chunk, at least one token a "
+                "step), so the weights are read once for both; a prompt "
+                "that finds nothing decoding, and every prompt under "
+                "--kv-pages, --tp > 1 or a layer plan, takes one standalone "
+                "N-token piece a tick between decode chunks. -1 = auto "
+                "(batch-chunk x batch-max, twice that where prompts ride "
+                "the chunk), 0 = monolithic",
             )
             sp.add_argument(
                 "--kv-buckets",

@@ -979,10 +979,47 @@ def _layer_slabs(k_cache, v_cache, layer):
                 jax.lax.dynamic_index_in_dim(v_cache, layer, 0, keepdims=False))
 
 
+def _ride_step(rope: dict, pos, ride, slab_len: int) -> dict:
+    """What a decode step that carries riders needs of its ``ride`` =
+    ``(tokens [t], row, start, n)``, worked out ONCE a step, outside the
+    layer loop, over the step's B decode rows followed by its t riders:
+    ``cos`` / ``sin`` the rope angles of every row's position; ``rows`` /
+    ``cols`` the pool row and slot where each row's K/V land (a decode row
+    at its clamped position, as ``_write_kv_rows`` clamps; a rider at
+    ``start + i`` of ``row``; a padded rider, ``i >= n``, at the SLAB's
+    length: out of bounds, so the scatter drops it and it touches no slot);
+    ``row`` / ``start`` for the riders' attention."""
+    tokens, row, start, n = ride
+    i = jnp.arange(tokens.shape[0], dtype=jnp.int32)
+    at = jnp.concatenate([pos, start + i])
+    return {
+        "row": row, "start": start,
+        "rows": jnp.concatenate([jnp.arange(pos.shape[0], dtype=jnp.int32),
+                                 jnp.full(i.shape, row, jnp.int32)]),
+        "cols": jnp.concatenate([jnp.clip(pos, 0, slab_len - 1),
+                                 jnp.where(i < n, start + i, slab_len)]),
+        # a gather clamps a padded rider's position past the table
+        "cos": rope["cos"][at][:, None, :], "sin": rope["sin"][at][:, None, :],
+    }
+
+
+def _write_rows_at(k_cache, v_cache, k, v, layer, rows, cols):
+    """Land the K/V rows ``k``/``v`` ``[n, kv, hd]`` at ``(layer, rows[i],
+    cols[i])`` of the stacked caches (``layer`` None: of this layer's
+    ``[B, S, kv, hd]`` slab): ``_write_kv_rows``'s in-place scatter for a
+    step whose rows are not one a sequence (decode rows and riders
+    together). A column out of bounds drops its row."""
+    idx = (rows, cols) if layer is None else (layer, rows, cols)
+    with jax.named_scope("kv_slab_write"):
+        return (k_cache.at[idx].set(k.astype(k_cache.dtype), mode="drop"),
+                v_cache.at[idx].set(v.astype(v_cache.dtype), mode="drop"))
+
+
 @jax.named_scope("attention")
 def _attn_block_batched(cfg: ModelConfig, lp: dict, rope: dict, x, k_cache,
                         v_cache, pos, layer=None, tp_axis=None,
-                        tp_compress: bool = False, row_mode: bool = False):
+                        tp_compress: bool = False, row_mode: bool = False,
+                        ride=None):
     """Batched-decode attention: x [B, dim] carries B INDEPENDENT sequences,
     each at its own position pos[b]. The projections are ordinary [B, K]
     matmuls (identical to a T=B prefill row block — the quant kernels need
@@ -995,8 +1032,14 @@ def _attn_block_batched(cfg: ModelConfig, lp: dict, rope: dict, x, k_cache,
     ``tp_axis`` (inside shard_map): local heads + kv-shard cache, activation
     gathers after the head concat and the wo matmul, exactly `_attn_block`.
     ``row_mode``: pre-normalized input, K-sharded wo, f32 partial output —
-    see ``_attn_block``."""
-    B = x.shape[0]
+    see ``_attn_block``.
+    ``ride`` (``_ride_step``, from ``forward_batched``): the rows of ``x``
+    past the B of ``pos`` are prompt tokens of one pool row. They share the
+    projections, the rope and the cache write (one scatter for the step's
+    B + t rows) with the decode rows, and their queries attend their own
+    row's slab alone, causally, after the write."""
+    B = pos.shape[0]
+    t = x.shape[0] - B
     eps = cfg.norm_eps
     if row_mode:  # pre-normalized input; rms_att was applied by the caller
         q = matmul_any(x, lp["wq"], layer, name="wq")
@@ -1010,54 +1053,78 @@ def _attn_block_batched(cfg: ModelConfig, lp: dict, rope: dict, x, k_cache,
         q = _norm_proj(x, lp["rms_att"], lp["wq"], layer, eps, name="wq")
         k = _norm_proj(x, lp["rms_att"], lp["wk"], layer, eps, name="wk")
         v = _norm_proj(x, lp["rms_att"], lp["wv"], layer, eps, name="wv")
-    q = q.reshape(B, -1, cfg.head_size)
-    k = k.reshape(B, -1, cfg.head_size)
-    v = v.reshape(B, -1, cfg.head_size)
-
-    cos = rope["cos"][pos][:, None, :]  # per-row angle: [B, 1, hs/2]
-    sin = rope["sin"][pos][:, None, :]
+    q = q.reshape(B + t, -1, cfg.head_size)
+    k = k.reshape(B + t, -1, cfg.head_size)
+    v = v.reshape(B + t, -1, cfg.head_size)
+    if ride is None:
+        cos = rope["cos"][pos][:, None, :]  # per-row angle: [B, 1, hs/2]
+        sin = rope["sin"][pos][:, None, :]
+    else:
+        cos, sin = ride["cos"], ride["sin"]  # [B + t, 1, hs/2]
     q = apply_rope(q, cos, sin, cfg.rope_style)
 
     fused_kv = (layer is not None
                 and fused_rope_cache.engages(1, k_cache.dtype))
-    if fused_kv:
+    # this step's rows go where they live, in the scan's donated carry,
+    # before whichever attention reads them (write-before-attend)
+    if ride is not None and not fused_kv:
+        k = apply_rope(k, cos, sin, cfg.rope_style)
+        k_cache, v_cache = _write_rows_at(k_cache, v_cache, k, v, layer,
+                                          ride["rows"], ride["cols"])
+    elif fused_kv:
         # DLLAMA_FUSE_ROPE_CACHE=1: rotate each row's K in-kernel and land
         # K/V at (layer, b, pos[b]) in one pass — bit-identical to the
         # scatter/DUS writes below, including their end-of-sequence clamp
+        kd, vd, cd, sd = ((k, v, cos, sin) if ride is None
+                          else (k[:B], v[:B], cos[:B], sin[:B]))
         k_cache, v_cache = fused_rope_cache.rope_cache_update_batched(
-            k, v, cos, sin, k_cache, v_cache, pos, layer, cfg.rope_style)
+            kd, vd, cd, sd, k_cache, v_cache, pos, layer, cfg.rope_style)
+        if ride is not None:  # the kernel knows the decode rows only
+            k_cache, v_cache = _write_rows_at(
+                k_cache, v_cache,
+                apply_rope(k[B:], cos[B:], sin[B:], cfg.rope_style), v[B:],
+                layer, ride["rows"][B:], ride["cols"][B:])
     else:
         k = apply_rope(k, cos, sin, cfg.rope_style)
+        if layer is None:
+            # dense xs-scan: the carry IS this layer's slab
+            with jax.named_scope("kv_slab_write"):
+                write = jax.vmap(
+                    lambda c, kk, p: jax.lax.dynamic_update_slice_in_dim(
+                        c, kk[None].astype(c.dtype), p, axis=0))
+                k_cache, v_cache = (write(k_cache, k, pos),
+                                    write(v_cache, v, pos))
+        else:
+            # layer scan: the stacked cache rides the carry
+            k_cache, v_cache = _write_kv_rows(
+                k_cache, v_cache, k[:, None], v[:, None], layer, pos)
+    if ride is not None:
+        q_r, q = q[B:], q[:B]
 
-    # this step's rows go where they live, in the scan's donated carry,
-    # before whichever attention reads them (write-before-attend)
-    if layer is None:
-        # dense xs-scan: the carry IS this layer's slab
-        with jax.named_scope("kv_slab_write"):
-            write = jax.vmap(
-                lambda c, kk, p: jax.lax.dynamic_update_slice_in_dim(
-                    c, kk[None].astype(c.dtype), p, axis=0))
-            k_cache, v_cache = write(k_cache, k, pos), write(v_cache, v, pos)
-    elif not fused_kv:
-        # layer scan: the stacked cache rides the carry
-        k_cache, v_cache = _write_kv_rows(
-            k_cache, v_cache, k[:, None], v[:, None], layer, pos)
-
+    slabs = None
     if (layer is not None
             and flash_decode.engages(1, k_cache.shape[2], k_cache.dtype)):
         # the kernel reads each row's OWN live prefix from the stacked cache
         out = flash_decode.flash_decode_attention_batched(
             q, k_cache, v_cache, pos, layer)  # [B, local heads, hs]
     else:
-        slab_k, slab_v = ((k_cache, v_cache) if layer is None
-                          else _layer_slabs(k_cache, v_cache, layer))
+        slabs = ((k_cache, v_cache) if layer is None
+                 else _layer_slabs(k_cache, v_cache, layer))
         out = jax.vmap(
             lambda qb, ks, vs, p: gqa_attention(qb[None], ks, vs, p)[0]
-        )(q, slab_k, slab_v, pos)  # [B, local heads, hs]
+        )(q, *slabs, pos)  # [B, local heads, hs]
+    if ride is not None:
+        if slabs is None:
+            slabs = _layer_slabs(k_cache, v_cache, layer)
+        row_k, row_v = (jax.lax.dynamic_index_in_dim(s, ride["row"], 0,
+                                                     keepdims=False)
+                        for s in slabs)
+        out = jnp.concatenate(
+            [out, gqa_attention(q_r, row_k, row_v, ride["start"])], axis=0)
     if row_mode:  # local heads -> K-sharded wo: no gathers, f32 partials
         return (matmul_any(out.reshape(B, -1), lp["wo"], layer, name="wo")
                 .astype(jnp.float32), k_cache, v_cache)
-    out = _gather(out.reshape(B, -1), tp_axis, tp_compress)
+    out = _gather(out.reshape(B + t, -1), tp_axis, tp_compress)
     return (_gather(matmul_any(out, lp["wo"], layer, name="wo"), tp_axis,
                     tp_compress), k_cache, v_cache)
 
@@ -1075,6 +1142,7 @@ def forward_batched(
     allow_flash: bool = True,
     tp_reduce=None,
     live=None,
+    ride=None,
 ) -> tuple:
     """One decode step for B independent sequences -> (logits [B, vocab], cache).
 
@@ -1092,6 +1160,17 @@ def forward_batched(
     ``live`` [B] bool (a ``layer_plan`` only, see ``forward``): the rows
     that are decoding; a third value then counts what they routed to
     (``layer_plan.forward_batched``).
+    ``ride`` = ``(tokens [t], row, pos, n)``: the prompt rides the decode
+    step. The first ``n`` of ``t`` further tokens are the next of the prompt
+    that pool row ``row`` is waiting on, the first of them at position
+    ``pos``. Their embedded rows are appended to the B decode rows, so every
+    projection and the FFN (an MoE's expert scans too) stream their weights
+    once for ``[B + t, K]``; rope, the cache write and attention are their
+    own (``_attn_block_batched``), and the classifier sees the B decode rows
+    only: a prompt's last token is fed by its row's first decode step, so no
+    rider needs logits. Single device, a uniform model; the row must not be
+    decoding (its decode-side position pins at the slab's last slot, which
+    no rider writes or attends).
     """
     if cfg.layer_plan:
         from dllama_tpu.models import layer_plan
@@ -1100,6 +1179,10 @@ def forward_batched(
             cfg.refuse_for_plan("the tensor-parallel forward (--tp > 1)")
         return layer_plan.forward_batched(cfg, params, rope, tokens, cache,
                                           pos, live=live)
+    B = tokens.shape[0]
+    if ride is not None:
+        tokens = jnp.concatenate([tokens, ride[0]])
+        ride = _ride_step(rope, pos, ride, cache["k"].shape[2])
     x = embed(cfg, params, tokens)
     layers = params["layers"]
     quant_scan = any(isinstance(v, QuantTensor) for v in layers.values())
@@ -1137,7 +1220,7 @@ def forward_batched(
                 return (x, k_cache, v_cache), None
             att_out, k_cache, v_cache = _attn_block_batched(
                 cfg, lp, rope, x, k_cache, v_cache, pos, layer=idx,
-                tp_axis=tp_axis, tp_compress=tp_compress)
+                tp_axis=tp_axis, tp_compress=tp_compress, ride=ride)
             x = _ffn_residual(cfg, lp, x, att_out, tp_axis, tp_compress, layer=idx)
             return (x, k_cache, v_cache), None
 
@@ -1150,13 +1233,15 @@ def forward_batched(
             lp, k_cache, v_cache = layer
             att_out, k_cache, v_cache = _attn_block_batched(
                 cfg, lp, rope, x, k_cache, v_cache, pos,
-                tp_axis=tp_axis, tp_compress=tp_compress)
+                tp_axis=tp_axis, tp_compress=tp_compress, ride=ride)
             x = _ffn_residual(cfg, lp, x, att_out, tp_axis, tp_compress)
             return x, (k_cache, v_cache)
 
         x, (new_k, new_v) = jax.lax.scan(
             layer_step, x, (layers, cache["k"], cache["v"])
         )
+    if ride is not None:
+        x = x[:B]  # the riders' rows end with the last layer's K/V
     if row:
         x = _row_norm_gather(x, params["rms_final"], tp_axis, tp_compress,
                              cfg.norm_eps, cfg.dim)

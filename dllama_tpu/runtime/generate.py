@@ -205,6 +205,18 @@ class Engine:
             self._m_prefill_chunk = metrics.histogram(
                 "dllama_prefill_chunk_ms",
                 "Incremental prefill chunk wall time (chunked admission)")
+            self._m_prefill_tokens = metrics.counter(
+                "dllama_prefill_tokens_total",
+                "Prompt tokens written to a pool row's KV by the way they "
+                "got there: how=\"ride\" beside the decode rows of a pooled "
+                "decode launch, how=\"piece\" by a standalone prefill piece",
+                labelnames=("how",))
+            self._m_ride_slots = metrics.counter(
+                "dllama_ride_slots_total",
+                "Prompt-token slots (steps x width) of the pooled decode "
+                "launches that carried a riding prompt; "
+                "dllama_prefill_tokens_total{how=\"ride\"} of them were "
+                "filled, the rest was padding")
             self._m_live_rows = metrics.histogram(
                 "dllama_decode_live_rows",
                 "Rows a pooled decode launch advances (live: resident, "
@@ -278,6 +290,7 @@ class Engine:
             self._m_prefill = self._m_step = self._m_chunk = None
             self._m_prefill_chunk = self._m_migrations = None
             self._m_live_rows = None
+            self._m_prefill_tokens = self._m_ride_slots = None
             self._m_quarantine = None
             self._m_spec_steps = self._m_spec_accepted = None
             self._m_spec_emitted = None
@@ -592,7 +605,8 @@ class Engine:
             @partial(jax.jit, donate_argnums=(2,),
                      static_argnames=("n_steps",))
             def _decode_loop_batch(params, rope, cache, tokens, pos, keys,
-                                   temps, topps, poison, n_steps):
+                                   temps, topps, poison, ride=None, *,
+                                   n_steps):
                 """N batched decode steps fused into one program: every step
                 streams the weights ONCE for all B sequences
                 (llama.forward_batched) and samples each row on device. A row
@@ -611,12 +625,26 @@ class Engine:
                 ``ok`` [B] accumulates each row's watchdog flag over the
                 chunk; a poisoned row's garbage stays confined to its own row
                 (per-row sampling, per-row cache slab) — siblings are
-                bit-identical."""
+                bit-identical.
 
-                def body(carry, _):
+                ``ride`` int32 ``[n_steps, t + 3]``: the prompt rides the
+                chunk. Step s's line holds ``t`` prompt tokens, then the
+                pool row that waits on them, the position of the first, and
+                how many of the ``t`` are real (0: nobody rides this step):
+                the step carries them beside its decode rows and writes
+                their K/V into that row's slab (``llama.forward_batched``);
+                the sampler, the watchdog and the outputs see the decode
+                rows only. One operand, so that a launch copies one more
+                array to the device, not four. A caller without a slot pool
+                (a fixed batch, the ``--tp`` wrappers' ``fwd_b``) names no
+                riders and traces the program as it was."""
+
+                def body(carry, ride_s):
                     cache, toks, pos_, keys_, ok = carry
+                    with_ride = {} if ride_s is None else {"ride": (
+                        ride_s[:-3], ride_s[-3], ride_s[-2], ride_s[-1])}
                     logits, cache = fwd_b(cfg, params, rope, toks, cache,
-                                          pos_)
+                                          pos_, **with_ride)
                     logits, ok = _health(logits, poison, ok)
                     with jax.named_scope("sample"):
                         split = jax.vmap(jax.random.split)(keys_)  # [B, 2, 2]
@@ -630,7 +658,7 @@ class Engine:
                     body,
                     (cache, tokens, pos, keys,
                      jnp.ones(tokens.shape, jnp.bool_)),
-                    length=n_steps,
+                    ride, length=n_steps,
                 )
                 return out, cache, keys, ok  # out [n_steps, B], ok [B]
 
@@ -1081,6 +1109,14 @@ class Engine:
         if self._m_reduce is not None:
             self._m_reduce.inc()
 
+    @property
+    def pooled_rides(self) -> bool:
+        """Whether the pooled decode program takes riding prompt tokens
+        (``_decode_loop_batch``'s ``ride``): the uniform models' program on
+        one device. A layer plan's pooled program and the ``--tp`` wrappers
+        are programs of their own and prefill by standalone pieces."""
+        return self.mesh is None and not self.cfg.layer_plan
+
     def batch_loop(self, rows: int):
         """The fused batched-decode chunk program for a dispatch with
         ``rows`` live rows — the overlap twin when built and engaged,
@@ -1173,12 +1209,16 @@ class Engine:
         cap = self.cfg.max_prefill_piece
         return max((b for b in PREFILL_BUCKETS if b <= cap), default=cap)
 
-    def _prefill_piece(self, cache: dict, tokens, pos: int) -> tuple:
-        """One bucketed prefill forward (validated by the callers)."""
+    def _prefill_piece(self, cache: dict, tokens, pos: int,
+                       bucket: Optional[int] = None) -> tuple:
+        """One bucketed prefill forward (validated by the callers).
+        ``bucket``: pad to this many rows instead of the tokens' own bucket
+        (a caller that wants one program for every piece it sends)."""
         # clamp the padded bucket to the remaining context: an out-of-range
         # dynamic_update_slice start would be silently clamped by XLA, writing
         # K/V into wrong slots with wrong rope angles
-        bucket = min(prefill_bucket(len(tokens)), self.cfg.seq_len - pos)
+        bucket = min(max(bucket or 0, prefill_bucket(len(tokens))),
+                     self.cfg.seq_len - pos)
         self._last_prefill_bucket = bucket
         padded = np.zeros(bucket, np.int32)
         padded[: len(tokens)] = tokens
@@ -1934,14 +1974,23 @@ class _SlotState:
 class _PendingPrefill:
     """A chunked admission's in-flight prompt state (admit_begin)."""
 
-    __slots__ = ("prompt", "scfg", "cache", "cursor", "pub_nodes",
+    __slots__ = ("prompt", "scfg", "cache", "cursor", "rides", "pub_nodes",
                  "scattered")
 
-    def __init__(self, prompt: list, scfg: SamplerConfig, cache: dict):
+    def __init__(self, prompt: list, scfg: SamplerConfig,
+                 cache: Optional[dict]):
         self.prompt = prompt
         self.scfg = scfg
-        self.cache = cache  # single-sequence [L, S, kv, hd] being filled
+        # the staging cache: single-sequence [L, S, kv, hd] being filled by
+        # standalone pieces. None while the way is undecided in a session
+        # whose prompts can ride, and for good once the row rides: its K/V
+        # are written where they live, in its pool row
+        self.cache = cache
         self.cursor = 0  # prompt-prefix tokens already prefilled
+        #: the way the prompt reaches the cache, decided at its first tokens
+        #: and kept: True rides the pool's decode chunks, False takes
+        #: standalone pieces into ``cache``, None not begun
+        self.rides: Optional[bool] = None
         # paged publish-at-admit state: the radix nodes this admission
         # created ready=False (index-aligned with the row's blocks; None
         # where another row's node already existed), and the token count
@@ -2092,10 +2141,14 @@ class BatchSession:
     (bucket, capacity) shape; capacities double, bounding retraces.
 
     Slot-slab reuse needs no clearing: admitting a multi-token prompt
-    overwrites the slot's whole attended window (_batch_cache_insert), and
+    overwrites the slot's whole attended window (_batch_cache_insert, or
+    token by token where the prompt rides the decode chunks), and
     a 1-token prompt starts at pos 0 where overwrite-before-attend holds —
     every position <= pos is written by the CURRENT occupant before any of
     its queries attends it; stale garbage sits only at masked positions.
+    A row whose prompt is riding is not live yet: like a free row it is
+    stepped at the slab's last slot, which no rider writes or attends, and
+    ``_go_live`` sets its true position.
     Migration copies the row's whole slab, i.e. its entire attended
     history, so the invariant carries across buckets.
     """
@@ -2117,6 +2170,19 @@ class BatchSession:
                                     "with it export_row / KV transfer")
         self.bucket_kv = bool(bucket_kv) and not self.paged
         self.prefill_chunk = max(0, int(prefill_chunk))
+        #: prompt tokens one step of a pool's decode chunk carries beside
+        #: its decode rows: prefill_chunk tokens a tick over the chunk's
+        #: steps, at least one. 0 where prompts never ride: monolithic
+        #: admission, the paged loop, and an engine whose pooled program
+        #: is another (``Engine.pooled_rides``)
+        self.ride_t = (max(1, self.prefill_chunk // chunk)
+                       if self.prefill_chunk > 0 and not self.paged
+                       and eng.pooled_rides else 0)
+        #: what the last step_chunk's riders did, for the scheduler's marks:
+        #: (handle, launch start, launch end, prefix complete), the times on
+        #: time.monotonic as ``piece_span``'s
+        self.rode: list = []
+        self._no_ride = None  # the ride operand of a launch nobody rides
         S = eng.cfg.seq_len
         if self.paged:
             # page size must divide the model context so logical blocks tile
@@ -2536,16 +2602,24 @@ class BatchSession:
                     sampler: Optional[SamplerConfig] = None,
                     stop_tokens: tuple = (), span_id: int = 0) -> int:
         """Reserve a row for the prompt WITHOUT prefilling it: the prompt
-        is consumed incrementally by ``prefill_step`` calls, interleaved
-        with ``step_chunk``, so resident rows keep emitting tokens while a
-        long prompt fills its cache. Once live, the row's stream is
-        bit-identical to a monolithic admit() of the same request: the
-        chunked prefill runs the same bucketed forwards at the same
-        positions into the same slab, and the sampler chain starts from the
-        same fresh PRNGKey. 1-token prompts have nothing to prefill and go
-        live immediately. ``span_id`` (the request's ``RequestTrace`` track)
-        rides on the row's prefill phase spans, so a tick's spans and the
-        request's track can be joined."""
+        is consumed incrementally, so resident rows keep emitting tokens
+        while it fills its cache. One of two ways, decided at the prompt's
+        first tokens and kept. If its pool launches a decode chunk then (a
+        row of it decodes, or a prompt already rides in it) and the
+        session's prompts can ride (``ride_t``), ``step_chunk`` carries
+        ``ride_t`` of its tokens in each step of the pool's chunks and
+        writes their K/V straight into the reserved row: no staging cache,
+        no insert, no pass of its own over the weights. Otherwise
+        ``prefill_step`` runs standalone pieces into a staging cache between
+        chunks and inserts it into the row when the prefix is complete.
+        Once live, the row's stream is that of a monolithic admit() of the
+        same request: every prompt token's K/V is computed at its own
+        position and written before any later query attends, and the
+        sampler chain starts from the same fresh PRNGKey. 1-token prompts
+        have nothing to prefill and go live immediately. ``span_id`` (the
+        request's ``RequestTrace`` track) is carried by the row's prefill
+        and go-live phase spans, so a tick's spans and the request's track
+        can be joined."""
         if self._closed:
             raise RuntimeError("batch session is closed")
         if not prompt_tokens:
@@ -2597,8 +2671,11 @@ class BatchSession:
         else:
             faults.fire("prefill")
             st.prefilling = True
+            # where prompts can ride, a staging cache waits for the row's
+            # first standalone piece: a riding row never has one
             self._prefills[handle] = _PendingPrefill(
-                list(prompt_tokens), scfg, self.eng.new_cache())
+                list(prompt_tokens), scfg,
+                None if self.ride_t else self.eng.new_cache())
         return handle
 
     def _admit_begin_paged(self, prompt_tokens: list, steps: int,
@@ -2727,21 +2804,38 @@ class BatchSession:
         step_chunk decodes it). Returns None when nothing is pending.
         Picks the OLDEST pending admission when ``handle`` is None — FIFO,
         so one call per scheduler tick bounds every resident row's stall to
-        one prefill chunk of compute."""
+        one prefill chunk of compute.
+
+        Where prompts can ride (``ride_t``) this is the way of a prompt
+        whose pool launches no chunk at its first tokens: nothing decodes
+        there whose inter-token gap a separate pass would stretch, and a
+        chunk would carry ``chunk x ride_t`` tokens at the price of a whole
+        decode launch. A prompt whose pool does launch is left to
+        ``step_chunk`` and skipped here, so with every pending prompt riding
+        this returns None. The way is decided at the prompt's first tokens
+        and kept; naming a ``handle`` that has not begun decides it for
+        standalone pieces."""
         if self._closed:
             raise RuntimeError("batch session is closed")
         if handle is None:
-            handle = next((h for h in self._prefills
-                           if not self._slots[h].done), None)
+            handle = next((h for h, pf in self._prefills.items()
+                           if not self._slots[h].done
+                           and not self._takes_ride(h, pf)), None)
             if handle is None:
                 return None
         pf = self._prefills.get(handle)
         if pf is None:
             raise ValueError(f"slot {handle} has no pending prefill")
+        if pf.rides:
+            raise ValueError(f"slot {handle}'s prompt rides the decode "
+                             "chunks (step_chunk advances it)")
         st = self._slots[handle]
         faults.fire("prefill_chunk")
         with observability.phase("prefill_dispatch", "engine",
                                  span_id=st.span_id) as dispatch:
+            pf.rides = False
+            if pf.cache is None:
+                pf.cache = self.eng.new_cache()
             prefix = pf.prompt[:-1]
             n = budget if budget is not None else self.prefill_chunk
             if n <= 0:
@@ -2749,7 +2843,15 @@ class BatchSession:
             if self.eng.prefill_piece_cap:
                 n = min(n, self.eng.prefill_piece_cap)
             piece = prefix[pf.cursor:pf.cursor + n]
-            _, pf.cache = self.eng._prefill_piece(pf.cache, piece, pf.cursor)
+            # where prompts ride, a standalone piece is the rare case (a
+            # burst at an idle pool) and the last piece of a prompt would
+            # be a program of its own for each bucket under the piece's
+            # that no warm-up is sure to have met: every piece is padded to
+            # the whole piece's bucket, one program, at the same price (a
+            # piece streams the planes once whatever its rows)
+            _, pf.cache = self.eng._prefill_piece(
+                pf.cache, piece, pf.cursor,
+                bucket=prefill_bucket(n) if self.ride_t else None)
         with observability.phase("prefill_wait", "engine", "device",
                                  span_id=st.span_id) as wait:
             jax.block_until_ready(pf.cache)
@@ -2761,6 +2863,7 @@ class BatchSession:
             st.prefill_ms += dt
             if self.eng._m_prefill_chunk is not None:
                 self.eng._m_prefill_chunk.observe(dt)
+                self.eng._m_prefill_tokens.inc(len(piece), how="piece")
             pf.cursor += len(piece)
             if self.paged:
                 self._scatter_published(handle, pf)
@@ -2779,14 +2882,29 @@ class BatchSession:
                 del self._prefills[handle]
                 st.prefilling = False
         if finished:
-            # its PRNGKey is a device program and a sync of its own
-            with observability.phase("go_live", "engine",
-                                     span_id=st.span_id) as last:
-                self._go_live(handle, pf.prompt, pf.scfg)
-                if self.eng._m_prefill is not None:
-                    self.eng._m_prefill.observe(st.prefill_ms)
+            last = self._go_live_phase(handle, pf)
         self.piece_span = (dispatch.t0, last.t1)
         return handle, finished
+
+    def _takes_ride(self, handle: int, pf: _PendingPrefill) -> bool:
+        """Whether this pending prompt reaches its row by riding the pool's
+        decode chunks. Undecided, it will if its pool launches a chunk this
+        tick (``_pool_launches``): ``step_chunk`` then gives it its first
+        tokens and records the way."""
+        if pf.rides is not None:
+            return pf.rides
+        return self.ride_t > 0 and self._pool_launches(self._where[handle][0])
+
+    def _pool_launches(self, pool) -> bool:
+        """A pool's next step_chunk runs its program when a row of it
+        decodes, or when a prompt already rides in it: a rider whose pool
+        lost its last live row has the chunk launched for it."""
+        for h in pool.rows:
+            if h is None or self._slots[h].done:
+                continue
+            if not self._slots[h].prefilling or self._prefills[h].rides:
+                return True
+        return False
 
     def _scatter_published(self, handle: int, pf: _PendingPrefill) -> None:
         """Land the staging cache's newly completed full blocks in their
@@ -2971,6 +3089,18 @@ class BatchSession:
                 self.eng._m_prefix_misses.inc()
         return handle
 
+    def _go_live_phase(self, handle: int, pf: _PendingPrefill):
+        """The ``go_live`` phase of the tick that ends a prompt, by either
+        way: the row's decode state (its PRNGKey is a device program and a
+        sync of its own) and the request's whole prefill time."""
+        st = self._slots[handle]
+        with observability.phase("go_live", "engine",
+                                 span_id=st.span_id) as phase:
+            self._go_live(handle, pf.prompt, pf.scfg)
+            if self.eng._m_prefill is not None:
+                self.eng._m_prefill.observe(st.prefill_ms)
+        return phase
+
     def _go_live(self, handle: int, prompt_tokens: list,
                  scfg: SamplerConfig) -> None:
         """Arm the row's decode state: pending last prompt token, position,
@@ -3026,6 +3156,16 @@ class BatchSession:
         the device when nothing is live. Mid-prefill rows are skipped until
         their prefill completes.
 
+        The prompt rides the chunk (``ride_t`` > 0): a pool that launches
+        carries, in each step, up to ``ride_t`` tokens of the oldest pending
+        prompt whose row lives in that pool and which did not begin in a
+        staging cache; where its prefix ends the next prompt starts at the
+        next step of the same chunk. The K/V land in the rows' own slabs.
+        After the launch the cursors advance and a row whose prefix is
+        complete goes live: it decodes from the next chunk. A pool whose
+        only work is a riding prompt launches for it. ``rode`` lists what
+        the riders of this call did.
+
         Bucketed sessions first migrate any live row that would outgrow its
         slab within this chunk, then run one program per occupied bucket,
         smallest first — a row migrated this tick decodes this tick, in its
@@ -3039,8 +3179,10 @@ class BatchSession:
         sampler chains and cache slabs; nothing crosses rows)."""
         if self._closed:
             raise RuntimeError("batch session is closed")
+        self.rode = []
         if not any(not st.done and not st.prefilling
-                   for st in self._slots.values()):
+                   for st in self._slots.values()) \
+                and not any(pf.rides for pf in self._prefills.values()):
             return {}
         faults.fire("step_chunk")
         if self.paged:
@@ -3077,8 +3219,10 @@ class BatchSession:
                         if pool.rows[r] is not None
                         and not self._slots[pool.rows[r]].done
                         and not self._slots[pool.rows[r]].prefilling]
-            if not live:
-                continue
+                if not live and not (self.ride_t
+                                     and self._pool_launches(pool)):
+                    continue
+                riders = self._plan_ride(pool) if self.ride_t else ()
             with observability.phase("decode_dispatch", "engine") as dispatch:
                 plan = {}
                 if self.eng.cfg.layer_plan:
@@ -3086,6 +3230,8 @@ class BatchSession:
                     mask = np.zeros((pool.cap,), np.bool_)
                     mask[live] = True
                     plan["live"] = jnp.asarray(mask)
+                if self.ride_t:
+                    plan["ride"] = self._ride_operand(riders)
                 chunk, pool.cache, keys, ok, *picks = self.eng.batch_loop(
                     len(live))(
                     pool.cache, jnp.asarray(pool.tokens),
@@ -3109,7 +3255,78 @@ class BatchSession:
                 self._account_chunk(pool, live, arr, okh, fresh)
                 if picked is not None:
                     self._account_picks(picked)
+                arrived = self._land_riders(riders, dispatch.t0, fetch.t1)
+            for h, pf in arrived:
+                self._go_live_phase(h, pf)
         return fresh
+
+    def _plan_ride(self, pool) -> list:
+        """The prompt tokens this launch of ``pool`` carries: [(handle,
+        first step, cursor after)], oldest pending prompt first. Each takes
+        ``ride_t`` tokens a step from its cursor until its prefix ends, the
+        next one starts at the next step, until the chunk's steps are
+        taken. A prompt that gets its first tokens here rides from now on;
+        one that began in a staging cache is not asked."""
+        out, step = [], 0
+        for h, pf in self._prefills.items():
+            if step >= self.chunk:
+                break
+            if (pf.rides is False or self._where[h][0] is not pool
+                    or self._slots[h].done):
+                continue
+            pf.rides = True
+            left = len(pf.prompt) - 1 - pf.cursor
+            steps = min(self.chunk - step, -(-left // self.ride_t))
+            out.append((h, step, pf.cursor + min(left, steps * self.ride_t)))
+            step += steps
+        return out
+
+    def _ride_operand(self, riders: list) -> jax.Array:
+        """``_decode_loop_batch``'s ``ride`` for one launch: a line a step,
+        ``ride_t`` tokens then (row, position of the first, how many are
+        real). A launch that nobody rides gets the same zeros every time."""
+        t = self.ride_t
+        if not riders:
+            if self._no_ride is None:
+                self._no_ride = jnp.zeros((self.chunk, t + 3), jnp.int32)
+            return self._no_ride
+        faults.fire("prefill_chunk")
+        lines = np.zeros((self.chunk, t + 3), np.int32)
+        for h, step, end in riders:
+            pf, row = self._prefills[h], self._where[h][1]
+            for at in range(pf.cursor, end, t):
+                n = min(t, end - at)
+                lines[step, :n] = pf.prompt[at:at + n]
+                lines[step, t:] = (row, at, n)
+                step += 1
+        return jnp.asarray(lines)
+
+    def _land_riders(self, riders: list, t0: float, t1: float) -> list:
+        """After the launch that carried them: advance the riders' cursors,
+        count their tokens and the launch's wall time as their prefill
+        chunk, note them in ``rode``. Returns the [(handle, pending)] whose
+        prefix is complete, taken off the pending list, for ``_go_live``."""
+        if not riders:
+            return []
+        ms = (t1 - t0) * 1000.0
+        self.prefill_ms += ms
+        arrived, tokens = [], 0
+        for h, _, end in riders:
+            pf, st = self._prefills[h], self._slots[h]
+            tokens += end - pf.cursor
+            pf.cursor = end
+            st.prefill_ms += ms
+            finished = end >= len(pf.prompt) - 1
+            self.rode.append((h, t0, t1, finished))
+            if finished:
+                del self._prefills[h]
+                st.prefilling = False
+                arrived.append((h, pf))
+        if self.eng._m_prefill_chunk is not None:
+            self.eng._m_prefill_chunk.observe(ms)
+            self.eng._m_prefill_tokens.inc(tokens, how="ride")
+            self.eng._m_ride_slots.inc(self.chunk * self.ride_t)
+        return arrived
 
     def _account_picks(self, picked) -> None:
         """One chunk's routing of a model with expert layers of which a

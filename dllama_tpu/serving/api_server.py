@@ -288,6 +288,14 @@ class Batcher:
     #: not hang the connection forever — the chaos suite's no-hang bound)
     DEADLINE_GRACE_S = 5.0
 
+    #: --prefill-chunk -1 where the prompt rides the decode chunk: this many
+    #: times chunk * max_batch prompt tokens a tick. A rider costs the
+    #: launch about 0.7 % a row at 7B width (v5e; PERF.md, PR 28): of 1, 2
+    #: and 4 this is the widest at which a tick with 8 x t riders in it is
+    #: no longer than a tick with a standalone piece in it was, so that the
+    #: gap between a resident row's tokens does not grow
+    RIDE_AUTO_WIDTH = 2
+
     def __init__(self, state, window_ms: float = 15.0, max_batch: int = 8,
                  chunk: int = 8, prefill_chunk: int = -1,
                  kv_buckets: bool = True, kv_bucket_min: int = 0,
@@ -306,12 +314,20 @@ class Batcher:
         #: host round trips per token
         self.chunk = max(1, chunk)
         #: --prefill-chunk: prompt tokens consumed per scheduler tick while
-        #: a long prompt fills its cache (admit_begin/prefill_step).
-        #: < 0 = auto (one decode chunk's worth of token-forwards:
-        #: chunk * max_batch); 0 = monolithic admission (the pre-chunking
+        #: a prompt fills its cache. Where the prompt rides the pool's decode
+        #: chunk (BatchSession.ride_t: a uniform model on one device, slab
+        #: pools) each of the chunk's steps carries prefill_chunk / chunk of
+        #: them beside its decode rows; elsewhere, and for a prompt that
+        #: finds nothing decoding, they are one standalone piece
+        #: (admit_begin/prefill_step). < 0 = auto: one decode chunk's worth
+        #: of token-forwards (chunk * max_batch), RIDE_AUTO_WIDTH times that
+        #: where prompts ride; 0 = monolithic admission (the pre-chunking
         #: behavior: every resident row stalls for the whole prefill)
-        self.prefill_chunk = (self.chunk * self.max_batch
-                              if prefill_chunk < 0 else int(prefill_chunk))
+        rides = (int(kv_pages) <= 0
+                 and getattr(state.engine, "pooled_rides", False))
+        self.prefill_chunk = (
+            self.chunk * self.max_batch * (self.RIDE_AUTO_WIDTH if rides else 1)
+            if prefill_chunk < 0 else int(prefill_chunk))
         #: --kv-buckets: length-bucketed slot pools under the same modeled
         #: HBM budget (more resident rows for short traffic); off = the
         #: classic uniform [L, max_batch, S, kv, hd] slab
@@ -778,9 +794,17 @@ class Batcher:
             slot_map[b] = s
 
     def _stream_out(self, sess, slot_map: dict, fresh: dict) -> None:
-        """The tail of a scheduler tick (its ``stream_out`` phase): hand
-        every live row's fresh burst to its waiter, release rows that
-        finished, export or checkpoint where the slot asks for it."""
+        """The tail of a scheduler tick (its ``stream_out`` phase): mark
+        the prompts that rode this tick's decode launch on their requests'
+        traces (the launch is their prefill chunk), hand every live row's
+        fresh burst to its waiter, release rows that finished, export or
+        checkpoint where the slot asks for it."""
+        for b, t0, t1, finished in sess.rode:
+            s = slot_map.get(b)
+            if s is not None:
+                s.mark_prefill_chunk(t0, t1)
+                if finished:
+                    s.mark_prefill(sess.prefill_ms_of(b))
         for b, burst in fresh.items():
             s = slot_map[b]
             s.tokens.extend(burst)
@@ -859,7 +883,16 @@ class Batcher:
         until the pool drains AND no arrivals are waiting. Every admitted
         row is bit-identical to its solo run (BatchSession's invariant);
         the session is closed on exit so the pool cache's HBM is held only
-        while traffic needs it."""
+        while traffic needs it.
+
+        What a tick does with a waiting prompt: where the session's prompts
+        ride (``BatchSession.ride_t``), the decode launch itself carries up
+        to ``prefill_chunk`` tokens of the oldest waiting prompts in its
+        steps, so the planes are read once for both and the tick has no
+        prefill phases. ``prefill_step`` then finds something to do only
+        for a prompt that has nothing decoding beside it (a burst at an
+        idle pool): one standalone piece a tick, as on the paths that never
+        ride (a layer plan, ``--kv-pages``, ``--tp``)."""
         st = self.state
         stop_ids = st.stop_token_ids()
         waiting = list(batch)
@@ -890,11 +923,13 @@ class Batcher:
                     with observability.phase("reap_admit", "scheduler"):
                         self._reap_admit(sess, stop_ids, waiting, slot_map,
                                          preempted)
-                    # ONE incremental prefill piece per tick (FIFO): the
-                    # oldest pending prompt advances by <= prefill_chunk
-                    # tokens, so every resident row's inter-token gap is
-                    # bounded by one prefill chunk + one decode chunk instead
-                    # of a whole monolithic prompt
+                    # at most ONE standalone prefill piece per tick (FIFO):
+                    # the oldest pending prompt that does not ride the decode
+                    # chunk advances by <= prefill_chunk tokens, so every
+                    # resident row's inter-token gap is bounded by one
+                    # prefill chunk + one decode chunk instead of a whole
+                    # monolithic prompt; None when every waiting prompt
+                    # rides (step_chunk below carries them)
                     adv = (sess.prefill_step() if self.prefill_chunk > 0
                            else None)
                     with observability.phase("publish", "scheduler"):
@@ -1209,8 +1244,9 @@ class ServerState:
         ``queue_depth``: max concurrent requests admitted (--queue-depth);
         overflow is rejected 429 + Retry-After instead of queuing
         unboundedly.
-        ``prefill_chunk``: prompt tokens per incremental prefill piece in
-        the pooled path (--prefill-chunk; <0 = auto, 0 = monolithic).
+        ``prefill_chunk``: prompt tokens a scheduler tick consumes in the
+        pooled path, riding the decode chunk or as one standalone piece
+        (--prefill-chunk; <0 = auto, 0 = monolithic; ``Batcher``).
         ``kv_buckets``/``kv_bucket_min``: length-bucketed KV slot pools
         (--kv-buckets/--kv-bucket-min) — more resident rows at the same
         modeled HBM budget when traffic skews short.

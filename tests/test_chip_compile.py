@@ -223,7 +223,8 @@ def _instructions(text: str) -> list:
     return out
 
 
-def test_pooled_decode_chunk_writes_rows_not_slabs(one_chip, monkeypatch):
+@pytest.mark.parametrize("t", [0, 16], ids=["no riders", "16 riders a step"])
+def test_pooled_decode_chunk_writes_rows_not_slabs(one_chip, monkeypatch, t):
     """``Engine._decode_loop_batch``'s body (8 steps of ``forward_batched``,
     the per-row sampler, the position clamp; the cache donated and carried)
     at the Mistral cells' shapes: capacity 8, bucket 1024, bf16. Under
@@ -234,7 +235,14 @@ def test_pooled_decode_chunk_writes_rows_not_slabs(one_chip, monkeypatch):
     back). In place also means few temporaries: 0.269 GB here, with nothing
     the size of the 1.07 GB cache among them; B unrolled
     ``dynamic_update_slice``s read 1.075 GB, the whole cache carried in
-    another layout. What the read side became is printed (``pytest -s``)."""
+    another layout. What the read side became is printed (``pytest -s``).
+
+    With riders (PR 28: each step carries ``t`` prompt tokens of one pool row
+    beside the decode rows, the session's ``ride`` operand) every pin holds:
+    the step's ``B + t`` rows go in the one scatter a cache, as much in
+    place; the one row's slab that the riders' queries attend is an eighth
+    of a slab, not a slab. (8 and 32 riders a step compile alike: tried
+    once, PR 28.)"""
     from dllama_tpu.runtime.sampler import sample_dynamic
 
     monkeypatch.setattr(qmatmul, "_interpret_default", lambda: False)
@@ -245,11 +253,13 @@ def test_pooled_decode_chunk_writes_rows_not_slabs(one_chip, monkeypatch):
     cache = jax.eval_shape(
         lambda: llama.init_batch_cache(cfg, rows, jnp.bfloat16, seq_len=ctx))
 
-    def chunk(params, rope, cache, tokens, pos, keys, temps, topps):
-        def body(carry, _):
+    def chunk(params, rope, cache, tokens, pos, keys, temps, topps, ride):
+        def body(carry, ride_s):
             cache, toks, pos_, keys_ = carry
+            with_ride = {} if ride_s is None else {"ride": (
+                ride_s[:-3], ride_s[-3], ride_s[-2], ride_s[-1])}
             logits, cache = llama.forward_batched(cfg, params, rope, toks,
-                                                  cache, pos_)
+                                                  cache, pos_, **with_ride)
             split = jax.vmap(jax.random.split)(keys_)
             nxt = jax.vmap(sample_dynamic)(
                 logits, split[:, 1], temps, topps).astype(jnp.int32)
@@ -257,7 +267,7 @@ def test_pooled_decode_chunk_writes_rows_not_slabs(one_chip, monkeypatch):
             return (cache, nxt, pos_, split[:, 0]), nxt
 
         (cache, *_), out = jax.lax.scan(
-            body, (cache, tokens, pos, keys), length=steps)
+            body, (cache, tokens, pos, keys), ride, length=steps)
         return out, cache
 
     ints, floats = (_s((rows,), jnp.int32, one_chip),
@@ -265,7 +275,8 @@ def test_pooled_decode_chunk_writes_rows_not_slabs(one_chip, monkeypatch):
     compiled = jax.jit(chunk, donate_argnums=(2,)).lower(
         _shapes(params, one_chip), _shapes(rope, one_chip),
         _shapes(cache, one_chip), ints, ints,
-        _s((rows, 2), jnp.uint32, one_chip), floats, floats).compile()
+        _s((rows, 2), jnp.uint32, one_chip), floats, floats,
+        _s((steps, t + 3), jnp.int32, one_chip) if t else None).compile()
     assert _has_kernel(compiled)
 
     instructions = _instructions(compiled.as_text())
@@ -278,7 +289,9 @@ def test_pooled_decode_chunk_writes_rows_not_slabs(one_chip, monkeypatch):
     for name, n, _, _, rest in scatters:
         assert n == cfg.n_layers * slab, name  # the stacked cache itself
         operands = re.findall(r"%([\w.\-]+)", rest.split(")", 1)[0])
-        assert size[operands[2]] == step_rows, (name, operands)
+        # the step's rows: the decode rows' and, with them, the riders'
+        assert size[operands[2]] == step_rows * (rows + t) // rows, (
+            name, operands)
     moved = [i[:4] for i in written if i[1] >= slab and i[3] in (
         "copy", "dynamic-update-slice", "dynamic-slice", "transpose")]
     assert not moved, moved

@@ -94,7 +94,12 @@ def test_chunked_admission_bit_identical_with_resident_row():
     pool where a resident row KEEPS DECODING between prefill pieces. Both
     streams must equal their solo runs bit for bit — the resident row must
     not see the newcomer's prefill, and the newcomer's chunked cache must
-    equal a monolithic one."""
+    equal a monolithic one. Both ways a prompt reaches the cache are here:
+    in the uniform slab the newcomer shares the resident's pool and RIDES
+    its decode chunks (prefill_chunk 5 over 4 steps: one token a step;
+    tests/test_prompt_rides.py has the mechanism's own tests); bucketed, it
+    is placed in a 32-slot pool of its own where nothing decodes, and takes
+    standalone pieces of 5."""
     params = llama.random_params(CFG, seed=1, dtype=np.float32)
     s_res = SamplerConfig(temperature=0.9, topp=0.95, seed=7)
     s_new = SamplerConfig(temperature=1.2, topp=0.9, seed=23)
@@ -117,8 +122,11 @@ def test_chunked_admission_bit_identical_with_resident_row():
         # across several ticks while the resident row nets tokens each tick
         ticks_mid_prefill = 0
         while new in sess.pending_prefills:
-            _, finished = sess.prefill_step()
+            adv = sess.prefill_step()
             fresh = sess.step_chunk()
+            assert (adv is None) == (not bucket_kv)
+            assert [r[0] for r in sess.rode] == ([] if bucket_kv else [new])
+            finished = adv[1] if bucket_kv else sess.rode[0][3]
             if not finished:
                 assert new not in fresh  # not live until the prefix completes
                 ticks_mid_prefill += 1

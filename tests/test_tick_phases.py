@@ -63,6 +63,13 @@ def counter(name: str) -> float:
     return sum(v["value"] for v in values)
 
 
+def prefill_tokens() -> dict:
+    """{how: prompt tokens} of ``dllama_prefill_tokens_total``, now."""
+    snap = ob.default_registry().snapshot()
+    values = snap.get("dllama_prefill_tokens_total", {}).get("values", [])
+    return {v["labels"]["how"]: v["value"] for v in values}
+
+
 def hist(name: str) -> tuple:
     values = ob.default_registry().snapshot().get(name, {}).get("values", [])
     return (sum(v["count"] for v in values), sum(v["sum"] for v in values))
@@ -209,7 +216,9 @@ def test_observability_is_importable_and_spans_work_without_jax():
 # ---------------------------------------------------------------------------
 
 class Pool:
-    """A ServerState whose batcher serves chunked-prefill requests."""
+    """A ServerState whose batcher serves chunked-prefill requests. The slab
+    pool's ladder starts at 64 slots, so the prompts below share one pool:
+    the first of a burst finds nothing decoding, the others ride."""
 
     def __init__(self, kv_pages: int):
         tok = make_tokenizer()
@@ -220,7 +229,7 @@ class Pool:
         self.state = ServerState(
             engine, tok, cfg, model_name="tiny", template="llama3",
             batch_window_ms=30.0, batch_max=4, batch_chunk=CHUNK,
-            prefill_chunk=8, kv_pages=kv_pages)
+            prefill_chunk=8, kv_bucket_min=64, kv_pages=kv_pages)
 
     def burst(self, n: int) -> dict:
         """n requests at once, prompts of 20, 27, 34, ... tokens, 10 output
@@ -268,7 +277,8 @@ def served(pool, tmp_path_factory):
               "ticks": counter("dllama_ticks_total"),
               "chunk": hist("dllama_decode_chunk_ms"),
               "live": hist("dllama_decode_live_rows"),
-              "turn": hist("dllama_prefill_turn_wait_ms")}
+              "turn": hist("dllama_prefill_turn_wait_ms"),
+              "tokens": prefill_tokens()}
     ob.configure_trace(str(path))
     try:
         results = pool.burst(5)
@@ -282,6 +292,8 @@ def served(pool, tmp_path_factory):
         "chunk": sub(hist("dllama_decode_chunk_ms"), before["chunk"]),
         "live": sub(hist("dllama_decode_live_rows"), before["live"]),
         "turn": sub(hist("dllama_prefill_turn_wait_ms"), before["turn"]),
+        "tokens": delta(prefill_tokens(), before["tokens"]),
+        "rides": pool.state.batcher.kv_pages == 0,
     }
 
 
@@ -350,11 +362,57 @@ def test_turn_wait_counts_every_request_and_grows_behind_others_pieces(served):
              for i in sorted(served["results"])]
     # the first admitted prefills at once; the last waits for every piece
     # of the four before it, which its queue wait does not show
-    assert waits[-1] > waits[0] and waits[-1] > 4 * (queue[-1] - queue[0] + 1)
-    # the prefill phases carry the request's track
+    assert waits[-1] > waits[0] and waits[-1] > queue[-1] - queue[0] + 1
+    # the prefill phases carry the request's track. Where the prompt rides
+    # (the slab pool) only the first request finds nothing decoding and
+    # takes standalone pieces; the four behind it have no prefill phase
     spans = {e["args"]["span_id"]
              for e in scheduler_events(served, "prefill_wait")}
-    assert spans == {tr.span_id for _, tr in served["results"].values()}
+    staged = (list(served["results"].values())[:1] if served["rides"]
+              else served["results"].values())
+    assert spans == {tr.span_id for _, tr in staged}
+
+
+def test_a_riding_tick_has_no_prefill_phase_and_marks_its_riders(served):
+    """The slab pool: four of the burst's five prompts ride decode launches.
+    A launch that carried one is its prefill chunk on the request's trace
+    (``mark_prefill_chunk``: the launch's dispatch start to its fetch end,
+    so the turn wait ends where the first tokens reach the device); the
+    tick that ends a prompt goes live after ``account``, and no tick of a
+    rider has a prefill phase (the leaves still tile every tick: the test
+    above). The paged pool never rides: each chunk mark is a piece."""
+    by_tick: dict = {}
+    for e in scheduler_events(served):
+        if e["name"] in PHASES:
+            by_tick.setdefault(e["args"]["tick"], []).append(e)
+    launches = {e["ts"] for e in scheduler_events(served, "decode_dispatch")}
+    pieces = {e["ts"] for e in scheduler_events(served, "prefill_dispatch")}
+    prompts = {i: 19 + 7 * i for i in served["results"]}  # prefix tokens
+    for i, (_, trace) in served["results"].items():
+        marks = {ob._mono_to_us(t0) for t0, _ in trace.prefill_chunks}
+        assert marks and trace.prefill_ms > 0.0
+        rode = served["rides"] and i > 0
+        assert marks <= (launches if rode else pieces), i
+        if served["rides"]:
+            # 8 tokens a tick either way, so a mark a tick (the paged pool
+            # aliases the prefix that the warm burst published)
+            assert len(marks) >= math.ceil(prompts[i] / 8)
+    went_live = [t for t, evs in by_tick.items()
+                 if any(e["name"] == "go_live" for e in evs)]
+    assert len(went_live) == len(served["results"])
+    riding = [t for t in went_live
+              if not any(e["name"] == "prefill_land" for e in by_tick[t])]
+    assert len(riding) == (4 if served["rides"] else 0)
+    for t in riding:
+        names = [e["name"] for e in sorted(by_tick[t], key=lambda e: e["ts"])]
+        assert not any(n.startswith("prefill_") for n in names)
+        assert names.index("account") < names.index("go_live") \
+            < names.index("stream_out")
+    if served["rides"]:
+        assert served["tokens"] == {
+            "piece": prompts[0], "ride": sum(prompts.values()) - prompts[0]}
+    else:
+        assert set(served["tokens"]) == {"piece"}
 
 
 def test_decode_chunk_ms_is_its_dispatch_wait_and_fetch(served):
