@@ -37,7 +37,6 @@ from dllama_tpu.models.config import ModelConfig
 from dllama_tpu.models.moe import moe_ffn, pick_counts, route_topk
 from dllama_tpu.ops.attention import gqa_attention
 from dllama_tpu.ops.norms import rmsnorm
-from dllama_tpu.ops.qmatmul import QuantTensor, matmul_any
 from dllama_tpu.ops.rope import apply_rope, rope_table
 
 #: cache leaves and rope tables of each attention kind
@@ -106,26 +105,6 @@ def _rope(cfg: ModelConfig, x, cos, sin):
         axis=-1)
 
 
-def _qkv(cfg: ModelConfig, att: str, lp: dict, x, layer):
-    """x [N, dim] -> q [N, heads, hd], k [N, kv, hd], v [N, kv, v_hd], the
-    values already scaled."""
-    N, eps = x.shape[0], cfg.norm_eps
-    qd = cfg.n_heads * cfg.head_size
-    kd = _kv_heads(cfg, att) * cfg.head_size
-    if "wqkv" in lp:
-        qkv = llama._norm_proj(x, lp["rms_att"], lp["wqkv"], layer, eps,
-                               name="wqkv")
-        q, k, v = qkv[:, :qd], qkv[:, qd:qd + kd], qkv[:, qd + kd:]
-    else:
-        q, k, v = (llama._norm_proj(x, lp["rms_att"], lp[n], layer, eps,
-                                    name=n) for n in ("wq", "wk", "wv"))
-    v = v.reshape(N, -1, cfg.v_size)
-    if cfg.value_scale != 1.0:
-        v = v * jnp.asarray(cfg.value_scale, v.dtype)
-    return (q.reshape(N, -1, cfg.head_size), k.reshape(N, -1, cfg.head_size),
-            v)
-
-
 def _attend(cfg: ModelConfig, att: str, lp: dict):
     """The attention of one sequence for this kind, under its own scope."""
     window = cfg.window if att == "window" else 0
@@ -139,74 +118,65 @@ def _attend(cfg: ModelConfig, att: str, lp: dict):
     return attend
 
 
-@jax.named_scope("attention")
-def _attn_block(cfg: ModelConfig, att: str, lp: dict, rope: dict, x, cache,
-                pos, layer, cidx):
-    """One sequence's attention sub-block: x [T, dim] at positions
-    ``pos..pos+T``; ``cache`` is the whole tree, of which layer ``cidx`` of
-    this kind's stacks is written and read. -> (output [T, dim], cache)"""
-    T = x.shape[0]
-    kk, vk = CACHE_KEYS[att]
+def _solo_core(cfg: ModelConfig, att: str, lp: dict, rope: dict, pos, cidx):
+    """The core (``llama._attention``) of one sequence's T tokens at
+    ``pos..pos+T``: layer ``cidx`` of this kind's cache stacks is written
+    and read."""
     ck, sk = ROPE_KEYS[att]
-    q, k, v = _qkv(cfg, att, lp, x, layer)
-    cos = jax.lax.dynamic_slice_in_dim(rope[ck], pos, T)[:, None, :]
-    sin = jax.lax.dynamic_slice_in_dim(rope[sk], pos, T)[:, None, :]
-    q, k = _rope(cfg, q, cos, sin), _rope(cfg, k, cos, sin)
-    k_cache, v_cache = cache[kk], cache[vk]
-    if att == "window":
-        if T > cfg.max_prefill_piece:
-            raise ValueError(
-                f"a forward over {T} tokens does not fit the window layers' "
-                f"ring ({cfg.ring_slots} slots, window {cfg.window}): at "
-                f"most {cfg.max_prefill_piece} tokens a piece")
-        slots = jnp.mod(pos + jnp.arange(T, dtype=jnp.int32),
-                        k_cache.shape[1])
-        with jax.named_scope("kv_ring_write"):
-            k_cache = k_cache.at[cidx, slots].set(k.astype(k_cache.dtype))
-            v_cache = v_cache.at[cidx, slots].set(v.astype(v_cache.dtype))
-    else:
-        zero = jnp.int32(0)
-        with jax.named_scope("kv_slab_write"):
-            k_cache = jax.lax.dynamic_update_slice(
-                k_cache, k.astype(k_cache.dtype)[None], (cidx, pos, zero, zero))
-            v_cache = jax.lax.dynamic_update_slice(
-                v_cache, v.astype(v_cache.dtype)[None], (cidx, pos, zero, zero))
-    with jax.named_scope("kv_slab_read"):
-        k_slab = jax.lax.dynamic_index_in_dim(k_cache, cidx, 0, keepdims=False)
-        v_slab = jax.lax.dynamic_index_in_dim(v_cache, cidx, 0, keepdims=False)
-    out = _attend(cfg, att, lp)(q, k_slab, v_slab, pos)
-    out = matmul_any(out.reshape(T, -1), lp["wo"], layer, name="wo")
-    return out, dict(cache, **{kk: k_cache, vk: v_cache})
+
+    def core(q, k, v, k_cache, v_cache, layer):
+        T = q.shape[0]
+        cos = jax.lax.dynamic_slice_in_dim(rope[ck], pos, T)[:, None, :]
+        sin = jax.lax.dynamic_slice_in_dim(rope[sk], pos, T)[:, None, :]
+        q, k = _rope(cfg, q, cos, sin), _rope(cfg, k, cos, sin)
+        if att == "window":
+            if T > cfg.max_prefill_piece:
+                raise ValueError(
+                    f"a forward over {T} tokens does not fit the window "
+                    f"layers' ring ({cfg.ring_slots} slots, window "
+                    f"{cfg.window}): at most {cfg.max_prefill_piece} tokens "
+                    f"a piece")
+            slots = jnp.mod(pos + jnp.arange(T, dtype=jnp.int32),
+                            k_cache.shape[1])
+            with jax.named_scope("kv_ring_write"):
+                k_cache = k_cache.at[cidx, slots].set(k.astype(k_cache.dtype))
+                v_cache = v_cache.at[cidx, slots].set(v.astype(v_cache.dtype))
+        else:
+            k_cache, v_cache = llama._write_kv_seq(k_cache, v_cache, k, v,
+                                                   cidx, pos)
+        out = _attend(cfg, att, lp)(
+            q, *llama._layer_slabs(k_cache, v_cache, cidx), pos)
+        return out, k_cache, v_cache
+
+    return core
 
 
-@jax.named_scope("attention")
-def _attn_block_batched(cfg: ModelConfig, att: str, lp: dict, rope: dict, x,
-                        cache, pos, layer, cidx):
-    """B independent sequences, one token each: x [B, dim], pos [B]; the
-    caches carry the row axis after the layer axis."""
-    B = x.shape[0]
-    kk, vk = CACHE_KEYS[att]
+def _rows_core(cfg: ModelConfig, att: str, lp: dict, rope: dict, pos, cidx):
+    """The core of B independent sequences, one token each at ``pos[b]``;
+    the caches carry the row axis after the layer axis."""
     ck, sk = ROPE_KEYS[att]
-    q, k, v = _qkv(cfg, att, lp, x, layer)
-    cos = rope[ck][pos][:, None, :]
-    sin = rope[sk][pos][:, None, :]
-    q, k = _rope(cfg, q, cos, sin), _rope(cfg, k, cos, sin)
-    k_cache, v_cache = cache[kk], cache[vk]
-    if att == "window":
-        rows = jnp.arange(B, dtype=jnp.int32)
-        slots = jnp.mod(pos, k_cache.shape[2])
-        with jax.named_scope("kv_ring_write"):
-            k_cache = k_cache.at[cidx, rows, slots].set(k.astype(k_cache.dtype))
-            v_cache = v_cache.at[cidx, rows, slots].set(v.astype(v_cache.dtype))
-    else:
-        k_cache, v_cache = llama._write_kv_rows(
-            k_cache, v_cache, k[:, None], v[:, None], cidx, pos)
-    slab_k, slab_v = llama._layer_slabs(k_cache, v_cache, cidx)
-    attend = _attend(cfg, att, lp)
-    out = jax.vmap(lambda qb, ks, vs, p: attend(qb[None], ks, vs, p)[0])(
-        q, slab_k, slab_v, pos)
-    out = matmul_any(out.reshape(B, -1), lp["wo"], layer, name="wo")
-    return out, dict(cache, **{kk: k_cache, vk: v_cache})
+
+    def core(q, k, v, k_cache, v_cache, layer):
+        cos = rope[ck][pos][:, None, :]
+        sin = rope[sk][pos][:, None, :]
+        q, k = _rope(cfg, q, cos, sin), _rope(cfg, k, cos, sin)
+        if att == "window":
+            rows = jnp.arange(q.shape[0], dtype=jnp.int32)
+            slots = jnp.mod(pos, k_cache.shape[2])
+            with jax.named_scope("kv_ring_write"):
+                k_cache = k_cache.at[cidx, rows, slots].set(
+                    k.astype(k_cache.dtype))
+                v_cache = v_cache.at[cidx, rows, slots].set(
+                    v.astype(v_cache.dtype))
+        else:
+            k_cache, v_cache = llama._write_kv_rows(
+                k_cache, v_cache, k[:, None], v[:, None], cidx, pos)
+        attend = _attend(cfg, att, lp)
+        out = jax.vmap(lambda qb, ks, vs, p: attend(qb[None], ks, vs, p)[0])(
+            q, *llama._layer_slabs(k_cache, v_cache, cidx), pos)
+        return out, k_cache, v_cache
+
+    return core
 
 
 def _ffn(cfg: ModelConfig, ffn: str, lp: dict, x, layer, live):
@@ -222,39 +192,44 @@ def _ffn(cfg: ModelConfig, ffn: str, lp: dict, x, layer, live):
     return x + moe_ffn(cfg, lp, xb, layer), picks
 
 
+def _run_step(cfg: ModelConfig, stack: dict, rope: dict, pos, core_of, live,
+              kind: tuple, p0: int, c0: int):
+    """The scan body of one run of layers of ``kind``: layer ``p0 + i`` of
+    the kind's parameter stack, ``c0 + i`` of its attention kind's caches;
+    ``llama._attention`` around this kind's core, then ``_ffn``."""
+    att = kind[0]
+    kk, vk = CACHE_KEYS[att]
+    widths = (cfg.n_heads * cfg.head_size, _kv_heads(cfg, att) * cfg.head_size)
+
+    def step(carry, i):
+        x, cache, picks = carry
+        idx = jnp.int32(p0) + i
+        lp = llama._layer_params(stack, idx)
+        core = core_of(cfg, att, lp, rope, pos, jnp.int32(c0) + i)
+        att_out, k_cache, v_cache = llama._attention(
+            cfg, lp, x, core, cache[kk], cache[vk], idx, widths=widths)
+        cache = dict(cache, **{kk: k_cache, vk: v_cache})
+        x, got = _ffn(cfg, kind[1], lp, x + att_out, idx, live)
+        if got is not None:
+            picks = picks + got
+        return (x, cache, picks), None
+
+    return step
+
+
 def _run_layers(cfg: ModelConfig, params: dict, rope: dict, x, cache: dict,
-                pos, attn_block, live=None):
+                pos, core_of, live=None):
     """Every run of like layers in turn. -> (x, cache, picks [3] or None)"""
-    picks = None if live is None else jnp.zeros((3,), jnp.int32)
+    carry = (x, cache, None if live is None else jnp.zeros((3,), jnp.int32))
     for kind, p0, c0, count in cfg.plan_runs:
-        stack = params["layers"][kind_name(kind)]
-
-        def step(carry, i, kind=kind, stack=stack, p0=p0, c0=c0):
-            x, cache, picks = carry
-            idx = jnp.int32(p0) + i
-            lp = {name: (leaf if isinstance(leaf, QuantTensor)
-                         else jax.lax.dynamic_index_in_dim(
-                             leaf, idx, 0, keepdims=False))
-                  for name, leaf in stack.items()}
-            att_out, cache = attn_block(cfg, kind[0], lp, rope, x, cache, pos,
-                                        idx, jnp.int32(c0) + i)
-            x, got = _ffn(cfg, kind[1], lp, x + att_out, idx, live)
-            if got is not None:
-                picks = picks + got
-            return (x, cache, picks), None
-
+        step = _run_step(cfg, params["layers"][kind_name(kind)], rope, pos,
+                         core_of, live, kind, p0, c0)
         if count == 1:
-            (x, cache, picks), _ = step((x, cache, picks), jnp.int32(0))
+            carry, _ = step(carry, jnp.int32(0))
         else:
-            (x, cache, picks), _ = jax.lax.scan(
-                step, (x, cache, picks), jnp.arange(count, dtype=jnp.int32))
-    return x, cache, picks
-
-
-def _logits(cfg: ModelConfig, params: dict, x):
-    x = rmsnorm(x, params["rms_final"], cfg.norm_eps)
-    logits = matmul_any(x, params["wcls"], name="wcls").astype(jnp.float32)
-    return logits * cfg.logit_scale if cfg.logit_scale != 1.0 else logits
+            carry, _ = jax.lax.scan(step, carry,
+                                    jnp.arange(count, dtype=jnp.int32))
+    return carry
 
 
 def forward(cfg: ModelConfig, params: dict, rope: dict, tokens, cache: dict,
@@ -262,10 +237,8 @@ def forward(cfg: ModelConfig, params: dict, rope: dict, tokens, cache: dict,
     """T tokens of one sequence from ``pos`` -> (logits [T, vocab] f32, or
     [1, vocab] at row ``last_pos``; the new cache tree)."""
     x = llama.embed(cfg, params, tokens)
-    x, cache, _ = _run_layers(cfg, params, rope, x, cache, pos, _attn_block)
-    if last_pos is not None:
-        x = jax.lax.dynamic_slice_in_dim(x, last_pos, 1, axis=0)
-    return _logits(cfg, params, x), cache
+    x, cache, _ = _run_layers(cfg, params, rope, x, cache, pos, _solo_core)
+    return llama._head(cfg, params, x, last_pos=last_pos), cache
 
 
 def forward_batched(cfg: ModelConfig, params: dict, rope: dict, tokens,
@@ -277,6 +250,6 @@ def forward_batched(cfg: ModelConfig, params: dict, rope: dict, tokens,
     experts they picked (``moe.pick_counts``)."""
     x = llama.embed(cfg, params, tokens)
     x, cache, picks = _run_layers(cfg, params, rope, x, cache, pos,
-                                  _attn_block_batched, live)
-    logits = _logits(cfg, params, x)
+                                  _rows_core, live)
+    logits = llama._head(cfg, params, x)
     return (logits, cache) if live is None else (logits, cache, picks)

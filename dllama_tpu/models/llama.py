@@ -577,7 +577,9 @@ def rope_tables(cfg: ModelConfig) -> dict:
 def _norm_proj(x, norm_w, w, layer, eps, name=None):
     """``rmsnorm(x, norm_w) @ w``. With DLLAMA_FUSE_NORM and a quantized
     ``w``, the norm rides inside the matmul kernel as an x-block epilogue
-    (qmatmul.qmatmul_norm — bit-identical, one fewer activation HBM
+    (qmatmul.qmatmul_norm — bit-identical in the kernel's products; the q40
+    recentering term's block sums are XLA's to order, a last place apart in
+    one case of tests/test_fused_ops.py — one fewer activation HBM
     round-trip). Callers needing the same normalized activation for several
     projections call this per projection: fused, the epilogue recomputes
     in-register (the point); unfused, XLA CSEs the repeated rmsnorm."""
@@ -686,63 +688,327 @@ def _ffn_residual(cfg: ModelConfig, lp: dict, x: jnp.ndarray, att_out: jnp.ndarr
                           layer)
 
 
-@jax.named_scope("attention")
-def _attn_block(cfg: ModelConfig, lp: dict, rope: dict, x, k_cache, v_cache, pos,
-                tp_axis=None, tp_compress: bool = False, layer=None,
-                row_mode: bool = False):
-    """One attention sub-block. Returns (attn output [T, dim], new k/v cache).
+def _layer_params(layers: dict, idx) -> dict:
+    """Layer ``idx``'s parameters out of the stacked ``layers``. A dense
+    leaf is sliced (a dense dynamic-slice fuses into its dot). A
+    ``QuantTensor`` stays STACKED: slicing the planes in the scan's body
+    (``w[idx]``) would make XLA materialize a full copy of every layer's
+    weights each step (a Pallas custom-call operand can't fuse a
+    dynamic-slice) — ~3x the per-token HBM traffic of reading the weights
+    once. Instead the scalar-prefetched ``idx`` steers each kernel's own DMA
+    straight into the stacked plane (qmatmul.*_stacked)."""
+    return {
+        name: (leaf if isinstance(leaf, QuantTensor)
+               else jax.lax.dynamic_index_in_dim(leaf, idx, 0, keepdims=False))
+        for name, leaf in layers.items()
+    }
 
-    With ``tp_axis`` (inside shard_map, quantized TP): the projections are
-    output-sharded, so head counts are *local* — derived from the array
-    shapes, never from cfg — and the attention runs on this device's heads
-    against its kv-head slice of the cache (the reference's
-    ``MultiHeadAttSlice``/``KvCacheSlice`` head split,
+
+def _qkv(cfg: ModelConfig, lp: dict, x, layer, lead: tuple,
+         row_mode: bool = False, widths=None):
+    """The q/k/v projections of ``x`` [N, dim], split into heads:
+    q ``[*lead, heads, hd]``, k ``[*lead, kv, hd]``, v ``[*lead, kv, v_hd]``
+    (``lead`` is ``(N,)``, or the verify step's ``(B, T)``). Head counts
+    derive from the ARRAY shapes, never from cfg: under tp the projections
+    are output-sharded and the counts are the local slices (the reference's
+    ``MultiHeadAttSlice`` head split,
     `/root/reference/src/transformer.cpp:161-181`).
-
-    With ``layer`` (the scalar-prefetch scan path): quant matrices in ``lp``
-    are layer-stacked and k_cache/v_cache are the FULL [L, S, kv, hd] caches;
-    the update touches only (layer, pos..pos+T) and the attention reads the
-    layer's slab. Without it, k_cache/v_cache are this layer's [S, kv, hd].
 
     ``row_mode`` (the --tp-reduce row-parallel path): ``x`` arrives ALREADY
     normalized (the caller's fused norm+gather epilogue), so the projections
-    skip ``_norm_proj``; and ``wo`` is K-sharded, so the LOCAL head concat
-    feeds it with NO gather and the return value is a full-width f32
-    PARTIAL sum for the caller's ring reduce-scatter — both of the attention
-    sub-block's gathers disappear."""
-    T = x.shape[0]
+    skip ``_norm_proj``. ``widths``: the (q, k) columns of a fused ``wqkv``
+    where they are not the uniform model's (a layer plan's kinds)."""
     eps = cfg.norm_eps
-
     if row_mode:  # pre-normalized input; rms_att was applied by the caller
-        q = matmul_any(x, lp["wq"], layer, name="wq")
-        k = matmul_any(x, lp["wk"], layer, name="wk")
-        v = matmul_any(x, lp["wv"], layer, name="wv")
+        q, k, v = (matmul_any(x, lp[n], layer, name=n)
+                   for n in ("wq", "wk", "wv"))
     elif "wqkv" in lp:  # fused single-kernel projection (fuse_qkv_ffn; no TP)
         qkv = _norm_proj(x, lp["rms_att"], lp["wqkv"], layer, eps, name="wqkv")
-        d, kv = cfg.dim, cfg.kv_dim
-        q = qkv[:, :d]
-        k = qkv[:, d : d + kv]
-        v = qkv[:, d + kv :]
+        d, kv = widths or (cfg.dim, cfg.kv_dim)
+        q, k, v = qkv[:, :d], qkv[:, d : d + kv], qkv[:, d + kv :]
     else:
-        q = _norm_proj(x, lp["rms_att"], lp["wq"], layer, eps, name="wq")
-        k = _norm_proj(x, lp["rms_att"], lp["wk"], layer, eps, name="wk")
-        v = _norm_proj(x, lp["rms_att"], lp["wv"], layer, eps, name="wv")
-    q = q.reshape(T, -1, cfg.head_size)
-    k = k.reshape(T, -1, cfg.head_size)
-    v = v.reshape(T, -1, cfg.head_size)
+        q, k, v = (_norm_proj(x, lp["rms_att"], lp[n], layer, eps, name=n)
+                   for n in ("wq", "wk", "wv"))
+    def heads(a, size):
+        return a.reshape(*lead, -1, size)
 
-    cos = jax.lax.dynamic_slice_in_dim(rope["cos"], pos, T)[:, None, :]
-    sin = jax.lax.dynamic_slice_in_dim(rope["sin"], pos, T)[:, None, :]
-    q = apply_rope(q, cos, sin, cfg.rope_style)
+    if cfg.value_scale != 1.0:
+        # scaled values are split first: the order in which the programs of
+        # the models that scale them were compiled
+        v = heads(v, cfg.v_size) * jnp.asarray(cfg.value_scale, v.dtype)
+        return heads(q, cfg.head_size), heads(k, cfg.head_size), v
+    return (heads(q, cfg.head_size), heads(k, cfg.head_size),
+            heads(v, cfg.v_size))
 
-    if layer is None:
-        k = apply_rope(k, cos, sin, cfg.rope_style)
-        k_cache = jax.lax.dynamic_update_slice_in_dim(
-            k_cache, k.astype(k_cache.dtype), pos, axis=0)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(
-            v_cache, v.astype(v_cache.dtype), pos, axis=0)
-        out = gqa_attention(q, k_cache, v_cache, pos)
-    else:
+
+def _attn_out(lp: dict, out, layer, tp_axis=None, tp_compress: bool = False,
+              row_mode: bool = False):
+    """The attention's output half on the head concat ``out`` [N, local
+    heads * hd]: gather the heads, ``wo``, gather the output. ``row_mode``:
+    ``wo`` is K-sharded, so the LOCAL head concat feeds it with NO gather
+    and the result is a full-width f32 PARTIAL sum for the caller's ring
+    reduce-scatter — both of the attention sub-block's gathers disappear."""
+    if row_mode:
+        return matmul_any(out, lp["wo"], layer, name="wo").astype(jnp.float32)
+    out = _gather(out, tp_axis, tp_compress)  # local heads -> full
+    return _gather(matmul_any(out, lp["wo"], layer, name="wo"), tp_axis,
+                   tp_compress)
+
+
+@jax.named_scope("attention")
+def _attention(cfg: ModelConfig, lp: dict, x, core, k_cache, v_cache, layer,
+               lead=None, tp_axis=None, tp_compress: bool = False,
+               row_mode: bool = False, widths=None):
+    """One attention sub-block around a *core*: the projections of ``x``
+    [N, dim], the core, the output half. Returns (attn output [N, dim], new
+    k/v cache).
+
+    ``core(q, k, v, k_cache, v_cache, layer) -> (out, k_cache, v_cache)``
+    is what the entry points differ in: where a row's rope angles come
+    from, how the step's K/V rows are written, and what each query attends
+    (``_solo_core``, ``_rows_core``, ``_verify_core``; a layer plan's own in
+    ``models.layer_plan``).
+
+    With ``tp_axis`` (inside shard_map, quantized TP) the attention runs on
+    this device's heads against its kv-head slice of the cache
+    (``KvCacheSlice``). With ``layer`` (the scalar-prefetch scan path):
+    quant matrices in ``lp`` are layer-stacked and k_cache/v_cache are the
+    FULL stacked caches; without it they are this layer's own.
+    ``row_mode``: see ``_qkv`` and ``_attn_out``."""
+    q, k, v = _qkv(cfg, lp, x, layer, lead or x.shape[:1], row_mode, widths)
+    out, k_cache, v_cache = core(q, k, v, k_cache, v_cache, layer)
+    return (_attn_out(lp, out.reshape(x.shape[0], -1), layer, tp_axis,
+                      tp_compress, row_mode), k_cache, v_cache)
+
+
+def _layer(cfg: ModelConfig, lp: dict, layer, streams: list, cores: list,
+           tp_axis=None, tp_compress: bool = False, tp_reduce=None) -> list:
+    """One transformer layer over one stream ``(x, k_cache, v_cache)`` or
+    over the two microbatches of an overlap step, each with its core ->
+    the streams after the layer. ``x`` is [N, dim], or the verify step's
+    [B, T, dim], whose rows every matmul sees flattened.
+
+    Gather mode: every stream's attention, then every stream's
+    ``_ffn_residual``. With two streams, microbatch A's attention (ending in
+    its head + wo gathers) is issued before microbatch B's in program order;
+    the two chains share only the layer's weights (read-only), so XLA's
+    latency-hiding scheduler is free to run B's matmuls while A's gather is
+    on the wire.
+
+    ``tp_reduce`` ('plain' | 'q80'; the caller passes it only where row mode
+    is active): the row-parallel sequence, a stream at a time. ``x`` rides
+    SCATTERED [N, dim/tp]; the fused norm+gather feeds the projections, the
+    K-sharded ``wo``/``w2`` partials take the ring reduce-scatter
+    (Q80-compressed hops when 'q80') and the residual adds happen on the
+    shard. The reduce-scatters are tp-1 ppermute hops by construction, so
+    they give the scheduler the same hop-granular boundaries the ring
+    gathers do."""
+    def rows(x):
+        return x.reshape(-1, x.shape[-1])
+
+    if tp_reduce is not None:
+        red_compress = tp_reduce == "q80"
+        out = []
+        for (x, k_cache, v_cache), core in zip(streams, cores):
+            x_s = rows(x)  # scattered residual rows
+            xn = _row_norm_gather(x_s, lp["rms_att"], tp_axis, tp_compress,
+                                  cfg.norm_eps, cfg.dim)
+            att_p, k_cache, v_cache = _attention(
+                cfg, lp, xn, core, k_cache, v_cache, layer, x.shape[:-1],
+                tp_axis, tp_compress, row_mode=True)
+            x_s = x_s + _reduce_scatter(att_p, tp_axis,
+                                        red_compress).astype(x_s.dtype)
+            xn = _row_norm_gather(x_s, lp["rms_ffn"], tp_axis, tp_compress,
+                                  cfg.norm_eps, cfg.dim)
+            ffn_p = _dense_ffn_row(cfg, lp, xn, layer=layer)
+            x_s = x_s + _reduce_scatter(ffn_p, tp_axis,
+                                        red_compress).astype(x_s.dtype)
+            out.append((x_s.reshape(x.shape), k_cache, v_cache))
+        return out
+    atts = [_attention(cfg, lp, rows(x), core, k_cache, v_cache, layer,
+                       x.shape[:-1], tp_axis, tp_compress)
+            for (x, k_cache, v_cache), core in zip(streams, cores)]
+    return [(_ffn_residual(cfg, lp, rows(x), att, tp_axis, tp_compress,
+                           layer=layer).reshape(x.shape), k_cache, v_cache)
+            for (x, _, _), (att, k_cache, v_cache) in zip(streams, atts)]
+
+
+def _scan_layers(layers: dict, n_layers: int, step, streams: list,
+                 index_scan: bool = True) -> list:
+    """``step(lp, layer, streams) -> streams`` over every layer; a stream is
+    ``(x, k_cache, v_cache)``, and two of them are the microbatches of an
+    overlap step: both advance inside ONE layer scan, so weights still
+    stream from HBM once per layer for all rows.
+
+    ``index_scan``: scan over a layer INDEX with the stacked planes closed
+    over as scan constants (``_layer_params``) and the stacked caches in the
+    carry, updated in place at (idx, pos). Otherwise dense weights and the
+    caches scan as scan-xs (per-layer slabs), ``layer`` is None and the
+    stream's caches are one layer's."""
+    if index_scan:
+        def body(carry, idx):
+            out = step(_layer_params(layers, idx), idx, list(zip(*carry)))
+            return tuple(zip(*out)), None
+
+        carry, _ = jax.lax.scan(body, tuple(zip(*streams)),
+                                jnp.arange(n_layers, dtype=jnp.int32))
+        return list(zip(*carry))
+    (x, k_caches, v_caches), = streams
+
+    def body(x, layer):
+        lp, k_cache, v_cache = layer
+        (x, k_cache, v_cache), = step(lp, None, [(x, k_cache, v_cache)])
+        return x, (k_cache, v_cache)
+
+    x, (new_k, new_v) = jax.lax.scan(body, x, (layers, k_caches, v_caches))
+    return [(x, new_k, new_v)]
+
+
+def _quant_scan(layers: dict) -> bool:
+    return any(isinstance(v, QuantTensor) for v in layers.values())
+
+
+def _scan_choice(layers: dict, allow_flash: bool, T: int, cache: dict,
+                 overlap: bool = False) -> tuple:
+    """-> (index scan?, flash decode?) of a forward over ``T`` tokens a
+    sequence: the one place that asks the flash gate and ``allow_flash``.
+
+    Dense weights normally scan the layer stack as scan-xs (per-layer
+    slabs); when flash decode engages, take the index-scan instead so the
+    stacked KV cache rides the carry and the flash kernel reads its live
+    prefix in place — dense weight slices still fuse into the dots (a
+    dense dynamic-slice is fusable, unlike a Pallas operand). Quantized
+    planes and the two streams of an overlap step always take the index
+    scan, and there the kernel engages whatever ``allow_flash`` says.
+
+    DLLAMA_FLASH_DECODE=1: online-softmax kernel reading ONLY the live
+    cache prefix, straight from the stacked cache — no per-layer slab
+    materialization, bytes scale with pos not seq_len (ops.flash_decode;
+    opt-in until benchmark-proven on hardware)."""
+    stacked = overlap or _quant_scan(layers)
+    flash = (stacked or allow_flash) and flash_decode.engages(
+        T, cache["k"].shape[-3], cache["k"].dtype)
+    return stacked or flash, flash
+
+
+def _row_mode(cfg: ModelConfig, layers: dict, tp_axis, tp_reduce) -> bool:
+    """Whether the row-parallel reduce path is active: it needs the
+    quantized index-scan (row_shard_quant_leaf repacks quant planes; the
+    Engine declines it elsewhere)."""
+    return (_check_tp_reduce(cfg, tp_reduce) and tp_axis is not None
+            and _quant_scan(layers))
+
+
+def _final_norm(cfg: ModelConfig, params: dict, x, tp_axis=None,
+                tp_compress: bool = False, row: bool = False):
+    """``rms_final``; in row mode one last fused norm+gather reassembles the
+    scattered residual already normalized for the classifier."""
+    if row:
+        return _row_norm_gather(x, params["rms_final"], tp_axis, tp_compress,
+                                cfg.norm_eps, cfg.dim)
+    return rmsnorm(x, params["rms_final"], cfg.norm_eps)
+
+
+def _head(cfg: ModelConfig, params: dict, x, tp_axis=None,
+          gather_logits: bool = True, tp_compress: bool = False,
+          row: bool = False, last_pos=None, normed: bool = False):
+    """The step's tail: the residual ``x`` [..., dim] -> logits
+    [..., vocab] f32. ``last_pos``: row ``last_pos`` alone (see
+    ``forward``). ``normed``: ``x`` has had its ``_final_norm`` (the halves
+    of an overlap step in row mode take it before they rejoin)."""
+    if last_pos is not None:
+        x = jax.lax.dynamic_slice_in_dim(x, last_pos, 1, axis=0)
+    if not normed:
+        x = _final_norm(cfg, params, x, tp_axis, tp_compress, row)
+    logits = matmul_any(x.reshape(-1, x.shape[-1]), params["wcls"],
+                        name="wcls").astype(jnp.float32)
+    if tp_axis is not None and gather_logits:
+        # slice off any lane-alignment vocab padding (zero logits there would
+        # beat real negative logits in an argmax) — no-op when unpadded
+        logits = _gather(logits, tp_axis)[..., : cfg.vocab_size]
+    logits = logits.reshape(*x.shape[:-1], -1)
+    if cfg.logit_scale != 1.0:
+        logits = logits * cfg.logit_scale
+    return logits
+
+
+def _write_kv_seq(k_cache, v_cache, k, v, layer, pos):
+    """Land one sequence's T new rows ``k``/``v`` [T, kv, hd] at
+    ``(layer, pos..pos+T)`` of the stacked ``[L, S, kv, hd]`` caches, in
+    place in the scan's carry."""
+    zero = jnp.int32(0)
+    with jax.named_scope("kv_slab_write"):
+        return (jax.lax.dynamic_update_slice(
+                    k_cache, k.astype(k_cache.dtype)[None],
+                    (layer, pos, zero, zero)),
+                jax.lax.dynamic_update_slice(
+                    v_cache, v.astype(v_cache.dtype)[None],
+                    (layer, pos, zero, zero)))
+
+
+def _write_kv_rows(k_cache, v_cache, k, v, layer, pos):
+    """Land each sequence's new K/V rows in the stacked ``[L, B, S, kv, hd]``
+    caches: ``k``/``v`` are ``[B, T, kv, hd]`` and sequence b's T rows go to
+    ``(layer, b, pos[b]..pos[b]+T)``. One scatter a cache, which XLA runs in
+    place on the donated scan carry: the compiled step writes ``B*T*kv*hd``
+    elements a layer and copies no slab out or back. The start clamps as
+    ``dynamic_update_slice`` clamps, to ``S - T``: a row stepped at
+    ``pos >= S`` lands in the last slot, where free rows pin.
+
+    Keep it a scatter: ``B`` unrolled ``dynamic_update_slice``s, or one
+    vmapped over the row axis, make the v5e compiler carry the whole cache
+    in another layout and turn it there and back around every launch
+    (PERF.md, PR 25)."""
+    B, T = k.shape[:2]
+    rows = jnp.arange(B, dtype=jnp.int32)[:, None]
+    cols = (jnp.clip(pos, 0, k_cache.shape[2] - T)[:, None]
+            + jnp.arange(T, dtype=jnp.int32))
+    with jax.named_scope("kv_slab_write"):
+        return (k_cache.at[layer, rows, cols].set(k.astype(k_cache.dtype)),
+                v_cache.at[layer, rows, cols].set(v.astype(v_cache.dtype)))
+
+
+def _write_rows_at(k_cache, v_cache, k, v, layer, rows, cols):
+    """Land the K/V rows ``k``/``v`` ``[n, kv, hd]`` at ``(layer, rows[i],
+    cols[i])`` of the stacked caches (``layer`` None: of this layer's
+    ``[B, S, kv, hd]`` slab): ``_write_kv_rows``'s in-place scatter for a
+    step whose rows are not one a sequence (decode rows and riders
+    together). A column out of bounds drops its row."""
+    idx = (rows, cols) if layer is None else (layer, rows, cols)
+    with jax.named_scope("kv_slab_write"):
+        return (k_cache.at[idx].set(k.astype(k_cache.dtype), mode="drop"),
+                v_cache.at[idx].set(v.astype(v_cache.dtype), mode="drop"))
+
+
+def _layer_slabs(k_cache, v_cache, layer):
+    """The layer's ``[(B,) S, kv, hd]`` K and V out of the stacked caches, to
+    be read only: on the v5e this is the one pass over the slab's bytes that
+    full-context attention needs (the slice is staged for the score and value
+    contractions, which then read no HBM again)."""
+    with jax.named_scope("kv_slab_read"):
+        return (jax.lax.dynamic_index_in_dim(k_cache, layer, 0, keepdims=False),
+                jax.lax.dynamic_index_in_dim(v_cache, layer, 0, keepdims=False))
+
+
+def _solo_core(cfg: ModelConfig, rope: dict, pos, flash: bool = False):
+    """The core of ``forward``: one sequence, T tokens at ``pos..pos+T``.
+    With ``layer`` the update touches only (layer, pos..pos+T) of the
+    stacked [L, S, kv, hd] caches and the attention reads the layer's slab
+    (``flash``: the kernel reads the live prefix in place, from BOTH
+    engines: the quantized layer-scan and the dense index-scan); without it
+    the caches are this layer's [S, kv, hd]."""
+    def core(q, k, v, k_cache, v_cache, layer):
+        T = q.shape[0]
+        cos = jax.lax.dynamic_slice_in_dim(rope["cos"], pos, T)[:, None, :]
+        sin = jax.lax.dynamic_slice_in_dim(rope["sin"], pos, T)[:, None, :]
+        q = apply_rope(q, cos, sin, cfg.rope_style)
+        if layer is None:
+            k = apply_rope(k, cos, sin, cfg.rope_style)
+            k_cache = jax.lax.dynamic_update_slice_in_dim(
+                k_cache, k.astype(k_cache.dtype), pos, axis=0)
+            v_cache = jax.lax.dynamic_update_slice_in_dim(
+                v_cache, v.astype(v_cache.dtype), pos, axis=0)
+            return gqa_attention(q, k_cache, v_cache, pos), k_cache, v_cache
         if fused_rope_cache.engages(T, k_cache.dtype):
             # DLLAMA_FUSE_ROPE_CACHE=1: K rotates in-kernel and lands with V
             # in the stacked cache in one pass (ops.fused_rope_cache) —
@@ -751,37 +1017,257 @@ def _attn_block(cfg: ModelConfig, lp: dict, rope: dict, x, k_cache, v_cache, pos
                 k, v, cos, sin, k_cache, v_cache, pos, layer, cfg.rope_style)
         else:
             k = apply_rope(k, cos, sin, cfg.rope_style)
-            zero = jnp.int32(0)
-            with jax.named_scope("kv_slab_write"):
-                k_cache = jax.lax.dynamic_update_slice(
-                    k_cache, k.astype(k_cache.dtype)[None],
-                    (layer, pos, zero, zero))
-                v_cache = jax.lax.dynamic_update_slice(
-                    v_cache, v.astype(v_cache.dtype)[None],
-                    (layer, pos, zero, zero))
-        # DLLAMA_FLASH_DECODE=1: online-softmax kernel reading ONLY the live
-        # cache prefix, straight from the stacked [L, S, kv, hd] cache — no
-        # per-layer slab materialization, bytes scale with pos not seq_len
-        # (ops.flash_decode; opt-in until benchmark-proven on hardware).
-        # Reached from BOTH engines: the quantized layer-scan and the dense
-        # index-scan forward() routes here when the gate engages.
-        if flash_decode.engages(T, k_cache.shape[1], k_cache.dtype):
-            out = flash_decode.flash_decode_attention(q, k_cache, v_cache, pos, layer)
+            k_cache, v_cache = _write_kv_seq(k_cache, v_cache, k, v, layer,
+                                             pos)
+        if flash:
+            out = flash_decode.flash_decode_attention(q, k_cache, v_cache,
+                                                      pos, layer)
         else:
-            with jax.named_scope("kv_slab_read"):
-                k_slab = jax.lax.dynamic_index_in_dim(
-                    k_cache, layer, 0, keepdims=False)
-                v_slab = jax.lax.dynamic_index_in_dim(
-                    v_cache, layer, 0, keepdims=False)
-            out = gqa_attention(q, k_slab, v_slab, pos)
-    if row_mode:
-        # local heads feed the K-sharded wo directly: no head gather, no
-        # output gather — the [T, dim] f32 partial rides the ring reduce
-        return (matmul_any(out.reshape(T, -1), lp["wo"], layer, name="wo")
-                .astype(jnp.float32), k_cache, v_cache)
-    out = _gather(out.reshape(T, -1), tp_axis, tp_compress)  # local heads -> full
-    return (_gather(matmul_any(out, lp["wo"], layer, name="wo"), tp_axis,
-                    tp_compress), k_cache, v_cache)
+            out = gqa_attention(q, *_layer_slabs(k_cache, v_cache, layer),
+                                pos)
+        return out, k_cache, v_cache
+
+    return core
+
+
+def _ride_step(rope: dict, pos, ride, slab_len: int) -> dict:
+    """What a decode step that carries riders needs of its ``ride`` =
+    ``(tokens [t], row, start, n)``, worked out ONCE a step, outside the
+    layer loop, over the step's B decode rows followed by its t riders:
+    ``cos`` / ``sin`` the rope angles of every row's position; ``rows`` /
+    ``cols`` the pool row and slot where each row's K/V land (a decode row
+    at its clamped position, as ``_write_kv_rows`` clamps; a rider at
+    ``start + i`` of ``row``; a padded rider, ``i >= n``, at the SLAB's
+    length: out of bounds, so the scatter drops it and it touches no slot);
+    ``row`` / ``start`` for the riders' attention."""
+    tokens, row, start, n = ride
+    i = jnp.arange(tokens.shape[0], dtype=jnp.int32)
+    at = jnp.concatenate([pos, start + i])
+    return {
+        "row": row, "start": start,
+        "rows": jnp.concatenate([jnp.arange(pos.shape[0], dtype=jnp.int32),
+                                 jnp.full(i.shape, row, jnp.int32)]),
+        "cols": jnp.concatenate([jnp.clip(pos, 0, slab_len - 1),
+                                 jnp.where(i < n, start + i, slab_len)]),
+        # a gather clamps a padded rider's position past the table
+        "cos": rope["cos"][at][:, None, :], "sin": rope["sin"][at][:, None, :],
+    }
+
+
+def _rows_core(cfg: ModelConfig, rope: dict, pos, flash: bool = False,
+               ride=None):
+    """The core of ``forward_batched``: B INDEPENDENT sequences, one token
+    each, row b at its own position ``pos[b]``. The projections around it
+    are ordinary [B, K] matmuls (identical to a T=B prefill row block — the
+    quant kernels need no batching rule); only rope/cache/attention are
+    per-row, via gather and vmap over the pure-jnp attention. Caches are
+    [L, B, S, kv, hd] under the layer scan (``layer`` given) or this layer's
+    [B, S, kv, hd] slab. Either way the step's B rows of K and V are written
+    where they live, in the scan's donated carry, and attention then reads
+    the layer's slab: no slab is copied out, updated and written back
+    (``_write_kv_rows``). ``flash``: the kernel reads each row's OWN live
+    prefix from the stacked cache.
+
+    ``ride`` (``_ride_step``, from ``forward_batched``): the rows past the B
+    of ``pos`` are prompt tokens of one pool row. They share the
+    projections, the rope and the cache write (one scatter for the step's
+    B + t rows) with the decode rows, and their queries attend their own
+    row's slab alone, causally, after the write."""
+    B = pos.shape[0]
+
+    def core(q, k, v, k_cache, v_cache, layer):
+        if ride is None:
+            cos = rope["cos"][pos][:, None, :]  # per-row angle: [B, 1, hs/2]
+            sin = rope["sin"][pos][:, None, :]
+        else:
+            cos, sin = ride["cos"], ride["sin"]  # [B + t, 1, hs/2]
+        q = apply_rope(q, cos, sin, cfg.rope_style)
+
+        fused_kv = (layer is not None
+                    and fused_rope_cache.engages(1, k_cache.dtype))
+        # this step's rows go where they live, in the scan's donated carry,
+        # before whichever attention reads them (write-before-attend)
+        if ride is not None and not fused_kv:
+            k = apply_rope(k, cos, sin, cfg.rope_style)
+            k_cache, v_cache = _write_rows_at(k_cache, v_cache, k, v, layer,
+                                              ride["rows"], ride["cols"])
+        elif fused_kv:
+            # DLLAMA_FUSE_ROPE_CACHE=1: rotate each row's K in-kernel and land
+            # K/V at (layer, b, pos[b]) in one pass — bit-identical to the
+            # scatter/DUS writes below, including their end-of-sequence clamp
+            kd, vd, cd, sd = ((k, v, cos, sin) if ride is None
+                              else (k[:B], v[:B], cos[:B], sin[:B]))
+            k_cache, v_cache = fused_rope_cache.rope_cache_update_batched(
+                kd, vd, cd, sd, k_cache, v_cache, pos, layer, cfg.rope_style)
+            if ride is not None:  # the kernel knows the decode rows only
+                k_cache, v_cache = _write_rows_at(
+                    k_cache, v_cache,
+                    apply_rope(k[B:], cos[B:], sin[B:], cfg.rope_style), v[B:],
+                    layer, ride["rows"][B:], ride["cols"][B:])
+        else:
+            k = apply_rope(k, cos, sin, cfg.rope_style)
+            if layer is None:
+                # dense xs-scan: the carry IS this layer's slab
+                with jax.named_scope("kv_slab_write"):
+                    write = jax.vmap(
+                        lambda c, kk, p: jax.lax.dynamic_update_slice_in_dim(
+                            c, kk[None].astype(c.dtype), p, axis=0))
+                    k_cache, v_cache = (write(k_cache, k, pos),
+                                        write(v_cache, v, pos))
+            else:
+                # layer scan: the stacked cache rides the carry
+                k_cache, v_cache = _write_kv_rows(
+                    k_cache, v_cache, k[:, None], v[:, None], layer, pos)
+        if ride is not None:
+            q_r, q = q[B:], q[:B]
+
+        slabs = None
+        if layer is not None and flash:
+            out = flash_decode.flash_decode_attention_batched(
+                q, k_cache, v_cache, pos, layer)  # [B, local heads, hs]
+        else:
+            slabs = ((k_cache, v_cache) if layer is None
+                     else _layer_slabs(k_cache, v_cache, layer))
+            out = jax.vmap(
+                lambda qb, ks, vs, p: gqa_attention(qb[None], ks, vs, p)[0]
+            )(q, *slabs, pos)  # [B, local heads, hs]
+        if ride is not None:
+            if slabs is None:
+                slabs = _layer_slabs(k_cache, v_cache, layer)
+            row_k, row_v = (jax.lax.dynamic_index_in_dim(s, ride["row"], 0,
+                                                         keepdims=False)
+                            for s in slabs)
+            out = jnp.concatenate(
+                [out, gqa_attention(q_r, row_k, row_v, ride["start"])], axis=0)
+        return out, k_cache, v_cache
+
+    return core
+
+
+def _verify_core(cfg: ModelConfig, rope: dict, pos):
+    """The core of ``forward_batched_verify``: B sequences of T tokens, row
+    b's at ``pos[b]..pos[b]+T``; q/k/v arrive [B, T, heads, hd] and the
+    caches are the stacked [L, B, S, kv, hd]. Dense attention only (the
+    batched flash kernel is one-token-per-row)."""
+    def core(q, k, v, k_cache, v_cache, layer):
+        T = q.shape[1]
+        # per-row angles for positions pos[b]..pos[b]+T-1 (the table gather
+        # clamps at seq_len-1; rows that close are emission-capped by the
+        # caller's budgets before any clamped position could be emitted)
+        ppos = pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+        cos = rope["cos"][ppos][:, :, None, :]  # [B, T, 1, hs/2]
+        sin = rope["sin"][ppos][:, :, None, :]
+        q = apply_rope(q, cos, sin, cfg.rope_style)
+        if fused_rope_cache.engages(T, k_cache.dtype):
+            # DLLAMA_FUSE_ROPE_CACHE=1: rotate the draft rows' K in-kernel
+            # and land K/V at (layer, b, pos[b]..pos[b]+T) in one pass —
+            # bit-identical to the apply_rope + per-row slab writes below
+            k_cache, v_cache = fused_rope_cache.rope_cache_update_verify(
+                k, v, cos, sin, k_cache, v_cache, pos, layer, cfg.rope_style)
+        else:
+            k = apply_rope(k, cos, sin, cfg.rope_style)
+            k_cache, v_cache = _write_kv_rows(k_cache, v_cache, k, v, layer,
+                                              pos)
+        out = jax.vmap(gqa_attention)(
+            q, *_layer_slabs(k_cache, v_cache, layer), pos)  # [B, T, H, hd]
+        return out, k_cache, v_cache
+
+    return core
+
+
+def _overlap_axis(tp_axis, ring: bool):
+    from dllama_tpu.parallel.collectives import RingAxis
+
+    return RingAxis(tp_axis) if (ring and tp_axis is not None) else tp_axis
+
+
+def _check_overlap_split(cfg: ModelConfig, batch: int) -> int:
+    """Static validation of the two-microbatch split; returns the cut row.
+
+    MoE is rejected at trace time: ``_moe_decode_selected`` computes the
+    selected-experts union over ALL rows (cap ``min(E, T*k)`` from the
+    column maxima), so a row-split changes which experts run and the
+    result would not be bit-identical to the monolithic step."""
+    if cfg.is_moe:
+        raise ValueError(
+            "tp_overlap requires a dense FFN: the MoE selected-experts "
+            "union spans all rows, so a microbatch split changes the "
+            "expert schedule (not bit-identical)")
+    if batch < 2:
+        raise ValueError(f"tp_overlap needs batch >= 2 rows, got {batch}")
+    return batch // 2
+
+
+def _model_step(cfg: ModelConfig, params: dict, tokens, cache: dict, pos,
+                  core_of, tp_axis=None, gather_logits: bool = True,
+                  tp_compress: bool = False, tp_reduce=None,
+                  index_scan: bool = True, split=None, ring: bool = True,
+                  interleave: bool = True, n_logits=None,
+                  last_pos=None) -> tuple:
+    """The model step around ``core_of(pos) -> core``: embed, every layer
+    (``_scan_layers`` over ``_layer``), the head. The T tokens of
+    ``forward``, the B rows of ``forward_batched`` or the B x T of
+    ``forward_batched_verify``; the pooled steps whole or, with ``split``
+    (``_check_overlap_split``), as two microbatches ``[:split]`` and
+    ``[split:]`` that advance inside one layer scan.
+
+    The split is EXACT by construction: every op in the layer body is
+    per-row (rmsnorm, rope, cache write, attention, sampling upstream), the
+    matmuls compute each output row from the full K independent of the other
+    rows, and the gathered chunk concatenation order is fixed — so splitting
+    [B] into [B//2] + [B - B//2] permutes nothing. With ``ring`` each gather
+    is the ``lax.ppermute`` chunk rotation
+    (`parallel.collectives.RingAxis`): tp-1 small async hops instead of one
+    fused blocking all-gather, giving the scheduler hop-granular boundaries
+    to hide. ``ring=False`` keeps fused all-gathers and relies on XLA alone
+    over the two-microbatch HLO. ``interleave``: both halves go through
+    ``_layer`` together (the decode step); without it each half's layer runs
+    whole before the other's (the verify step).
+
+    ``last_pos``: see ``forward``.
+    ``n_logits``: the classifier sees the first ``n_logits`` rows only."""
+    layers = params["layers"]
+    row = _row_mode(cfg, layers, tp_axis, tp_reduce)
+    x = embed(cfg, params, tokens)
+    if split is None:
+        axis, xs, poss = tp_axis, [x], [pos]
+        ks, vs = [cache["k"]], [cache["v"]]
+    else:
+        axis = _overlap_axis(tp_axis, ring)
+        xs, poss = [x[:split], x[split:]], [pos[:split], pos[split:]]
+        ks = [cache["k"][:, :split], cache["k"][:, split:]]
+        vs = [cache["v"][:, :split], cache["v"][:, split:]]
+    if row:  # residual rides the scan scattered
+        xs = [_scatter(x, axis) for x in xs]
+    cores = [core_of(p) for p in poss]
+    red = tp_reduce if row else None
+
+    def step(lp, layer, streams):
+        if interleave:
+            return _layer(cfg, lp, layer, streams, cores, axis, tp_compress,
+                          red)
+        return [_layer(cfg, lp, layer, [s], [c], axis, tp_compress, red)[0]
+                for s, c in zip(streams, cores)]
+
+    xs, ks, vs = zip(*_scan_layers(layers, cfg.n_layers, step,
+                                   list(zip(xs, ks, vs)), index_scan))
+    if split is None:
+        x, new_k, new_v = xs[0], ks[0], vs[0]
+        if n_logits is not None:
+            x = x[:n_logits]  # the riders' rows end with the last layer's K/V
+    else:
+        if row:  # per-half fused final norm (rmsnorm is per-row, so exact)
+            xs = [_final_norm(cfg, params, x, axis, tp_compress, row)
+                  for x in xs]
+        # rejoin, then a tail IDENTICAL to the whole step's: the final
+        # rmsnorm, logits matmul and (plain fused) logits gather see the
+        # same rows
+        x = jnp.concatenate(xs, axis=0)
+        new_k = jnp.concatenate(ks, axis=1)
+        new_v = jnp.concatenate(vs, axis=1)
+    logits = _head(cfg, params, x, tp_axis, gather_logits, tp_compress, row,
+                   last_pos, normed=row and split is not None)
+    return logits, {"k": new_k, "v": new_v}
 
 
 def forward(
@@ -840,94 +1326,12 @@ def forward(
             cfg.refuse_for_plan("the tensor-parallel forward (--tp > 1)")
         return layer_plan.forward(cfg, params, rope, tokens, cache, pos,
                                   last_pos=last_pos)
-    x = embed(cfg, params, tokens)
-    layers = params["layers"]
-    quant_scan = any(isinstance(v, QuantTensor) for v in layers.values())
-    # row mode needs the quantized index-scan (row_shard_quant_leaf repacks
-    # quant planes; the Engine declines it elsewhere)
-    row = (_check_tp_reduce(cfg, tp_reduce) and tp_axis is not None
-           and quant_scan)
-    red_compress = tp_reduce == "q80"
-    # Dense weights normally scan the layer stack as scan-xs (per-layer
-    # slabs); when flash decode engages, take the index-scan instead so the
-    # stacked KV cache rides the carry and the flash kernel reads its live
-    # prefix in place — dense weight slices still fuse into the dots (a
-    # dense dynamic-slice is fusable, unlike a Pallas operand).
-    if quant_scan or (allow_flash and flash_decode.engages(
-            tokens.shape[0], cache["k"].shape[1], cache["k"].dtype)):
-        # Scan over a layer INDEX with the stacked quant planes closed over
-        # as scan constants. Slicing the planes in the body (`w[idx]`) would
-        # make XLA materialize a full copy of every layer's weights each
-        # step (a Pallas custom-call operand can't fuse a dynamic-slice) —
-        # ~3x the per-token HBM traffic of reading the weights once. Instead
-        # a scalar-prefetched idx steers each kernel's own DMA straight into
-        # the stacked plane (qmatmul.*_stacked) and the KV cache is updated
-        # in place at (idx, pos).
-        if row:
-            x = _scatter(x, tp_axis)  # residual rides the scan scattered
-
-        def layer_step(carry, idx):
-            x, k_cache, v_cache = carry
-            lp = {
-                name: (leaf if isinstance(leaf, QuantTensor)
-                       else jax.lax.dynamic_index_in_dim(leaf, idx, 0, keepdims=False))
-                for name, leaf in layers.items()
-            }
-            if row:
-                xn = _row_norm_gather(x, lp["rms_att"], tp_axis, tp_compress,
-                                      cfg.norm_eps, cfg.dim)
-                att_p, k_cache, v_cache = _attn_block(
-                    cfg, lp, rope, xn, k_cache, v_cache, pos, tp_axis,
-                    tp_compress, layer=idx, row_mode=True)
-                x = x + _reduce_scatter(att_p, tp_axis,
-                                        red_compress).astype(x.dtype)
-                xn = _row_norm_gather(x, lp["rms_ffn"], tp_axis, tp_compress,
-                                      cfg.norm_eps, cfg.dim)
-                ffn_p = _dense_ffn_row(cfg, lp, xn, layer=idx)
-                x = x + _reduce_scatter(ffn_p, tp_axis,
-                                        red_compress).astype(x.dtype)
-                return (x, k_cache, v_cache), None
-            att_out, k_cache, v_cache = _attn_block(
-                cfg, lp, rope, x, k_cache, v_cache, pos, tp_axis, tp_compress,
-                layer=idx,
-            )
-            x = _ffn_residual(cfg, lp, x, att_out, tp_axis, tp_compress, layer=idx)
-            return (x, k_cache, v_cache), None
-
-        (x, new_k, new_v), _ = jax.lax.scan(
-            layer_step, (x, cache["k"], cache["v"]),
-            jnp.arange(cfg.n_layers, dtype=jnp.int32),
-        )
-    else:
-        def layer_step(x, layer):
-            lp, k_cache, v_cache = layer
-            att_out, k_cache, v_cache = _attn_block(
-                cfg, lp, rope, x, k_cache, v_cache, pos, tp_axis, tp_compress
-            )
-            x = _ffn_residual(cfg, lp, x, att_out, tp_axis, tp_compress)
-            return x, (k_cache, v_cache)
-
-        x, (new_k, new_v) = jax.lax.scan(
-            layer_step, x, (layers, cache["k"], cache["v"])
-        )
-
-    if last_pos is not None:
-        x = jax.lax.dynamic_slice_in_dim(x, last_pos, 1, axis=0)
-    if row:
-        # one last fused norm+gather reassembles the scattered residual
-        # already normalized for the classifier
-        x = _row_norm_gather(x, params["rms_final"], tp_axis, tp_compress,
-                             cfg.norm_eps, cfg.dim)
-    else:
-        x = rmsnorm(x, params["rms_final"], cfg.norm_eps)
-    logits = matmul_any(x, params["wcls"], name="wcls").astype(jnp.float32)
-    if tp_axis is not None and gather_logits:
-        # slice off any lane-alignment vocab padding (zero logits there would
-        # beat real negative logits in an argmax) — no-op when unpadded
-        logits = _gather(logits, tp_axis)[..., : cfg.vocab_size]
-    if cfg.logit_scale != 1.0:
-        logits = logits * cfg.logit_scale
-    return logits, {"k": new_k, "v": new_v}
+    index_scan, flash = _scan_choice(params["layers"], allow_flash,
+                                     tokens.shape[0], cache)
+    return _model_step(
+        cfg, params, tokens, cache, pos,
+        lambda p: _solo_core(cfg, rope, p, flash), tp_axis, gather_logits,
+        tp_compress, tp_reduce, index_scan, last_pos=last_pos)
 
 
 def init_batch_cache(cfg: ModelConfig, batch: int, cache_dtype=jnp.float32,
@@ -945,188 +1349,6 @@ def init_batch_cache(cfg: ModelConfig, batch: int, cache_dtype=jnp.float32,
     S = cfg.seq_len if seq_len is None else seq_len
     shape = (cfg.n_layers, batch, S, cfg.n_kv_heads, cfg.head_size)
     return {"k": jnp.zeros(shape, cache_dtype), "v": jnp.zeros(shape, cache_dtype)}
-
-
-def _write_kv_rows(k_cache, v_cache, k, v, layer, pos):
-    """Land each sequence's new K/V rows in the stacked ``[L, B, S, kv, hd]``
-    caches: ``k``/``v`` are ``[B, T, kv, hd]`` and sequence b's T rows go to
-    ``(layer, b, pos[b]..pos[b]+T)``. One scatter a cache, which XLA runs in
-    place on the donated scan carry: the compiled step writes ``B*T*kv*hd``
-    elements a layer and copies no slab out or back. The start clamps as
-    ``dynamic_update_slice`` clamps, to ``S - T``: a row stepped at
-    ``pos >= S`` lands in the last slot, where free rows pin.
-
-    Keep it a scatter: ``B`` unrolled ``dynamic_update_slice``s, or one
-    vmapped over the row axis, make the v5e compiler carry the whole cache
-    in another layout and turn it there and back around every launch
-    (PERF.md, PR 25)."""
-    B, T = k.shape[:2]
-    rows = jnp.arange(B, dtype=jnp.int32)[:, None]
-    cols = (jnp.clip(pos, 0, k_cache.shape[2] - T)[:, None]
-            + jnp.arange(T, dtype=jnp.int32))
-    with jax.named_scope("kv_slab_write"):
-        return (k_cache.at[layer, rows, cols].set(k.astype(k_cache.dtype)),
-                v_cache.at[layer, rows, cols].set(v.astype(v_cache.dtype)))
-
-
-def _layer_slabs(k_cache, v_cache, layer):
-    """The layer's ``[B, S, kv, hd]`` K and V out of the stacked caches, to
-    be read only: on the v5e this is the one pass over the slab's bytes that
-    full-context attention needs (the slice is staged for the score and value
-    contractions, which then read no HBM again)."""
-    with jax.named_scope("kv_slab_read"):
-        return (jax.lax.dynamic_index_in_dim(k_cache, layer, 0, keepdims=False),
-                jax.lax.dynamic_index_in_dim(v_cache, layer, 0, keepdims=False))
-
-
-def _ride_step(rope: dict, pos, ride, slab_len: int) -> dict:
-    """What a decode step that carries riders needs of its ``ride`` =
-    ``(tokens [t], row, start, n)``, worked out ONCE a step, outside the
-    layer loop, over the step's B decode rows followed by its t riders:
-    ``cos`` / ``sin`` the rope angles of every row's position; ``rows`` /
-    ``cols`` the pool row and slot where each row's K/V land (a decode row
-    at its clamped position, as ``_write_kv_rows`` clamps; a rider at
-    ``start + i`` of ``row``; a padded rider, ``i >= n``, at the SLAB's
-    length: out of bounds, so the scatter drops it and it touches no slot);
-    ``row`` / ``start`` for the riders' attention."""
-    tokens, row, start, n = ride
-    i = jnp.arange(tokens.shape[0], dtype=jnp.int32)
-    at = jnp.concatenate([pos, start + i])
-    return {
-        "row": row, "start": start,
-        "rows": jnp.concatenate([jnp.arange(pos.shape[0], dtype=jnp.int32),
-                                 jnp.full(i.shape, row, jnp.int32)]),
-        "cols": jnp.concatenate([jnp.clip(pos, 0, slab_len - 1),
-                                 jnp.where(i < n, start + i, slab_len)]),
-        # a gather clamps a padded rider's position past the table
-        "cos": rope["cos"][at][:, None, :], "sin": rope["sin"][at][:, None, :],
-    }
-
-
-def _write_rows_at(k_cache, v_cache, k, v, layer, rows, cols):
-    """Land the K/V rows ``k``/``v`` ``[n, kv, hd]`` at ``(layer, rows[i],
-    cols[i])`` of the stacked caches (``layer`` None: of this layer's
-    ``[B, S, kv, hd]`` slab): ``_write_kv_rows``'s in-place scatter for a
-    step whose rows are not one a sequence (decode rows and riders
-    together). A column out of bounds drops its row."""
-    idx = (rows, cols) if layer is None else (layer, rows, cols)
-    with jax.named_scope("kv_slab_write"):
-        return (k_cache.at[idx].set(k.astype(k_cache.dtype), mode="drop"),
-                v_cache.at[idx].set(v.astype(v_cache.dtype), mode="drop"))
-
-
-@jax.named_scope("attention")
-def _attn_block_batched(cfg: ModelConfig, lp: dict, rope: dict, x, k_cache,
-                        v_cache, pos, layer=None, tp_axis=None,
-                        tp_compress: bool = False, row_mode: bool = False,
-                        ride=None):
-    """Batched-decode attention: x [B, dim] carries B INDEPENDENT sequences,
-    each at its own position pos[b]. The projections are ordinary [B, K]
-    matmuls (identical to a T=B prefill row block — the quant kernels need
-    no batching rule); only rope/cache/attention are per-row, via gather and
-    vmap over the pure-jnp attention. Caches are [L, B, S, kv, hd] under the
-    layer scan (``layer`` given) or this layer's [B, S, kv, hd] slab. Either
-    way the step's B rows of K and V are written where they live, in the
-    scan's donated carry, and attention then reads the layer's slab: no slab
-    is copied out, updated and written back (``_write_kv_rows``).
-    ``tp_axis`` (inside shard_map): local heads + kv-shard cache, activation
-    gathers after the head concat and the wo matmul, exactly `_attn_block`.
-    ``row_mode``: pre-normalized input, K-sharded wo, f32 partial output —
-    see ``_attn_block``.
-    ``ride`` (``_ride_step``, from ``forward_batched``): the rows of ``x``
-    past the B of ``pos`` are prompt tokens of one pool row. They share the
-    projections, the rope and the cache write (one scatter for the step's
-    B + t rows) with the decode rows, and their queries attend their own
-    row's slab alone, causally, after the write."""
-    B = pos.shape[0]
-    t = x.shape[0] - B
-    eps = cfg.norm_eps
-    if row_mode:  # pre-normalized input; rms_att was applied by the caller
-        q = matmul_any(x, lp["wq"], layer, name="wq")
-        k = matmul_any(x, lp["wk"], layer, name="wk")
-        v = matmul_any(x, lp["wv"], layer, name="wv")
-    elif "wqkv" in lp:
-        qkv = _norm_proj(x, lp["rms_att"], lp["wqkv"], layer, eps, name="wqkv")
-        d, kv = cfg.dim, cfg.kv_dim
-        q, k, v = qkv[:, :d], qkv[:, d : d + kv], qkv[:, d + kv :]
-    else:
-        q = _norm_proj(x, lp["rms_att"], lp["wq"], layer, eps, name="wq")
-        k = _norm_proj(x, lp["rms_att"], lp["wk"], layer, eps, name="wk")
-        v = _norm_proj(x, lp["rms_att"], lp["wv"], layer, eps, name="wv")
-    q = q.reshape(B + t, -1, cfg.head_size)
-    k = k.reshape(B + t, -1, cfg.head_size)
-    v = v.reshape(B + t, -1, cfg.head_size)
-    if ride is None:
-        cos = rope["cos"][pos][:, None, :]  # per-row angle: [B, 1, hs/2]
-        sin = rope["sin"][pos][:, None, :]
-    else:
-        cos, sin = ride["cos"], ride["sin"]  # [B + t, 1, hs/2]
-    q = apply_rope(q, cos, sin, cfg.rope_style)
-
-    fused_kv = (layer is not None
-                and fused_rope_cache.engages(1, k_cache.dtype))
-    # this step's rows go where they live, in the scan's donated carry,
-    # before whichever attention reads them (write-before-attend)
-    if ride is not None and not fused_kv:
-        k = apply_rope(k, cos, sin, cfg.rope_style)
-        k_cache, v_cache = _write_rows_at(k_cache, v_cache, k, v, layer,
-                                          ride["rows"], ride["cols"])
-    elif fused_kv:
-        # DLLAMA_FUSE_ROPE_CACHE=1: rotate each row's K in-kernel and land
-        # K/V at (layer, b, pos[b]) in one pass — bit-identical to the
-        # scatter/DUS writes below, including their end-of-sequence clamp
-        kd, vd, cd, sd = ((k, v, cos, sin) if ride is None
-                          else (k[:B], v[:B], cos[:B], sin[:B]))
-        k_cache, v_cache = fused_rope_cache.rope_cache_update_batched(
-            kd, vd, cd, sd, k_cache, v_cache, pos, layer, cfg.rope_style)
-        if ride is not None:  # the kernel knows the decode rows only
-            k_cache, v_cache = _write_rows_at(
-                k_cache, v_cache,
-                apply_rope(k[B:], cos[B:], sin[B:], cfg.rope_style), v[B:],
-                layer, ride["rows"][B:], ride["cols"][B:])
-    else:
-        k = apply_rope(k, cos, sin, cfg.rope_style)
-        if layer is None:
-            # dense xs-scan: the carry IS this layer's slab
-            with jax.named_scope("kv_slab_write"):
-                write = jax.vmap(
-                    lambda c, kk, p: jax.lax.dynamic_update_slice_in_dim(
-                        c, kk[None].astype(c.dtype), p, axis=0))
-                k_cache, v_cache = (write(k_cache, k, pos),
-                                    write(v_cache, v, pos))
-        else:
-            # layer scan: the stacked cache rides the carry
-            k_cache, v_cache = _write_kv_rows(
-                k_cache, v_cache, k[:, None], v[:, None], layer, pos)
-    if ride is not None:
-        q_r, q = q[B:], q[:B]
-
-    slabs = None
-    if (layer is not None
-            and flash_decode.engages(1, k_cache.shape[2], k_cache.dtype)):
-        # the kernel reads each row's OWN live prefix from the stacked cache
-        out = flash_decode.flash_decode_attention_batched(
-            q, k_cache, v_cache, pos, layer)  # [B, local heads, hs]
-    else:
-        slabs = ((k_cache, v_cache) if layer is None
-                 else _layer_slabs(k_cache, v_cache, layer))
-        out = jax.vmap(
-            lambda qb, ks, vs, p: gqa_attention(qb[None], ks, vs, p)[0]
-        )(q, *slabs, pos)  # [B, local heads, hs]
-    if ride is not None:
-        if slabs is None:
-            slabs = _layer_slabs(k_cache, v_cache, layer)
-        row_k, row_v = (jax.lax.dynamic_index_in_dim(s, ride["row"], 0,
-                                                     keepdims=False)
-                        for s in slabs)
-        out = jnp.concatenate(
-            [out, gqa_attention(q_r, row_k, row_v, ride["start"])], axis=0)
-    if row_mode:  # local heads -> K-sharded wo: no gathers, f32 partials
-        return (matmul_any(out.reshape(B, -1), lp["wo"], layer, name="wo")
-                .astype(jnp.float32), k_cache, v_cache)
-    out = _gather(out.reshape(B + t, -1), tp_axis, tp_compress)
-    return (_gather(matmul_any(out, lp["wo"], layer, name="wo"), tp_axis,
-                    tp_compress), k_cache, v_cache)
 
 
 def forward_batched(
@@ -1166,7 +1388,7 @@ def forward_batched(
     ``pos``. Their embedded rows are appended to the B decode rows, so every
     projection and the FFN (an MoE's expert scans too) stream their weights
     once for ``[B + t, K]``; rope, the cache write and attention are their
-    own (``_attn_block_batched``), and the classifier sees the B decode rows
+    own (``_rows_core``), and the classifier sees the B decode rows
     only: a prompt's last token is fed by its row's first decode step, so no
     rider needs logits. Single device, a uniform model; the row must not be
     decoding (its decode-side position pins at the slab's last slot, which
@@ -1183,100 +1405,14 @@ def forward_batched(
     if ride is not None:
         tokens = jnp.concatenate([tokens, ride[0]])
         ride = _ride_step(rope, pos, ride, cache["k"].shape[2])
-    x = embed(cfg, params, tokens)
-    layers = params["layers"]
-    quant_scan = any(isinstance(v, QuantTensor) for v in layers.values())
-    row = (_check_tp_reduce(cfg, tp_reduce) and tp_axis is not None
-           and quant_scan)
-    red_compress = tp_reduce == "q80"
-    # same routing as `forward`: dense weights take the index-scan when the
-    # batched flash kernel engages, so the stacked [L, B, S, kv, hd] cache
-    # stays in the carry and each row reads only its own live prefix
-    if quant_scan or (allow_flash and flash_decode.engages(
-            1, cache["k"].shape[2], cache["k"].dtype)):
-        if row:
-            x = _scatter(x, tp_axis)  # residual rides the scan scattered
-
-        def layer_step(carry, idx):
-            x, k_cache, v_cache = carry
-            lp = {
-                name: (leaf if isinstance(leaf, QuantTensor)
-                       else jax.lax.dynamic_index_in_dim(leaf, idx, 0, keepdims=False))
-                for name, leaf in layers.items()
-            }
-            if row:
-                xn = _row_norm_gather(x, lp["rms_att"], tp_axis, tp_compress,
-                                      cfg.norm_eps, cfg.dim)
-                att_p, k_cache, v_cache = _attn_block_batched(
-                    cfg, lp, rope, xn, k_cache, v_cache, pos, layer=idx,
-                    tp_axis=tp_axis, tp_compress=tp_compress, row_mode=True)
-                x = x + _reduce_scatter(att_p, tp_axis,
-                                        red_compress).astype(x.dtype)
-                xn = _row_norm_gather(x, lp["rms_ffn"], tp_axis, tp_compress,
-                                      cfg.norm_eps, cfg.dim)
-                ffn_p = _dense_ffn_row(cfg, lp, xn, layer=idx)
-                x = x + _reduce_scatter(ffn_p, tp_axis,
-                                        red_compress).astype(x.dtype)
-                return (x, k_cache, v_cache), None
-            att_out, k_cache, v_cache = _attn_block_batched(
-                cfg, lp, rope, x, k_cache, v_cache, pos, layer=idx,
-                tp_axis=tp_axis, tp_compress=tp_compress, ride=ride)
-            x = _ffn_residual(cfg, lp, x, att_out, tp_axis, tp_compress, layer=idx)
-            return (x, k_cache, v_cache), None
-
-        (x, new_k, new_v), _ = jax.lax.scan(
-            layer_step, (x, cache["k"], cache["v"]),
-            jnp.arange(cfg.n_layers, dtype=jnp.int32),
-        )
-    else:
-        def layer_step(x, layer):
-            lp, k_cache, v_cache = layer
-            att_out, k_cache, v_cache = _attn_block_batched(
-                cfg, lp, rope, x, k_cache, v_cache, pos,
-                tp_axis=tp_axis, tp_compress=tp_compress, ride=ride)
-            x = _ffn_residual(cfg, lp, x, att_out, tp_axis, tp_compress)
-            return x, (k_cache, v_cache)
-
-        x, (new_k, new_v) = jax.lax.scan(
-            layer_step, x, (layers, cache["k"], cache["v"])
-        )
-    if ride is not None:
-        x = x[:B]  # the riders' rows end with the last layer's K/V
-    if row:
-        x = _row_norm_gather(x, params["rms_final"], tp_axis, tp_compress,
-                             cfg.norm_eps, cfg.dim)
-    else:
-        x = rmsnorm(x, params["rms_final"], cfg.norm_eps)
-    logits = matmul_any(x, params["wcls"], name="wcls").astype(jnp.float32)
-    if tp_axis is not None and gather_logits:
-        # slice off lane-alignment vocab padding, exactly like `forward`
-        logits = _gather(logits, tp_axis)[..., : cfg.vocab_size]
-    if cfg.logit_scale != 1.0:
-        logits = logits * cfg.logit_scale
-    return logits, {"k": new_k, "v": new_v}
-
-
-def _overlap_axis(tp_axis, ring: bool):
-    from dllama_tpu.parallel.collectives import RingAxis
-
-    return RingAxis(tp_axis) if (ring and tp_axis is not None) else tp_axis
-
-
-def _check_overlap_split(cfg: ModelConfig, batch: int) -> int:
-    """Static validation of the two-microbatch split; returns the cut row.
-
-    MoE is rejected at trace time: ``_moe_decode_selected`` computes the
-    selected-experts union over ALL rows (cap ``min(E, T*k)`` from the
-    column maxima), so a row-split changes which experts run and the
-    result would not be bit-identical to the monolithic step."""
-    if cfg.is_moe:
-        raise ValueError(
-            "tp_overlap requires a dense FFN: the MoE selected-experts "
-            "union spans all rows, so a microbatch split changes the "
-            "expert schedule (not bit-identical)")
-    if batch < 2:
-        raise ValueError(f"tp_overlap needs batch >= 2 rows, got {batch}")
-    return batch // 2
+    # same routing as `forward`: the stacked [L, B, S, kv, hd] cache stays
+    # in the carry where each row reads only its own live prefix
+    index_scan, flash = _scan_choice(params["layers"], allow_flash, 1, cache)
+    return _model_step(
+        cfg, params, tokens, cache, pos,
+        lambda p: _rows_core(cfg, rope, p, flash, ride), tp_axis,
+        gather_logits, tp_compress, tp_reduce, index_scan,
+        n_logits=None if ride is None else B)
 
 
 def forward_batched_overlap(
@@ -1295,192 +1431,23 @@ def forward_batched_overlap(
 ) -> tuple:
     """``forward_batched`` with the rows split into two microbatches whose
     per-layer schedules interleave — the TokenWeave-style compute/comm
-    overlap for TP decode, EXACT by construction.
-
-    Per layer, microbatch A's attention (ending in its head + wo gathers)
-    is issued before microbatch B's in program order; the two chains share
-    only the layer's weights (read-only), so XLA's latency-hiding
-    scheduler is free to run B's matmuls while A's gather is on the wire.
-    With ``ring=True`` each gather is the ``lax.ppermute`` chunk rotation
-    (`parallel.collectives.RingAxis`): tp-1 small async hops instead of
-    one fused blocking all-gather, giving the scheduler hop-granular
-    boundaries to hide. ``ring=False`` keeps fused all-gathers and relies
-    on XLA alone over the interleaved two-microbatch HLO.
-
-    Bit-identity with the monolithic step (tested across tp degrees with
-    and without ``tp_compress``): every op in the layer body is per-row
-    (rmsnorm, rope, cache write, attention, sampling upstream), the
-    matmuls compute each output row from the full K independent of the
-    other rows, and the gathered chunk concatenation order is fixed —
-    so splitting [B] into [B//2] + [B - B//2] permutes nothing. Both
-    halves advance inside ONE layer scan, so weights still stream from
-    HBM once per layer for all B rows. MoE is rejected (see
+    overlap for TP decode, EXACT by construction (``_model_step``,
+    ``_layer``; tested bit-identical with the monolithic step across tp
+    degrees with and without ``tp_compress``). MoE is rejected (see
     ``_check_overlap_split``).
 
     ``tp_reduce`` composes: each microbatch runs the row-parallel sequence
-    (fused norm+gather, K-sharded wo/w2, ring reduce-scatter) with the SAME
-    interleaving — the reduce-scatters are tp-1 ppermute hops by
-    construction, so they give the scheduler the same hop-granular
-    boundaries the ring gathers do. Row mode is NOT bit-identical to the
-    monolithic gather path (split-K reassociation); it IS the same math as
-    the non-overlap row-parallel step, microbatch-split exactly."""
+    inside the same scan. Row mode is NOT bit-identical to the monolithic
+    gather path (split-K reassociation); it IS the same math as the
+    non-overlap row-parallel step, microbatch-split exactly."""
     cfg.refuse_for_plan("the microbatch-overlap forward (--tp-overlap)")
-    B = tokens.shape[0]
-    h = _check_overlap_split(cfg, B)
-    ga = _overlap_axis(tp_axis, ring)
-    x = embed(cfg, params, tokens)
-    xa, xb = x[:h], x[h:]
-    pa, pb = pos[:h], pos[h:]
-    ka, kb = cache["k"][:, :h], cache["k"][:, h:]
-    va, vb = cache["v"][:, :h], cache["v"][:, h:]
-    layers = params["layers"]
-    quant_scan = any(isinstance(v, QuantTensor) for v in layers.values())
-    row = (_check_tp_reduce(cfg, tp_reduce) and tp_axis is not None
-           and quant_scan)
-    red_compress = tp_reduce == "q80"
-    if row:
-        xa, xb = _scatter(xa, ga), _scatter(xb, ga)
-
-    def _row_half(lp, idx, x_s, kc, vc, p):
-        """One microbatch's row-parallel layer: fused norm+gather feeds the
-        attention, partials ride the ring, residual adds stay scattered."""
-        xn = _row_norm_gather(x_s, lp["rms_att"], ga, tp_compress,
-                              cfg.norm_eps, cfg.dim)
-        att_p, kc, vc = _attn_block_batched(
-            cfg, lp, rope, xn, kc, vc, p, layer=idx,
-            tp_axis=ga, tp_compress=tp_compress, row_mode=True)
-        x_s = x_s + _reduce_scatter(att_p, ga, red_compress).astype(x_s.dtype)
-        xn = _row_norm_gather(x_s, lp["rms_ffn"], ga, tp_compress,
-                              cfg.norm_eps, cfg.dim)
-        ffn_p = _dense_ffn_row(cfg, lp, xn, layer=idx)
-        x_s = x_s + _reduce_scatter(ffn_p, ga, red_compress).astype(x_s.dtype)
-        return x_s, kc, vc
-
-    def layer_step(carry, idx):
-        xa, xb, ka, kb, va, vb = carry
-        lp = {
-            name: (leaf if isinstance(leaf, QuantTensor)
-                   else jax.lax.dynamic_index_in_dim(leaf, idx, 0, keepdims=False))
-            for name, leaf in layers.items()
-        }
-        if row:
-            xa, ka, va = _row_half(lp, idx, xa, ka, va, pa)
-            xb, kb, vb = _row_half(lp, idx, xb, kb, vb, pb)
-            return (xa, xb, ka, kb, va, vb), None
-        att_a, ka, va = _attn_block_batched(
-            cfg, lp, rope, xa, ka, va, pa, layer=idx,
-            tp_axis=ga, tp_compress=tp_compress)
-        att_b, kb, vb = _attn_block_batched(
-            cfg, lp, rope, xb, kb, vb, pb, layer=idx,
-            tp_axis=ga, tp_compress=tp_compress)
-        xa = _ffn_residual(cfg, lp, xa, att_a, ga, tp_compress, layer=idx)
-        xb = _ffn_residual(cfg, lp, xb, att_b, ga, tp_compress, layer=idx)
-        return (xa, xb, ka, kb, va, vb), None
-
-    (xa, xb, ka, kb, va, vb), _ = jax.lax.scan(
-        layer_step, (xa, xb, ka, kb, va, vb),
-        jnp.arange(cfg.n_layers, dtype=jnp.int32),
-    )
-    if row:  # per-half fused final norm (rmsnorm is per-row, so exact)
-        xa = _row_norm_gather(xa, params["rms_final"], ga, tp_compress,
-                              cfg.norm_eps, cfg.dim)
-        xb = _row_norm_gather(xb, params["rms_final"], ga, tp_compress,
-                              cfg.norm_eps, cfg.dim)
-    # rejoin, then a tail IDENTICAL to forward_batched's: the final rmsnorm,
-    # logits matmul and (plain fused) logits gather see the same [B, dim]
-    x = jnp.concatenate([xa, xb], axis=0)
-    new_k = jnp.concatenate([ka, kb], axis=1)
-    new_v = jnp.concatenate([va, vb], axis=1)
-    if not row:
-        x = rmsnorm(x, params["rms_final"], cfg.norm_eps)
-    logits = matmul_any(x, params["wcls"], name="wcls").astype(jnp.float32)
-    if tp_axis is not None and gather_logits:
-        logits = _gather(logits, tp_axis)[..., : cfg.vocab_size]
-    if cfg.logit_scale != 1.0:
-        logits = logits * cfg.logit_scale
-    return logits, {"k": new_k, "v": new_v}
-
-
-@jax.named_scope("attention")
-def _verify_layer(cfg: ModelConfig, lp: dict, rope: dict, x, k_cache,
-                  v_cache, pos, idx, tp_axis=None, tp_compress: bool = False,
-                  row_mode: bool = False, red_compress: bool = False):
-    """One layer of the batched spec-verify step: x [B, T, dim], stacked
-    [L, B, S, kv, hd] caches, per-row base positions ``pos``. The shared
-    body of ``forward_batched_verify`` and its microbatch-overlap twin.
-
-    ``row_mode`` (--tp-reduce): ``x`` arrives SCATTERED ``[B, T, dim/tp]``
-    and stays scattered on return — the fused norm+gather feeds the
-    projections, the K-sharded ``wo``/``w2`` partials ride the ring
-    reduce-scatter, and the residual adds happen on the shard."""
-    B, T = x.shape[:2]
-    if row_mode:
-        x_s = x.reshape(B * T, x.shape[-1])  # scattered residual rows
-        xn = _row_norm_gather(x_s, lp["rms_att"], tp_axis, tp_compress,
-                              cfg.norm_eps, cfg.dim)
-        q = matmul_any(xn, lp["wq"], idx, name="wq")
-        k = matmul_any(xn, lp["wk"], idx, name="wk")
-        v = matmul_any(xn, lp["wv"], idx, name="wv")
-    elif "wqkv" in lp:
-        xf = x.reshape(B * T, cfg.dim)  # raw rows; rmsnorm rides in _norm_proj
-        qkv = _norm_proj(xf, lp["rms_att"], lp["wqkv"], idx, cfg.norm_eps,
-                         name="wqkv")
-        d, kv = cfg.dim, cfg.kv_dim
-        q, k, v = qkv[:, :d], qkv[:, d : d + kv], qkv[:, d + kv :]
-    else:
-        xf = x.reshape(B * T, cfg.dim)  # raw rows; rmsnorm rides in _norm_proj
-        q = _norm_proj(xf, lp["rms_att"], lp["wq"], idx, cfg.norm_eps,
-                       name="wq")
-        k = _norm_proj(xf, lp["rms_att"], lp["wk"], idx, cfg.norm_eps,
-                       name="wk")
-        v = _norm_proj(xf, lp["rms_att"], lp["wv"], idx, cfg.norm_eps,
-                       name="wv")
-    # head counts derive from the ARRAY shapes: under tp they are the
-    # local slices (the reference's MultiHeadAttSlice head split)
-    q = q.reshape(B, T, -1, cfg.head_size)
-    k = k.reshape(B, T, -1, cfg.head_size)
-    v = v.reshape(B, T, -1, cfg.head_size)
-
-    # per-row angles for positions pos[b]..pos[b]+T-1 (the table gather
-    # clamps at seq_len-1; rows that close are emission-capped by the
-    # caller's budgets before any clamped position could be emitted)
-    ppos = pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-    cos = rope["cos"][ppos][:, :, None, :]  # [B, T, 1, hs/2]
-    sin = rope["sin"][ppos][:, :, None, :]
-    q = apply_rope(q, cos, sin, cfg.rope_style)
-
-    if fused_rope_cache.engages(T, k_cache.dtype):
-        # DLLAMA_FUSE_ROPE_CACHE=1: rotate the draft rows' K in-kernel and
-        # land K/V at (idx, b, pos[b]..pos[b]+T) in one pass — bit-identical
-        # to the apply_rope + per-row slab writes below
-        k_cache, v_cache = fused_rope_cache.rope_cache_update_verify(
-            k, v, cos, sin, k_cache, v_cache, pos, idx, cfg.rope_style)
-    else:
-        k = apply_rope(k, cos, sin, cfg.rope_style)
-        k_cache, v_cache = _write_kv_rows(k_cache, v_cache, k, v, idx, pos)
-
-    slab_k, slab_v = _layer_slabs(k_cache, v_cache, idx)
-    out = jax.vmap(gqa_attention)(q, slab_k, slab_v, pos)  # [B, T, H, hd]
-    if row_mode:
-        # local heads feed the K-sharded wo directly; the partial rides the
-        # ring reduce-scatter and the residual add stays on the shard
-        att_p = matmul_any(out.reshape(B * T, -1), lp["wo"], idx,
-                           name="wo").astype(jnp.float32)
-        x_s = x_s + _reduce_scatter(att_p, tp_axis, red_compress
-                                    ).astype(x_s.dtype)
-        xn = _row_norm_gather(x_s, lp["rms_ffn"], tp_axis, tp_compress,
-                              cfg.norm_eps, cfg.dim)
-        ffn_p = _dense_ffn_row(cfg, lp, xn, layer=idx)
-        x_s = x_s + _reduce_scatter(ffn_p, tp_axis, red_compress
-                                    ).astype(x_s.dtype)
-        return x_s.reshape(B, T, -1), k_cache, v_cache
-    heads = _gather(out.reshape(B * T, -1), tp_axis, tp_compress)
-    att = _gather(matmul_any(heads, lp["wo"], idx, name="wo"), tp_axis,
-                  tp_compress)
-    x = _ffn_residual(cfg, lp, x.reshape(B * T, cfg.dim),
-                      att, tp_axis, tp_compress,
-                      layer=idx).reshape(B, T, cfg.dim)
-    return x, k_cache, v_cache
+    split = _check_overlap_split(cfg, tokens.shape[0])
+    _, flash = _scan_choice(params["layers"], allow_flash, 1, cache,
+                            overlap=True)
+    return _model_step(
+        cfg, params, tokens, cache, pos,
+        lambda p: _rows_core(cfg, rope, p, flash), tp_axis, gather_logits,
+        tp_compress, tp_reduce, split=split, ring=ring)
 
 
 def forward_batched_verify(
@@ -1506,53 +1473,16 @@ def forward_batched_verify(
     per matrix — the quant kernels never see the batch structure); rope,
     cache writes, and attention are per-row (vmap over the pure attention).
     MoE routing on the flattened rows is exact: the selected-experts union
-    caps at min(E, B*T*k). Dense attention only (the batched flash kernel
-    is one-token-per-row). ``tp_axis``: inside shard_map over a tp mesh
+    caps at min(E, B*T*k). Dense attention only (``_verify_core``).
+    ``tp_axis``: inside shard_map over a tp mesh
     (quant-TP, parallel.quant_tp.make_tp_verify_batched) — local heads +
     kv-shard caches, the same activation gathers as ``forward_batched``.
     """
     cfg.refuse_for_plan("the speculative verify step (--spec-draft)")
-    B, T = tokens.shape
-    x = embed(cfg, params, tokens)  # [B, T, dim]
-    layers = params["layers"]
-    quant_scan = any(isinstance(v, QuantTensor) for v in layers.values())
-    row = (_check_tp_reduce(cfg, tp_reduce) and tp_axis is not None
-           and quant_scan)
-    red_compress = tp_reduce == "q80"
-    if row:
-        x = _scatter(x, tp_axis)
-
-    def layer_step(carry, idx):
-        x, k_cache, v_cache = carry
-        lp = {
-            name: (leaf if isinstance(leaf, QuantTensor)
-                   else jax.lax.dynamic_index_in_dim(leaf, idx, 0, keepdims=False))
-            for name, leaf in layers.items()
-        }
-        x, k_cache, v_cache = _verify_layer(
-            cfg, lp, rope, x, k_cache, v_cache, pos, idx,
-            tp_axis=tp_axis, tp_compress=tp_compress,
-            row_mode=row, red_compress=red_compress)
-        return (x, k_cache, v_cache), None
-
-    (x, new_k, new_v), _ = jax.lax.scan(
-        layer_step, (x, cache["k"], cache["v"]),
-        jnp.arange(cfg.n_layers, dtype=jnp.int32),
-    )
-    if row:  # fused final norm on the scattered residual
-        x = _row_norm_gather(x, params["rms_final"], tp_axis, tp_compress,
-                             cfg.norm_eps, cfg.dim)
-    else:
-        x = rmsnorm(x, params["rms_final"], cfg.norm_eps)
-    logits = matmul_any(x.reshape(B * T, cfg.dim),
-                        params["wcls"], name="wcls").astype(jnp.float32)
-    if tp_axis is not None and gather_logits:
-        # slice off lane-alignment vocab padding, exactly like `forward`
-        logits = _gather(logits, tp_axis)[..., : cfg.vocab_size]
-    logits = logits.reshape(B, T, -1)
-    if cfg.logit_scale != 1.0:
-        logits = logits * cfg.logit_scale
-    return logits, {"k": new_k, "v": new_v}
+    return _model_step(
+        cfg, params, tokens, cache, pos,
+        lambda p: _verify_core(cfg, rope, p), tp_axis, gather_logits,
+        tp_compress, tp_reduce)
 
 
 def forward_batched_verify_overlap(
@@ -1568,69 +1498,19 @@ def forward_batched_verify_overlap(
     ring: bool = True,
     tp_reduce=None,
 ) -> tuple:
-    """``forward_batched_verify`` with the rows split into two interleaved
-    microbatches — the spec-verify twin of ``forward_batched_overlap``
-    (same exactness argument: ``_verify_layer`` is per-row throughout, the
-    flattened [h*T, dim] matmuls compute each row from the full K, and
-    ring-gather chunk order is fixed). Both halves share one layer scan so
-    weights stream once per layer. ``tp_reduce`` composes the same way as
-    in ``forward_batched_overlap``: each half runs the row-parallel
-    ``_verify_layer`` against the ring axis."""
+    """``forward_batched_verify`` with the rows split into two microbatches
+    inside one layer scan — the spec-verify twin of
+    ``forward_batched_overlap`` (same exactness argument: the layer is
+    per-row throughout, the flattened [h*T, dim] matmuls compute each row
+    from the full K, and ring-gather chunk order is fixed). ``tp_reduce``
+    composes the same way: each half runs the row-parallel layer against the
+    ring axis."""
     cfg.refuse_for_plan("the speculative verify step (--spec-draft)")
-    B, T = tokens.shape
-    h = _check_overlap_split(cfg, B)
-    ga = _overlap_axis(tp_axis, ring)
-    x = embed(cfg, params, tokens)  # [B, T, dim]
-    xa, xb = x[:h], x[h:]
-    pa, pb = pos[:h], pos[h:]
-    ka, kb = cache["k"][:, :h], cache["k"][:, h:]
-    va, vb = cache["v"][:, :h], cache["v"][:, h:]
-    layers = params["layers"]
-    quant_scan = any(isinstance(v, QuantTensor) for v in layers.values())
-    row = (_check_tp_reduce(cfg, tp_reduce) and tp_axis is not None
-           and quant_scan)
-    red_compress = tp_reduce == "q80"
-    if row:
-        xa, xb = _scatter(xa, ga), _scatter(xb, ga)
-
-    def layer_step(carry, idx):
-        xa, xb, ka, kb, va, vb = carry
-        lp = {
-            name: (leaf if isinstance(leaf, QuantTensor)
-                   else jax.lax.dynamic_index_in_dim(leaf, idx, 0, keepdims=False))
-            for name, leaf in layers.items()
-        }
-        xa, ka, va = _verify_layer(cfg, lp, rope, xa, ka, va, pa, idx,
-                                   tp_axis=ga, tp_compress=tp_compress,
-                                   row_mode=row, red_compress=red_compress)
-        xb, kb, vb = _verify_layer(cfg, lp, rope, xb, kb, vb, pb, idx,
-                                   tp_axis=ga, tp_compress=tp_compress,
-                                   row_mode=row, red_compress=red_compress)
-        return (xa, xb, ka, kb, va, vb), None
-
-    (xa, xb, ka, kb, va, vb), _ = jax.lax.scan(
-        layer_step, (xa, xb, ka, kb, va, vb),
-        jnp.arange(cfg.n_layers, dtype=jnp.int32),
-    )
-    if row:  # per-half fused final norm (rmsnorm is per-row, so exact)
-        xa = _row_norm_gather(xa, params["rms_final"], ga, tp_compress,
-                              cfg.norm_eps, cfg.dim)
-        xb = _row_norm_gather(xb, params["rms_final"], ga, tp_compress,
-                              cfg.norm_eps, cfg.dim)
-    x = jnp.concatenate([xa, xb], axis=0)
-    new_k = jnp.concatenate([ka, kb], axis=1)
-    new_v = jnp.concatenate([va, vb], axis=1)
-    if not row:
-        x = rmsnorm(x, params["rms_final"], cfg.norm_eps)
-    logits = matmul_any(x.reshape(B * T, cfg.dim),
-                        params["wcls"], name="wcls").astype(jnp.float32)
-    if tp_axis is not None and gather_logits:
-        # slice off lane-alignment vocab padding, exactly like `forward`
-        logits = _gather(logits, tp_axis)[..., : cfg.vocab_size]
-    logits = logits.reshape(B, T, -1)
-    if cfg.logit_scale != 1.0:
-        logits = logits * cfg.logit_scale
-    return logits, {"k": new_k, "v": new_v}
+    split = _check_overlap_split(cfg, tokens.shape[0])
+    return _model_step(
+        cfg, params, tokens, cache, pos,
+        lambda p: _verify_core(cfg, rope, p), tp_axis, gather_logits,
+        tp_compress, tp_reduce, split=split, ring=ring, interleave=False)
 
 
 def forward_train(
@@ -1694,7 +1574,7 @@ def train_layer(
     ring=None,  # (mesh, sp_axis) -> ring attention over that axis
 ) -> jnp.ndarray:
     """One cache-free causal transformer layer (the batched-training twin of
-    the incremental ``_attn_block``/``_ffn_residual`` pair). Shared by the
+    the incremental ``_attention``/``_ffn_residual`` pair). Shared by the
     ``forward_train`` layer scan and the pipeline-parallel stage body."""
     B, T = x.shape[:2]
     group = cfg.n_heads // cfg.n_kv_heads

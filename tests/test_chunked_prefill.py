@@ -1,9 +1,12 @@
 """Chunked prefill + bucketed slot KV (the perf tentpole).
 
-Two invariants under test. (1) Bit-identity: consuming a prompt in fixed
+Two invariants under test. (1) Identity: consuming a prompt in fixed
 token-budget pieces — solo (``Engine.prefill(chunk=...)``) or pooled
 (``admit_begin`` + ``prefill_step`` interleaved with ``step_chunk``) —
-produces EXACTLY the logits/streams of monolithic prefill: each piece
+produces EXACTLY the streams of monolithic prefill, and its logits to the
+last places of a float32 sum (a shorter piece is another compiled program:
+``test_solo_chunked_prefill_logits_bit_identical`` says what is exact and
+what is bounded): each piece
 writes its K/V before any later query attends, so causal masking makes the
 split invisible. Migration between KV buckets carries the whole attended
 slab plus the host sampler chain, so a row crossing buckets mid-stream is
@@ -58,12 +61,30 @@ def _drain_interleaved(sess, out):
 # solo: chunked == monolithic, to the bit
 # ---------------------------------------------------------------------------
 
+def _last_places(got, want) -> float:
+    """The largest difference, in units of the last place of ``want``'s
+    largest magnitude."""
+    return float(np.abs(got - want).max() / np.spacing(np.abs(want).max()))
+
+
 def test_solo_chunked_prefill_logits_bit_identical():
     """Every chunk size (including ragged last pieces and chunk=1) must
-    reproduce the monolithic final-position logits EXACTLY — the causal
-    write-before-attend argument, checked to the bit. Cache contents are
-    compared only over REAL positions: padded-tail slots hold whatever
-    garbage the prefill bucket wrote, by design."""
+    reproduce the monolithic final-position logits — the causal
+    write-before-attend argument. Cache contents are compared only over
+    REAL positions: padded-tail slots hold whatever garbage the prefill
+    bucket wrote, by design.
+
+    EXACT, to the bit: the greedy token; layer 0's K/V (every position
+    written where and as the monolithic prefill writes it); and everything
+    when the one piece is the monolithic program (chunk >= n). A piece
+    shorter than the monolithic bucket is ANOTHER program, and XLA:CPU sums
+    attention's two contractions (``gqa_attention``'s q.K and att.V einsums,
+    batched over the KV heads) in an order that follows the number of query
+    rows: 8 rows against the same cache give other last bits than 32 (a
+    plain ``x @ w`` does not depend on its row count, nor do the softmax's
+    sums). So from layer 0's attention on, the two sides are apart by the
+    rounding of a float32 sum and no more: measured 2.2 last places of the
+    largest logit and 2.0 of the largest cached value; held to 8 and 8."""
     params = llama.random_params(CFG, seed=0, dtype=np.float32)
     eng = Engine(CFG, params, SamplerConfig(temperature=0.0))
     logits_mono, cache_mono = eng.prefill(eng.new_cache(), LONG_PROMPT)
@@ -71,11 +92,19 @@ def test_solo_chunked_prefill_logits_bit_identical():
     n = len(LONG_PROMPT)
     for chunk in (1, 4, 7, 16, n, n + 5):
         logits, cache = eng.prefill(eng.new_cache(), LONG_PROMPT, chunk=chunk)
-        assert np.array_equal(np.asarray(logits), ref), f"chunk={chunk}"
+        logits = np.asarray(logits)
+        assert logits.argmax() == ref.argmax(), f"chunk={chunk}"
+        if chunk >= n:
+            assert np.array_equal(logits, ref), f"chunk={chunk}"
+        else:
+            assert _last_places(logits, ref) <= 8, f"chunk={chunk}"
         for k in cache_mono:  # [L, S, kv, hd]: positions on axis 1
             a = np.asarray(cache[k])[:, :n]
             b = np.asarray(cache_mono[k])[:, :n]
-            assert np.array_equal(a, b), f"chunk={chunk} cache[{k}]"
+            exact = slice(None) if chunk >= n else slice(0, 1)
+            assert np.array_equal(a[exact], b[exact]), \
+                f"chunk={chunk} cache[{k}]"
+            assert _last_places(a, b) <= 8, f"chunk={chunk} cache[{k}]"
 
 
 def test_solo_prefill_chunk_validation():

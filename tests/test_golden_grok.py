@@ -131,8 +131,9 @@ def test_grok1_block_matches_reference_golden(tmp_path):
 
     k_cache = jnp.zeros((cfg.seq_len, N_KV, HEAD), jnp.float32)
     v_cache = jnp.zeros((cfg.seq_len, N_KV, HEAD), jnp.float32)
-    att_out, _, _ = llama._attn_block(
-        cfg, lp, rope, x, k_cache, v_cache, jnp.int32(0)
+    att_out, _, _ = llama._attention(
+        cfg, lp, x, llama._solo_core(cfg, rope, jnp.int32(0)), k_cache,
+        v_cache, None
     )
     out = np.asarray(llama._ffn_residual(cfg, lp, x, att_out))[0]
 

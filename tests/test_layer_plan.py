@@ -293,3 +293,57 @@ def test_engine_generate_matches_pool(tiny):
     solo = [t for t, _ in eng.generate(prompt, 8)]
     rows = eng.generate_batch([prompt, tiny["seqs"][0][:5]], 8)
     assert rows[0] == solo
+
+
+def test_a_plan_of_one_kind_is_the_uniform_model():
+    """The seam between ``models.llama`` and ``models.layer_plan`` is real:
+    a plan whose every layer is ``("full", "dense")``, over a uniform tiny
+    model's weights stacked under ``params["layers"]["full_dense"]``, walks
+    the plan's runs, cores and cache tree and gives the uniform model's
+    logits and K/V bit for bit (CPU, float32), through ``forward`` (a
+    prefill of 5, then a decode step) and ``forward_batched`` (3 rows at
+    positions of their own)."""
+    from dllama_tpu.models.config import ModelConfig
+
+    base = dict(arch="llama", dim=64, hidden_dim=128, n_layers=3, n_heads=4,
+                n_kv_heads=2, vocab_size=96, seq_len=32, head_size=16,
+                kv_dim=32, dtype="float32")
+    uni = ModelConfig(**base)
+    plan = ModelConfig(**base, layer_plan=(("full", "dense"),) * 3)
+    assert plan.plan_text() == "F.D*3" and not uni.layer_plan
+    params = llama.random_params(uni, seed=3, dtype=np.float32)
+    rng = np.random.default_rng(4)
+    for n in ("rms_att", "rms_ffn"):  # norms that are not all ones
+        params["layers"][n] = (1.0 + 0.1 * rng.standard_normal(
+            params["layers"][n].shape)).astype(np.float32)
+    by_cfg = {uni: params,
+              plan: dict(params, layers={"full_dense": params["layers"]})}
+
+    def same(a, b):
+        la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+        assert len(la) == len(lb)
+        for x, y in zip(la, lb):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+    def solo(cfg):
+        fwd = jax.jit(lambda p, r, t, c, ps: llama.forward(cfg, p, r, t, c, ps))
+        rope, cache = llama.rope_tables(cfg), llama.init_cache(cfg)
+        first, cache = fwd(by_cfg[cfg], rope, jnp.asarray([7, 1, 50, 3, 9]),
+                           cache, jnp.int32(0))
+        then, cache = fwd(by_cfg[cfg], rope, jnp.asarray([11]), cache,
+                          jnp.int32(5))
+        return first, then, cache
+
+    def pooled(cfg):
+        cache = llama.init_batch_cache(cfg, 3, seq_len=16)
+        assert sorted(cache) == ["k", "v"]
+        fill = np.random.default_rng(5)
+        cache = {k: jnp.asarray(fill.standard_normal(v.shape), jnp.float32)
+                 for k, v in sorted(cache.items())}
+        step = jax.jit(lambda p, r, t, c, ps: llama.forward_batched(
+            cfg, p, r, t, c, ps))
+        return step(by_cfg[cfg], llama.rope_tables(cfg),
+                    jnp.asarray([5, 6, 7]), cache, jnp.asarray([3, 0, 7]))
+
+    same(solo(plan), solo(uni))
+    same(pooled(plan), pooled(uni))

@@ -369,7 +369,7 @@ def test_row_decode_matches_gather_only(qp40, qp80, kind, tp, mode):
 @pytest.mark.parametrize("kind,tp,mode", _POINTS[:2],
                          ids=["q40-tp2-plain", "q40-tp2-q80"])
 def test_row_verify_matches_gather_only(qp40, qp80, kind, tp, mode):
-    """Speculative verify runs the row-parallel `_verify_layer` — plain
+    """Speculative verify runs the row-parallel `_layer` — plain
     mode must match the gather-only engine's streams and acceptance
     statistics exactly; q80 must be pinned-deterministic (see decode)."""
     qp = qp40 if kind == "q40" else qp80
