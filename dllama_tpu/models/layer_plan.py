@@ -34,7 +34,8 @@ import jax.numpy as jnp
 
 from dllama_tpu.models import llama
 from dllama_tpu.models.config import ModelConfig
-from dllama_tpu.models.moe import moe_ffn, pick_counts, route_topk
+from dllama_tpu.models.moe import (moe_ffn, moe_ffn_counted, pick_counts,
+                                   route_topk)
 from dllama_tpu.ops.attention import gqa_attention
 from dllama_tpu.ops.norms import rmsnorm
 from dllama_tpu.ops.rope import apply_rope, rope_table
@@ -184,12 +185,14 @@ def _ffn(cfg: ModelConfig, ffn: str, lp: dict, x, layer, live):
     if ffn == "dense":
         return x + llama._dense_ffn(cfg, lp, x, lp["rms_ffn"], layer=layer), None
     xb = rmsnorm(x, lp["rms_ffn"], cfg.norm_eps)
-    picks = None
-    if live is not None:
-        # the same product as inside moe_ffn: the compiler keeps one
-        topi, _ = route_topk(cfg, lp["moe_router"], xb, lp.get("moe_bias"))
-        picks = pick_counts(cfg, topi, live)
-    return x + moe_ffn(cfg, lp, xb, layer), picks
+    if live is None:
+        return x + moe_ffn(cfg, lp, xb, layer), None
+    # the same product as inside moe_ffn: the compiler keeps one
+    topi, _ = route_topk(cfg, lp["moe_router"], xb, lp.get("moe_bias"))
+    out, reads = moe_ffn_counted(cfg, lp, xb, layer, live=live)
+    picks = jnp.append(pick_counts(cfg, topi, live),
+                       jnp.asarray(reads, jnp.int32))
+    return x + out, picks
 
 
 def _run_step(cfg: ModelConfig, stack: dict, rope: dict, pos, core_of, live,
@@ -219,8 +222,8 @@ def _run_step(cfg: ModelConfig, stack: dict, rope: dict, pos, core_of, live,
 
 def _run_layers(cfg: ModelConfig, params: dict, rope: dict, x, cache: dict,
                 pos, core_of, live=None):
-    """Every run of like layers in turn. -> (x, cache, picks [3] or None)"""
-    carry = (x, cache, None if live is None else jnp.zeros((3,), jnp.int32))
+    """Every run of like layers in turn. -> (x, cache, picks [4] or None)"""
+    carry = (x, cache, None if live is None else jnp.zeros((4,), jnp.int32))
     for kind, p0, c0, count in cfg.plan_runs:
         step = _run_step(cfg, params["layers"][kind_name(kind)], rope, pos,
                          core_of, live, kind, p0, c0)
@@ -245,9 +248,11 @@ def forward_batched(cfg: ModelConfig, params: dict, rope: dict, tokens,
                     cache: dict, pos, live=None) -> tuple:
     """One decode step for B independent sequences -> (logits [B, vocab],
     cache), and with ``live`` [B] (bool: the rows that are decoding) a third
-    value, int32 [3], summed over the expert layers: the live rows' picks
-    that fell on held experts, all their picks, and the distinct held
-    experts they picked (``moe.pick_counts``)."""
+    value, int32 [4], summed over the expert layers: the live rows' picks
+    that fell on held experts, all their picks, the distinct held experts
+    they picked (``moe.pick_counts``), and the expert plane sets the step
+    READ (``moe.moe_ffn_counted``). Rows that are not live activate no
+    expert where the expert layer runs only the picked experts."""
     x = llama.embed(cfg, params, tokens)
     x, cache, picks = _run_layers(cfg, params, rope, x, cache, pos,
                                   _rows_core, live)

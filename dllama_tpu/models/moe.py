@@ -25,14 +25,24 @@ Compute paths:
 * Quantized stacks under the scalar-prefetch layer scan (``layer`` given):
   the expert planes stay layer-stacked ([L, E, ...] folded to [L*E, ...], a
   free bitcast) and a traced ``layer * E + e`` steers each fused kernel's
-  DMA. For small T (decode T==1, speculative verify T==k_spec+1) only the
-  UNION of the rows' top-k selected experts is computed — at most
-  min(E, T*k) expert plane reads instead of E — the bandwidth win that
-  makes Q40 Grok-1-class models decode at quantized speed, the analog of
-  the reference running only active experts
-  (`/root/reference/src/grok1-tasks.cpp:128-143`). For batched prefill every
-  expert runs once (different rows pick different experts) with the same
-  zero-copy indexing.
+  DMA. **The rule that picks a branch reads shapes and ``cfg`` only:** where
+  the rows' picks cannot be expected to cover the held experts,
+  ``T * k < n_experts`` (ALL the experts the router scores: a process that
+  holds a share of them has the same fraction of its experts picked), the
+  step runs ``_moe_decode_selected``: only the distinct held experts that
+  its counted rows picked, one trip of a loop each (up -> act -> down ->
+  accumulate in float32). The trip count is a TRACED number, read from the
+  combine matrix; ``cap = min(n_experts_held, T * k)`` is only its static
+  bound, and rows a caller masks out (``live``: a pool's free and finished
+  rows) count for nothing. That is decode T==1, a pooled step of a model
+  that holds 32 of 256 experts (8 rows x 8 picks reach about 4 of the 32),
+  a speculative verify step T==k_spec+1: the bandwidth win that makes Q40
+  MoE models decode at quantized speed, the analog of the reference running
+  only active experts (`/root/reference/src/grok1-tasks.cpp:128-143`).
+  ``moe_ffn_counted`` also returns how many expert plane sets the call read.
+  Otherwise (batched prefill; a pooled step of Mixtral, whose 24 rows x 2
+  picks do cover its 8 experts) every held expert runs once with the same
+  zero-copy indexing, and the combine matrix zeroes what a row did not pick.
 """
 
 from __future__ import annotations
@@ -174,22 +184,36 @@ def _expert_down(h: jnp.ndarray, w, base=None) -> jnp.ndarray:
 
 
 def _moe_decode_selected(cfg: ModelConfig, lp: dict, xb: jnp.ndarray, layer,
-                         tp_axis=None, tp_compress: bool = False) -> jnp.ndarray:
-    """Small-T decode/verify with layer-stacked quantized experts: run ONLY
-    the union of the rows' top-k selected experts, each kernel DMA-ing just
-    that expert's planes. T==1 is plain decode (the union is exactly the
-    top-k); T==k_spec+1 is a speculative verify step, which still reads at
-    most min(E, T*k) expert plane sets instead of all E. Exact same math as
-    the dense combine: every expert outside the union has zero combine
-    weight for every row, and union slots beyond the actually-selected set
-    (ties in the top-cap selection) multiply a zero weight.
+                         tp_axis=None, tp_compress: bool = False,
+                         live=None) -> tuple:
+    """Small-T step with layer-stacked quantized experts: run ONLY the
+    distinct held experts that the step's counted rows picked, each kernel
+    DMA-ing just that expert's planes -> (out [T, dim], the expert plane
+    sets read, int32). How many that is comes from the input: ``n``, the
+    columns of the combine matrix whose maximum is positive, is a traced
+    number and the trip count of the loop over experts; ``cap = min(E, T*k)``
+    is only its static upper bound. ``n == 0`` (no counted row picked a held
+    expert) runs no expert and gives zeros. T==1 is plain decode, T==B a
+    pooled step, T==k_spec+1 a speculative verify step.
+
+    ``live`` [T] bool: the rows that count. A row that is not decoding (a
+    free or finished row of a pool) has its combine weights zeroed BEFORE
+    the experts are chosen, so it activates no expert and its expert part is
+    zero; without ``live`` every row counts.
+
+    Same math as the dense combine: an expert outside the chosen ones has
+    zero combine weight for every row. The sum over the experts is kept in
+    float32 and rounded once.
 
     Under quantized TP (``tp_axis``): the expert planes are output shards;
     all selected experts' hidden activations are gathered in ONE collective
     (decode payloads are latency-bound — collective count matters more than
-    bytes, see ``parallel.collectives``), then each feeds its down matmul and the
-    combined output — accumulated in output shards — is gathered at the end:
-    2 collectives per MoE FFN, like the dense FFN's pair.
+    bytes, see ``parallel.collectives``), which needs the static
+    ``[cap, T, H]`` stack: there the scans run all ``cap`` slots (slots past
+    ``n`` multiply a zero weight) and ``cap`` plane sets are read. Each
+    hidden feeds its down matmul and the combined output — accumulated in
+    output shards — is gathered at the end: 2 collectives per MoE FFN, like
+    the dense FFN's pair.
     """
     act = ACTIVATIONS[cfg.hidden_act]
     E, k = cfg.n_experts_held, cfg.n_active_experts
@@ -197,10 +221,14 @@ def _moe_decode_selected(cfg: ModelConfig, lp: dict, xb: jnp.ndarray, layer,
     cap = min(E, T * k)
     # [T, E] f32, zero off top-k
     combine = route(cfg, lp["moe_router"], xb, lp.get("moe_bias"))
-    # every expert any row selected has a positive combine weight somewhere,
-    # and there are at most T*k of them — the top `cap` column-maxima cover
-    # the whole union (extra slots carry zero weight and contribute nothing)
-    _, expert_ids = jax.lax.top_k(combine.max(axis=0), cap)  # [cap]
+    if live is not None:
+        combine = jnp.where(live[:, None], combine, 0.0)
+    # every expert a counted row selected has a positive combine weight
+    # somewhere, and there are at most T*k of them: top_k over the column
+    # maxima puts them first, n of them
+    col_max = combine.max(axis=0)
+    _, expert_ids = jax.lax.top_k(col_max, cap)  # [cap]
+    n = (col_max > 0).sum().astype(jnp.int32)  # <= cap
     base = layer * E
 
     fused = "moe_upgate" in lp
@@ -209,59 +237,59 @@ def _moe_decode_selected(cfg: ModelConfig, lp: dict, xb: jnp.ndarray, layer,
     down_flat = _flat_experts(lp["moe_down"])
     out_dim = down_flat.out_features  # local under tp, full otherwise
 
-    def up_step(_, j):
-        idx = base + expert_ids[j]
+    def hidden(e):
+        idx = base + e
         if fused:
             ug = matmul_any(xb, up_flat, idx, name="expert_upgate")
             half = ug.shape[-1] // 2
-            h = ug[..., :half] * act(ug[..., half:])
-        else:
-            h = (matmul_any(xb, up_flat, idx, name="expert_up")
-                 * act(matmul_any(xb, gate_flat, idx, name="expert_gate")))
-        return None, h
+            return ug[..., :half] * act(ug[..., half:])
+        return (matmul_any(xb, up_flat, idx, name="expert_up")
+                * act(matmul_any(xb, gate_flat, idx, name="expert_gate")))
 
-    _, hs = jax.lax.scan(up_step, None, jnp.arange(cap, dtype=jnp.int32))
-    hs = _gather(hs, tp_axis, tp_compress)  # [cap, T, full hidden] in one hop
-
-    def down_step(acc, jh):
-        j, h = jh
-        e = expert_ids[j]
+    def weighted_down(acc, e, h):
         d = matmul_any(h, down_flat, base + e,
                        name="expert_down")  # [T, out_dim]
-        w_e = jax.lax.dynamic_index_in_dim(combine, e, axis=1)  # [T, 1]
-        return acc + d * w_e.astype(d.dtype), None
+        w_e = jax.lax.dynamic_index_in_dim(combine, e, axis=1)  # [T, 1] f32
+        return acc + d.astype(jnp.float32) * w_e
 
+    acc = jnp.zeros((T, out_dim), jnp.float32)
+    if tp_axis is None:
+        # up -> act -> down -> accumulate of one expert a trip, n trips
+        def one_expert(j, acc):
+            e = expert_ids[j]
+            return weighted_down(acc, e, hidden(e))
+
+        acc = jax.lax.fori_loop(0, n, one_expert, acc)
+        return acc.astype(xb.dtype), n
+
+    _, hs = jax.lax.scan(lambda _, e: (None, hidden(e)), None, expert_ids)
+    hs = _gather(hs, tp_axis, tp_compress)  # [cap, T, full hidden] in one hop
     acc, _ = jax.lax.scan(
-        down_step, jnp.zeros((T, out_dim), xb.dtype),
-        (jnp.arange(cap, dtype=jnp.int32), hs))
-    return _gather(acc, tp_axis, tp_compress)
+        lambda acc, eh: (weighted_down(acc, *eh), None), acc,
+        (expert_ids, hs))
+    return _gather(acc.astype(xb.dtype), tp_axis, tp_compress), cap
 
 
 @jax.named_scope("moe")
-def moe_ffn(cfg: ModelConfig, lp: dict, xb: jnp.ndarray, layer=None,
-            tp_axis=None, tp_compress: bool = False) -> jnp.ndarray:
-    """MoE FFN over xb [..., dim] -> [..., dim].
-
-    lp holds: moe_router [dim, E], moe_up/moe_gate [E, dim, hidden],
-    moe_down [E, hidden, dim] — each expert stack a dense array or a
-    quantized (QuantTensor) stack — and, for the "sigmoid_bias" router,
-    moe_bias [E]. Where the process holds a share of the experts
-    (``cfg.expert_count``) the stacks hold those, the router still scores
-    all E, and the result is the held experts' PART of the sum: what expert
-    parallelism adds up across processes. With ``layer`` (the scalar-prefetch scan),
-    quantized stacks carry a leading layer axis and dense leaves arrive
-    already layer-indexed. ``tp_axis`` (inside shard_map, quantized TP):
-    expert stacks are output shards; the hidden activation is gathered
-    before the down matmuls and the output once after the combine.
-    """
+def moe_ffn_counted(cfg: ModelConfig, lp: dict, xb: jnp.ndarray, layer=None,
+                    tp_axis=None, tp_compress: bool = False,
+                    live=None) -> tuple:
+    """``moe_ffn`` -> (out, the expert plane sets this call read: a traced
+    int32 where the input decides it, else a Python int). ``live`` [T] bool
+    (it matters to a small-T step of quantized stacks only): the rows that
+    are decoding; the others activate no expert (``_moe_decode_selected``)."""
     act = ACTIVATIONS[cfg.hidden_act]
     up_names = ("moe_upgate",) if "moe_upgate" in lp else ("moe_up", "moe_gate")
     quant_experts = all(
         isinstance(lp.get(n), QuantTensor) for n in up_names + ("moe_down",)
     )
+    # the selected path where the rows' picks cannot be expected to cover
+    # the held experts: from shapes alone (a process that holds a share has
+    # T*k/n_experts of its experts picked, whatever it holds)
     if (layer is not None and quant_experts and xb.ndim == 2
-            and xb.shape[0] * cfg.n_active_experts < cfg.n_experts_held):
-        return _moe_decode_selected(cfg, lp, xb, layer, tp_axis, tp_compress)
+            and xb.shape[0] * cfg.n_active_experts < cfg.n_experts):
+        return _moe_decode_selected(cfg, lp, xb, layer, tp_axis, tp_compress,
+                                    live)
 
     # Under the layer scan, EVERY QuantTensor stack is layer-stacked and needs
     # index-steered kernels — even if a sibling stack fell back to dense (the
@@ -284,4 +312,23 @@ def moe_ffn(cfg: ModelConfig, lp: dict, xb: jnp.ndarray, layer=None,
     h = slice_to_in_features(h, lp["moe_down"])
     down = _expert_down(h, lp["moe_down"], base)
     out = jnp.einsum("...ed,...e->...d", down, combine)
-    return _gather(out, tp_axis, tp_compress)
+    return _gather(out, tp_axis, tp_compress), cfg.n_experts_held
+
+
+def moe_ffn(cfg: ModelConfig, lp: dict, xb: jnp.ndarray, layer=None,
+            tp_axis=None, tp_compress: bool = False) -> jnp.ndarray:
+    """MoE FFN over xb [..., dim] -> [..., dim].
+
+    lp holds: moe_router [dim, E], moe_up/moe_gate [E, dim, hidden],
+    moe_down [E, hidden, dim] — each expert stack a dense array or a
+    quantized (QuantTensor) stack — and, for the "sigmoid_bias" router,
+    moe_bias [E]. Where the process holds a share of the experts
+    (``cfg.expert_count``) the stacks hold those, the router still scores
+    all E, and the result is the held experts' PART of the sum: what expert
+    parallelism adds up across processes. With ``layer`` (the scalar-prefetch scan),
+    quantized stacks carry a leading layer axis and dense leaves arrive
+    already layer-indexed. ``tp_axis`` (inside shard_map, quantized TP):
+    expert stacks are output shards; the hidden activation is gathered
+    before the down matmuls and the output once after the combine.
+    """
+    return moe_ffn_counted(cfg, lp, xb, layer, tp_axis, tp_compress)[0]

@@ -274,9 +274,15 @@ class Engine:
                 "dllama_moe_active_experts_total",
                 "Distinct held experts the live rows picked, summed over "
                 "expert layers and decode steps")
+            self._m_moe_reads = metrics.counter(
+                "dllama_moe_expert_reads_total",
+                "Held experts whose planes the pooled decode step read, "
+                "summed over expert layers and decode steps (the trip "
+                "counts of the expert loop; every held expert where an "
+                "expert layer runs them all)")
             self._m_moe_layer_steps = metrics.counter(
                 "dllama_moe_layer_steps_total",
-                "Expert layers x decode steps the two counters above were "
+                "Expert layers x decode steps the three counters above were "
                 "summed over (a chunk adds its steps x the expert layers)")
             self._m_kv_resident = metrics.gauge(
                 "dllama_kv_resident_bytes",
@@ -286,7 +292,8 @@ class Engine:
                 labelnames=("kind",))
         else:
             self._m_moe_picks = self._m_moe_active = None
-            self._m_moe_layer_steps = self._m_kv_resident = None
+            self._m_moe_reads = self._m_moe_layer_steps = None
+            self._m_kv_resident = None
             self._m_prefill = self._m_step = self._m_chunk = None
             self._m_prefill_chunk = self._m_migrations = None
             self._m_live_rows = None
@@ -667,9 +674,10 @@ class Engine:
         def _make_decode_loop_batch_plan():
             """The pooled decode program of a model with a layer plan: the
             same steps as above, and beside the tokens what the ``live`` rows
-            [B] routed to, summed over the chunk (``moe.pick_counts``: int32
-            [3]). Its own program, so that a uniform model's keeps its
-            fingerprint; the name stays (``jit__decode_loop_batch``)."""
+            [B] routed to and the expert plane sets read, summed over the
+            chunk (``layer_plan.forward_batched``: int32 [4]). Its own
+            program, so that a uniform model's keeps its fingerprint; the
+            name stays (``jit__decode_loop_batch``)."""
 
             @partial(jax.jit, donate_argnums=(2,),
                      static_argnames=("n_steps",))
@@ -692,7 +700,7 @@ class Engine:
                     body,
                     (cache, tokens, pos, keys,
                      jnp.ones(tokens.shape, jnp.bool_),
-                     jnp.zeros((3,), jnp.int32)),
+                     jnp.zeros((4,), jnp.int32)),
                     length=n_steps,
                 )
                 return out, cache, keys, ok, picks
@@ -3330,15 +3338,17 @@ class BatchSession:
 
     def _account_picks(self, picked) -> None:
         """One chunk's routing of a model with expert layers of which a
-        share is held (``moe.pick_counts``, summed over the chunk): picks on
-        held experts, all picks, distinct held experts a layer-step."""
+        share is held (``layer_plan.forward_batched``, summed over the
+        chunk): picks on held experts, all picks, distinct held experts a
+        layer-step, and the expert plane sets the program read."""
         eng = self.eng
         if eng._m_moe_picks is None:
             return
-        held, total, active = (int(v) for v in picked)
+        held, total, active, reads = (int(v) for v in picked)
         eng._m_moe_picks.inc(held, held="1")
         eng._m_moe_picks.inc(total - held, held="0")
         eng._m_moe_active.inc(active)
+        eng._m_moe_reads.inc(reads)
         eng._m_moe_layer_steps.inc(
             self.chunk * eng.cfg.plan_count(ffn="moe"))
 

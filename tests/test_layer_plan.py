@@ -183,12 +183,15 @@ def test_batch_session_serves_the_references_greedy_tokens(tiny):
     away = float(_sample(text, 'dllama_moe_picks_total{held="0"}'))
     steps = float(_sample(text, "dllama_moe_layer_steps_total"))
     active = float(_sample(text, "dllama_moe_active_experts_total"))
+    reads = float(_sample(text, "dllama_moe_expert_reads_total"))
     k, layers = cfg.n_active_experts, cfg.plan_count(ffn="moe")
     assert steps > 0 and steps % (4 * layers) == 0
     # every live row picks k experts a layer-step; rows live a whole chunk
     assert (held + away) % (4 * layers * k) == 0 and held + away > 0
     assert 0 < active <= held
     assert active <= steps * cfg.n_experts_held
+    # float32 matrices take the all-experts branch: every held expert read
+    assert reads == steps * cfg.n_experts_held
     assert 'dllama_kv_resident_bytes{kind="window"}' in text
     sess.close()
 

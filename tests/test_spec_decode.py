@@ -211,9 +211,9 @@ def test_spec_verify_routes_to_selected_experts(monkeypatch):
     seen_t = []
     real = moe_mod._moe_decode_selected
 
-    def spy(cfg, lp, xb, layer, tp_axis=None, tp_compress=False):
+    def spy(cfg, lp, xb, layer, *a, **k):
         seen_t.append(int(xb.shape[0]))
-        return real(cfg, lp, xb, layer, tp_axis, tp_compress)
+        return real(cfg, lp, xb, layer, *a, **k)
 
     monkeypatch.setattr(moe_mod, "_moe_decode_selected", spy)
     list(_engine(kind="q40", cfg=MOE_CFG).generate_spec(
