@@ -1,4 +1,14 @@
-"""Runtime model configuration, derived from the on-disk ModelSpec."""
+"""Runtime model configuration, derived from the on-disk ModelSpec.
+
+What a model IS lives here, each field defaulting to a uniform Llama's: the
+norm (``norm``: "rms", or "layer", a LayerNorm without bias), the block
+(``block``: "sequential", attention then FFN each behind its own norm, or
+"parallel", ONE norm a layer feeding attention and FFN side by side), the
+routers (``router``: "softmax", "sigmoid_bias", or "sigmoid" without a
+bias), experts that are always on beside the routed ones (``shared_dim``,
+``shared_scale``), which attention kinds of a layer plan rotate
+(``rope_attention``) and a head tied to the embedding (``tied_embedding``).
+"""
 
 from __future__ import annotations
 
@@ -24,7 +34,9 @@ def resolve_dtype(name: str | None, default: str) -> jnp.dtype:
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
-    arch: str  # "llama" | "grok1" | "mixtral" | "mimo_v2" (needs a layer_plan)
+    # "llama" | "grok1" | "mixtral" | "mimo_v2" | "cohere2_moe" (the last two
+    # need a layer_plan)
+    arch: str
     dim: int
     hidden_dim: int
     n_layers: int
@@ -66,13 +78,31 @@ class ModelConfig:
     value_scale: float = 1.0  # v <- value_scale * v after its projection
     # "softmax": softmax over all experts, the top k renormalised;
     # "sigmoid_bias": sigmoid scores, the top k of score + bias chosen, the
-    # chosen experts' unbiased scores renormalised
+    # chosen experts' unbiased scores renormalised; "sigmoid": the same
+    # without a bias (the scores choose)
     router: str = "softmax"
     # the experts this process holds of the ``n_experts`` the router scores:
     # [expert_first, expert_first + expert_count); 0 = all of them. An expert
     # layer then returns its held experts' part of the sum
     expert_first: int = 0
     expert_count: int = 0
+    # experts that are always on beside the routed ones, held as ONE gated FFN
+    # of their summed width (``shared_upgate`` / ``shared_down`` in the
+    # kind's stack; 0: none), its output times ``shared_scale`` (1 / their
+    # number where they are averaged)
+    shared_dim: int = 0
+    shared_scale: float = 1.0
+    # "rms": w * x / sqrt(mean(x^2) + eps); "layer": the same of x - mean(x)
+    # (a LayerNorm without bias)
+    norm: str = "rms"
+    # "sequential": x += att(norm_att(x)); x += ffn(norm_ffn(x)). "parallel":
+    # h = norm_att(x); x += att(h) + ffn(h), one norm a layer (no ``rms_ffn``)
+    block: str = "sequential"
+    # the attention kinds of a layer plan whose q and k rotate
+    rope_attention: tuple = ("full", "window")
+    # the head is the embedding: ``_head`` multiplies by ``wcls`` where the
+    # parameters bring the table's planes, else by ``embedding`` transposed
+    tied_embedding: bool = False
 
     def __post_init__(self):
         # Arch-implied semantics, resolved from None sentinels: the Grok
@@ -111,8 +141,18 @@ class ModelConfig:
             if self.plan_count("window") and self.window < 1:
                 raise ValueError(
                     f"window layers need a window, got {self.window}")
-        if self.router not in ("softmax", "sigmoid_bias"):
+        if self.router not in ("softmax", "sigmoid_bias", "sigmoid"):
             raise ValueError(f"unknown router {self.router!r}")
+        if self.norm not in ("rms", "layer"):
+            raise ValueError(f"unknown norm {self.norm!r}")
+        if self.block not in ("sequential", "parallel"):
+            raise ValueError(f"unknown block {self.block!r}")
+        object.__setattr__(self, "rope_attention", tuple(self.rope_attention))
+        if ((self.block == "parallel" or self.shared_dim
+             or self.norm == "layer") and not self.layer_plan):
+            raise ValueError(
+                "a parallel block, a LayerNorm and shared experts are built "
+                "for a model with a layer_plan (models/layer_plan.py)")
         if not 0 <= self.expert_first <= self.n_experts - self.expert_count:
             raise ValueError(
                 f"held experts [{self.expert_first}, +{self.expert_count}) "

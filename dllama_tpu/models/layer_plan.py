@@ -13,7 +13,11 @@ Parameters: ``params["layers"]`` holds one stack a kind, ``"full_dense"``,
 model's ``layers`` (``wqkv`` or ``wq wk wv``, ``wo``, ``rms_att``,
 ``rms_ffn``; ``w13``/``w2`` or ``w1 w3 w2``; ``moe_router``, ``moe_bias``,
 ``moe_upgate`` or ``moe_up moe_gate``, ``moe_down``) plus ``sink`` [n, heads]
-on window kinds. The forward walks ``cfg.plan_runs``: a ``lax.scan`` over
+on window kinds and, where experts are always on (``cfg.shared_dim``),
+``shared_upgate`` / ``shared_down``. A parallel block (``cfg.block``) has one
+norm a layer, ``rms_att`` (the name stays whatever ``cfg.norm`` is), and an
+attention kind outside ``cfg.rope_attention`` does not rotate (it has no
+tables). The forward walks ``cfg.plan_runs``: a ``lax.scan`` over
 each run of like layers, indexing the kind's stack (quantized planes stay
 stacked and the kernels' scalar prefetch picks the layer, as in
 ``llama.forward``).
@@ -37,7 +41,7 @@ from dllama_tpu.models.config import ModelConfig
 from dllama_tpu.models.moe import (moe_ffn, moe_ffn_counted, pick_counts,
                                    route_topk)
 from dllama_tpu.ops.attention import gqa_attention
-from dllama_tpu.ops.norms import rmsnorm
+from dllama_tpu.ops.norms import NORMS
 from dllama_tpu.ops.rope import apply_rope, rope_table
 
 #: cache leaves and rope tables of each attention kind
@@ -89,7 +93,7 @@ def rope_tables(cfg: ModelConfig) -> dict:
     rd = cfg.rope_dim or cfg.head_size
     out = {}
     for att, (ck, sk) in ROPE_KEYS.items():
-        if cfg.plan_count(att):
+        if cfg.plan_count(att) and att in cfg.rope_attention:
             theta = cfg.rope_theta_window if att == "window" else cfg.rope_theta
             cos, sin = rope_table(cfg.seq_len, rd, theta)
             out[ck], out[sk] = jnp.asarray(cos), jnp.asarray(sin)
@@ -97,7 +101,10 @@ def rope_tables(cfg: ModelConfig) -> dict:
 
 
 def _rope(cfg: ModelConfig, x, cos, sin):
-    """Rotate the first ``rope_dim`` dimensions of every head; the rest pass."""
+    """Rotate the first ``rope_dim`` dimensions of every head; the rest
+    pass. ``cos`` None: an attention kind that does not rotate."""
+    if cos is None:
+        return x
     rd = cfg.rope_dim or cfg.head_size
     if rd == x.shape[-1]:
         return apply_rope(x, cos, sin, cfg.rope_style)
@@ -127,8 +134,10 @@ def _solo_core(cfg: ModelConfig, att: str, lp: dict, rope: dict, pos, cidx):
 
     def core(q, k, v, k_cache, v_cache, layer):
         T = q.shape[0]
-        cos = jax.lax.dynamic_slice_in_dim(rope[ck], pos, T)[:, None, :]
-        sin = jax.lax.dynamic_slice_in_dim(rope[sk], pos, T)[:, None, :]
+        cos = sin = None
+        if ck in rope:
+            cos = jax.lax.dynamic_slice_in_dim(rope[ck], pos, T)[:, None, :]
+            sin = jax.lax.dynamic_slice_in_dim(rope[sk], pos, T)[:, None, :]
         q, k = _rope(cfg, q, cos, sin), _rope(cfg, k, cos, sin)
         if att == "window":
             if T > cfg.max_prefill_piece:
@@ -158,8 +167,10 @@ def _rows_core(cfg: ModelConfig, att: str, lp: dict, rope: dict, pos, cidx):
     ck, sk = ROPE_KEYS[att]
 
     def core(q, k, v, k_cache, v_cache, layer):
-        cos = rope[ck][pos][:, None, :]
-        sin = rope[sk][pos][:, None, :]
+        cos = sin = None
+        if ck in rope:
+            cos = rope[ck][pos][:, None, :]
+            sin = rope[sk][pos][:, None, :]
         q, k = _rope(cfg, q, cos, sin), _rope(cfg, k, cos, sin)
         if att == "window":
             rows = jnp.arange(q.shape[0], dtype=jnp.int32)
@@ -180,26 +191,30 @@ def _rows_core(cfg: ModelConfig, att: str, lp: dict, rope: dict, pos, cidx):
     return core
 
 
-def _ffn(cfg: ModelConfig, ffn: str, lp: dict, x, layer, live):
-    """The FFN half on the residual after attention -> (x, picks or None)."""
+def _ffn(cfg: ModelConfig, ffn: str, lp: dict, x, norm_w, layer, live):
+    """The FFN of the residual ``x`` behind the norm ``norm_w`` -> (its
+    output, picks or None)."""
     if ffn == "dense":
-        return x + llama._dense_ffn(cfg, lp, x, lp["rms_ffn"], layer=layer), None
-    xb = rmsnorm(x, lp["rms_ffn"], cfg.norm_eps)
+        return llama._dense_ffn(cfg, lp, x, norm_w, layer=layer), None
+    xb = NORMS[cfg.norm](x, norm_w, cfg.norm_eps)
     if live is None:
-        return x + moe_ffn(cfg, lp, xb, layer), None
+        return moe_ffn(cfg, lp, xb, layer), None
     # the same product as inside moe_ffn: the compiler keeps one
     topi, _ = route_topk(cfg, lp["moe_router"], xb, lp.get("moe_bias"))
     out, reads = moe_ffn_counted(cfg, lp, xb, layer, live=live)
     picks = jnp.append(pick_counts(cfg, topi, live),
                        jnp.asarray(reads, jnp.int32))
-    return x + out, picks
+    return out, picks
 
 
 def _run_step(cfg: ModelConfig, stack: dict, rope: dict, pos, core_of, live,
               kind: tuple, p0: int, c0: int):
     """The scan body of one run of layers of ``kind``: layer ``p0 + i`` of
     the kind's parameter stack, ``c0 + i`` of its attention kind's caches;
-    ``llama._attention`` around this kind's core, then ``_ffn``."""
+    ``llama._attention`` around this kind's core, then ``_ffn``: on the
+    residual after attention behind ``rms_ffn`` (a sequential block), or on
+    the layer's input behind the attention's own norm, joined ``x + att +
+    ffn`` (``cfg.block`` "parallel": one norm a layer, no ``rms_ffn``)."""
     att = kind[0]
     kk, vk = CACHE_KEYS[att]
     widths = (cfg.n_heads * cfg.head_size, _kv_heads(cfg, att) * cfg.head_size)
@@ -212,7 +227,13 @@ def _run_step(cfg: ModelConfig, stack: dict, rope: dict, pos, core_of, live,
         att_out, k_cache, v_cache = llama._attention(
             cfg, lp, x, core, cache[kk], cache[vk], idx, widths=widths)
         cache = dict(cache, **{kk: k_cache, vk: v_cache})
-        x, got = _ffn(cfg, kind[1], lp, x + att_out, idx, live)
+        if cfg.block == "parallel":
+            out, got = _ffn(cfg, kind[1], lp, x, lp["rms_att"], idx, live)
+            x = (x + att_out + out).astype(x.dtype)
+        else:
+            x = x + att_out
+            out, got = _ffn(cfg, kind[1], lp, x, lp["rms_ffn"], idx, live)
+            x = x + out
         if got is not None:
             picks = picks + got
         return (x, cache, picks), None

@@ -28,7 +28,7 @@ from dllama_tpu.models.config import ModelConfig
 from dllama_tpu.ops import flash_decode, fused_rope_cache
 from dllama_tpu.ops.activations import ACTIVATIONS
 from dllama_tpu.ops.attention import gqa_attention
-from dllama_tpu.ops.norms import rmsnorm
+from dllama_tpu.ops.norms import NORMS, centred, rmsnorm
 from dllama_tpu.ops.qmatmul import (
     QuantTensor, matmul_any, norm_fusion_engages, qmatmul_norm,
     quantize_tensor, slice_to_in_features,
@@ -574,8 +574,10 @@ def rope_tables(cfg: ModelConfig) -> dict:
 # Forward pass
 # ---------------------------------------------------------------------------
 
-def _norm_proj(x, norm_w, w, layer, eps, name=None):
-    """``rmsnorm(x, norm_w) @ w``. With DLLAMA_FUSE_NORM and a quantized
+def _norm_proj(x, norm_w, w, layer, eps, name=None, norm="rms"):
+    """``rmsnorm(x, norm_w) @ w`` (``norm`` "layer": a LayerNorm without
+    bias, which is the rmsnorm of the centred input, so the fused kernel
+    takes ``centred(x)``). With DLLAMA_FUSE_NORM and a quantized
     ``w``, the norm rides inside the matmul kernel as an x-block epilogue
     (qmatmul.qmatmul_norm — bit-identical in the kernel's products; the q40
     recentering term's block sums are XLA's to order, a last place apart in
@@ -584,8 +586,9 @@ def _norm_proj(x, norm_w, w, layer, eps, name=None):
     projections call this per projection: fused, the epilogue recomputes
     in-register (the point); unfused, XLA CSEs the repeated rmsnorm."""
     if norm_fusion_engages(w):
-        return qmatmul_norm(x, norm_w, w, layer, eps, name)
-    return matmul_any(rmsnorm(x, norm_w, eps), w, layer, name)
+        return qmatmul_norm(centred(x) if norm == "layer" else x, norm_w, w,
+                            layer, eps, name)
+    return matmul_any(NORMS[norm](x, norm_w, eps), w, layer, name)
 
 
 def _check_tp_reduce(cfg: ModelConfig, tp_reduce) -> bool:
@@ -649,12 +652,12 @@ def _dense_ffn(cfg: ModelConfig, lp: dict, x: jnp.ndarray, norm_w, tp_axis=None,
     act = ACTIVATIONS[cfg.hidden_act]
     eps = cfg.norm_eps
     if "w13" in lp:  # fused single-kernel up|gate projection (fuse_qkv_ffn)
-        u = _norm_proj(x, norm_w, lp["w13"], layer, eps, name="w13")
+        u = _norm_proj(x, norm_w, lp["w13"], layer, eps, "w13", cfg.norm)
         half = u.shape[-1] // 2
         h = act(u[..., :half]) * u[..., half:]
         return matmul_any(h, lp["w2"], layer, name="w2")
-    h = (act(_norm_proj(x, norm_w, lp["w1"], layer, eps, name="w1"))
-         * _norm_proj(x, norm_w, lp["w3"], layer, eps, name="w3"))
+    h = (act(_norm_proj(x, norm_w, lp["w1"], layer, eps, "w1", cfg.norm))
+         * _norm_proj(x, norm_w, lp["w3"], layer, eps, "w3", cfg.norm))
     h = slice_to_in_features(_gather(h, tp_axis, tp_compress), lp["w2"])
     return _gather(matmul_any(h, lp["w2"], layer, name="w2"), tp_axis,
                    tp_compress)
@@ -723,11 +726,12 @@ def _qkv(cfg: ModelConfig, lp: dict, x, layer, lead: tuple,
         q, k, v = (matmul_any(x, lp[n], layer, name=n)
                    for n in ("wq", "wk", "wv"))
     elif "wqkv" in lp:  # fused single-kernel projection (fuse_qkv_ffn; no TP)
-        qkv = _norm_proj(x, lp["rms_att"], lp["wqkv"], layer, eps, name="wqkv")
+        qkv = _norm_proj(x, lp["rms_att"], lp["wqkv"], layer, eps, "wqkv",
+                         cfg.norm)
         d, kv = widths or (cfg.dim, cfg.kv_dim)
         q, k, v = qkv[:, :d], qkv[:, d : d + kv], qkv[:, d + kv :]
     else:
-        q, k, v = (_norm_proj(x, lp["rms_att"], lp[n], layer, eps, name=n)
+        q, k, v = (_norm_proj(x, lp["rms_att"], lp[n], layer, eps, n, cfg.norm)
                    for n in ("wq", "wk", "wv"))
     def heads(a, size):
         return a.reshape(*lead, -1, size)
@@ -906,7 +910,7 @@ def _final_norm(cfg: ModelConfig, params: dict, x, tp_axis=None,
     if row:
         return _row_norm_gather(x, params["rms_final"], tp_axis, tp_compress,
                                 cfg.norm_eps, cfg.dim)
-    return rmsnorm(x, params["rms_final"], cfg.norm_eps)
+    return NORMS[cfg.norm](x, params["rms_final"], cfg.norm_eps)
 
 
 def _head(cfg: ModelConfig, params: dict, x, tp_axis=None,
@@ -915,12 +919,18 @@ def _head(cfg: ModelConfig, params: dict, x, tp_axis=None,
     """The step's tail: the residual ``x`` [..., dim] -> logits
     [..., vocab] f32. ``last_pos``: row ``last_pos`` alone (see
     ``forward``). ``normed``: ``x`` has had its ``_final_norm`` (the halves
-    of an overlap step in row mode take it before they rejoin)."""
+    of an overlap step in row mode take it before they rejoin). A tied head
+    (``cfg.tied_embedding``) multiplies by the table's own planes where the
+    parameters bring them as ``wcls``, else by ``embedding`` transposed."""
     if last_pos is not None:
         x = jax.lax.dynamic_slice_in_dim(x, last_pos, 1, axis=0)
     if not normed:
         x = _final_norm(cfg, params, x, tp_axis, tp_compress, row)
-    logits = matmul_any(x.reshape(-1, x.shape[-1]), params["wcls"],
+    if cfg.tied_embedding and "wcls" not in params:
+        wcls = params["embedding"].T.astype(x.dtype)
+    else:
+        wcls = params["wcls"]
+    logits = matmul_any(x.reshape(-1, x.shape[-1]), wcls,
                         name="wcls").astype(jnp.float32)
     if tp_axis is not None and gather_logits:
         # slice off any lane-alignment vocab padding (zero logits there would

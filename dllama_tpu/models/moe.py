@@ -1,4 +1,14 @@
-"""Mixture-of-experts FFN (Grok-1 / Mixtral).
+"""Mixture-of-experts FFN (Grok-1 / Mixtral, and a layer plan's expert kinds).
+
+Routers (``cfg.router``, ``route_topk``): "softmax" (softmax over ALL
+experts, the top k renormalised: Grok-1, Mixtral), "sigmoid_bias" (sigmoid
+scores, the top k of score + correction bias chosen, the chosen experts'
+unbiased scores renormalised) and "sigmoid" (the same without a bias). Beside
+the routed experts a layer may hold experts that are ALWAYS on
+(``cfg.shared_dim``, ``shared_ffn``): one gated FFN of their summed width
+whose output is scaled (by 1 / their number where they are averaged), added
+to the routed sum inside ``moe_ffn_counted`` under the scope ``moe_shared``;
+its kernels are named ``shared_upgate`` / ``shared_down``.
 
 Reference semantics (`/root/reference/src/grok1-tasks.cpp:56-243`):
 router logits -> softmax over ALL experts -> top-k (k = n_active_experts,
@@ -64,8 +74,9 @@ def route_topk(cfg: ModelConfig, router_kernel: jnp.ndarray,
     ``cfg.router`` "sigmoid_bias" (with ``bias`` [E], the per-expert
     correction): the scores are sigmoids, the bias only CHOOSES (top k of
     score + bias) and the chosen experts' unbiased scores weigh,
-    renormalized to sum 1. The indices are over all ``cfg.n_experts`` the
-    router scores, whichever of them this process holds.
+    renormalized to sum 1; "sigmoid": the scores choose. The indices are
+    over all ``cfg.n_experts`` the router scores, whichever of them this
+    process holds.
 
     Router math runs in f32 like the reference (router matmul outputs F32,
     `/root/reference/src/grok1-tasks.cpp:56-60`); selected probabilities are
@@ -79,6 +90,9 @@ def route_topk(cfg: ModelConfig, router_kernel: jnp.ndarray,
         _, topi = jax.lax.top_k(scores + bias.astype(jnp.float32),
                                 cfg.n_active_experts)
         topv = jnp.take_along_axis(scores, topi, axis=-1)
+    elif cfg.router == "sigmoid":
+        topv, topi = jax.lax.top_k(jax.nn.sigmoid(logits),
+                                   cfg.n_active_experts)
     else:
         probs = jax.nn.softmax(logits, axis=-1)
         topv, topi = jax.lax.top_k(probs, cfg.n_active_experts)
@@ -270,6 +284,23 @@ def _moe_decode_selected(cfg: ModelConfig, lp: dict, xb: jnp.ndarray, layer,
     return _gather(acc.astype(xb.dtype), tp_axis, tp_compress), cap
 
 
+@jax.named_scope("moe_shared")
+def shared_ffn(cfg: ModelConfig, lp: dict, xb: jnp.ndarray,
+               layer=None) -> jnp.ndarray:
+    """The experts that are always on, as ONE gated FFN of their summed
+    width: ``shared_scale * down(up(x) * act(gate(x)))`` with
+    ``shared_upgate`` = up | gate (the experts' column order) and
+    ``shared_down``, dense or layer-stacked quantized planes."""
+    act = ACTIVATIONS[cfg.hidden_act]
+    ug = matmul_any(xb, lp["shared_upgate"], layer, name="shared_upgate")
+    half = ug.shape[-1] // 2
+    out = matmul_any(ug[..., :half] * act(ug[..., half:]), lp["shared_down"],
+                     layer, name="shared_down")
+    if cfg.shared_scale != 1.0:
+        out = out * jnp.asarray(cfg.shared_scale, out.dtype)
+    return out
+
+
 @jax.named_scope("moe")
 def moe_ffn_counted(cfg: ModelConfig, lp: dict, xb: jnp.ndarray, layer=None,
                     tp_axis=None, tp_compress: bool = False,
@@ -277,7 +308,19 @@ def moe_ffn_counted(cfg: ModelConfig, lp: dict, xb: jnp.ndarray, layer=None,
     """``moe_ffn`` -> (out, the expert plane sets this call read: a traced
     int32 where the input decides it, else a Python int). ``live`` [T] bool
     (it matters to a small-T step of quantized stacks only): the rows that
-    are decoding; the others activate no expert (``_moe_decode_selected``)."""
+    are decoding; the others activate no expert (``_moe_decode_selected``).
+    Where the layer has always-on experts (``cfg.shared_dim``) their part is
+    added here, for every row, and is no expert plane set of the count."""
+    out, reads = _routed_counted(cfg, lp, xb, layer, tp_axis, tp_compress,
+                                 live)
+    if cfg.shared_dim:
+        out = out + shared_ffn(cfg, lp, xb, layer)
+    return out, reads
+
+
+def _routed_counted(cfg: ModelConfig, lp: dict, xb: jnp.ndarray, layer,
+                    tp_axis, tp_compress: bool, live) -> tuple:
+    """The routed experts' part of ``moe_ffn_counted``."""
     act = ACTIVATIONS[cfg.hidden_act]
     up_names = ("moe_upgate",) if "moe_upgate" in lp else ("moe_up", "moe_gate")
     quant_experts = all(

@@ -290,7 +290,17 @@ class Engine:
                 "(kind=\"full\": grows with the slab's context; "
                 "kind=\"window\": rings, the same at any context)",
                 labelnames=("kind",))
+            self._m_ring_live = metrics.counter(
+                "dllama_kv_ring_live_slots_total",
+                "Ring slots that held a position the query saw, summed over "
+                "the pooled decode step's live rows, steps and window "
+                "layers: min(position + 1, window) a row a step a layer")
+            self._m_ring_scored = metrics.counter(
+                "dllama_kv_ring_scored_slots_total",
+                "Ring slots the window attention scored for those rows, "
+                "steps and layers (the whole ring, ModelConfig.ring_slots)")
         else:
+            self._m_ring_live = self._m_ring_scored = None
             self._m_moe_picks = self._m_moe_active = None
             self._m_moe_reads = self._m_moe_layer_steps = None
             self._m_kv_resident = None
@@ -3238,6 +3248,7 @@ class BatchSession:
                     mask = np.zeros((pool.cap,), np.bool_)
                     mask[live] = True
                     plan["live"] = jnp.asarray(mask)
+                    live_pos = pool.pos[live]  # before the launch moves them
                 if self.ride_t:
                     plan["ride"] = self._ride_operand(riders)
                 chunk, pool.cache, keys, ok, *picks = self.eng.batch_loop(
@@ -3263,6 +3274,7 @@ class BatchSession:
                 self._account_chunk(pool, live, arr, okh, fresh)
                 if picked is not None:
                     self._account_picks(picked)
+                    self._account_ring(live_pos)
                 arrived = self._land_riders(riders, dispatch.t0, fetch.t1)
             for h, pf in arrived:
                 self._go_live_phase(h, pf)
@@ -3351,6 +3363,21 @@ class BatchSession:
         eng._m_moe_reads.inc(reads)
         eng._m_moe_layer_steps.inc(
             self.chunk * eng.cfg.plan_count(ffn="moe"))
+
+    def _account_ring(self, live_pos) -> None:
+        """One chunk's window attention, from numbers the session holds: the
+        ring slots that held a position a live row's query saw (``live_pos``:
+        the rows' positions at the chunk's first step) and the slots the
+        program scored for them, the whole ring a row a step a layer."""
+        eng, cfg = self.eng, self.eng.cfg
+        layers = cfg.plan_count("window")
+        if eng._m_ring_live is None or not layers:
+            return
+        seen = np.minimum(live_pos[:, None] + np.arange(self.chunk)[None, :] + 1,
+                          cfg.window)
+        eng._m_ring_live.inc(int(seen.sum()) * layers)
+        eng._m_ring_scored.inc(
+            len(live_pos) * self.chunk * cfg.ring_slots * layers)
 
     def _observe_chunk(self, t0: float, t1: float, live: int) -> None:
         """One decode launch's wall time (its dispatch, wait and fetch

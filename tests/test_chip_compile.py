@@ -304,28 +304,48 @@ def test_pooled_decode_chunk_writes_rows_not_slabs(one_chip, monkeypatch, t):
 
 
 # ---------------------------------------------------------------------------
-# a model whose layers differ in kind, at its published widths: 192-wide q/k
-# and 128-wide v heads, 2048-wide experts, a 16384-wide dense layer, a
-# ragged 13568- / 14848-column wqkv (the benchmark's third configuration)
+# the models whose layers differ in kind, at their published widths. The
+# benchmark's third configuration: 192-wide q/k and 128-wide v heads,
+# 2048-wide experts, a 16384-wide dense layer, a ragged 13568- / 14848-column
+# wqkv. Its fourth: a 16384-wide q projection for 128 heads, a 16384-wide
+# shared plane beside 4096-wide experts, rings of 8192 slots, a tied head
 # ---------------------------------------------------------------------------
 
+_PLAN_CONFIGS = {
+    "mimo-v2-flash-d13-e32-q40": (
+        {"wqkv_q40_matmul", "wo_q40_matmul", "w13_q40_matmul",
+         "w2_q40_matmul", "expert_upgate_q40_matmul",
+         "expert_down_q40_matmul", "wcls_q40_matmul"},
+        ("attention_full", "attention_window", "kv_ring_write",
+         "moe_router")),
+    "command-a-plus-d8-e16-q40": (
+        {"wqkv_q40_matmul", "wo_q40_matmul", "shared_upgate_q40_matmul",
+         "shared_down_q40_matmul", "expert_upgate_q40_matmul",
+         "expert_down_q40_matmul", "wcls_q40_matmul"},
+        ("attention_full", "attention_window", "kv_ring_write",
+         "moe_router", "moe_shared")),
+}
+
+
 @pytest.mark.parametrize("program", ["forward T=64", "forward_batched"])
+@pytest.mark.parametrize("config", sorted(_PLAN_CONFIGS, reverse=True))
 def test_layer_plan_programs_compile_at_published_widths(one_chip,
-                                                         monkeypatch, program):
-    """The prefill piece and the pooled decode step of
-    ``benchmarks/configs/mimo-v2-flash-d13-e32-q40.json``, as the compile
-    rehearsal (``benchmarks/rehearse_compile.py``) takes them from the
-    family's ``rehearsal``: whatever the v5e compiler refuses of them is
-    found here, not on the chip. Both fit one chip beside their weights."""
+                                                         monkeypatch, config,
+                                                         program):
+    """The prefill piece and the pooled decode step of a benchmark
+    configuration with a layer plan, as the compile rehearsal
+    (``benchmarks/rehearse_compile.py``) takes them from the family's
+    ``rehearsal``: whatever the v5e compiler refuses of them is found here,
+    not on the chip. Both fit one chip beside their weights."""
     import json
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     monkeypatch.syspath_prepend(os.path.join(root, "benchmarks"))
     import families
 
-    with open(os.path.join(
-            root, "benchmarks/configs/mimo-v2-flash-d13-e32-q40.json")) as f:
+    with open(os.path.join(root, f"benchmarks/configs/{config}.json")) as f:
         conf = json.load(f)
+    want_kernels, want_scopes = _PLAN_CONFIGS[config]
     interpret = qmatmul._interpret_default
     try:
         listed = families.load(conf).rehearsal(conf)
@@ -336,12 +356,9 @@ def test_layer_plan_programs_compile_at_published_widths(one_chip,
         qmatmul._interpret_default = interpret  # rehearsal() sets it
     assert _has_kernel(compiled)
     kernels = set(_kernel_names(compiled))
-    assert {"wqkv_q40_matmul", "wo_q40_matmul", "w13_q40_matmul",
-            "w2_q40_matmul", "expert_upgate_q40_matmul",
-            "expert_down_q40_matmul", "wcls_q40_matmul"} <= kernels, kernels
+    assert want_kernels <= kernels, kernels
     text = compiled.as_text()
-    for scope in ("attention_full", "attention_window", "kv_ring_write",
-                  "moe_router"):
+    for scope in want_scopes:
         assert scope in text, scope
     m = compiled.memory_analysis()
     assert (m.argument_size_in_bytes + m.temp_size_in_bytes

@@ -1,8 +1,8 @@
 """The seam between the benchmark's harness and a model: a configuration's
 ``"family"`` (``benchmarks/families/<name>/``, found by ``families.load``).
 Guarded here, in the suite the driver runs, so that a change to the program
-or to a family that breaks the seam is seen before a chip run: both families
-load and bring every function of ``REQUIRED``, every configuration of
+or to a family that breaks the seam is seen before a chip run: every family
+loads and brings every function of ``REQUIRED``, every configuration of
 ``BENCHMARK.json`` names a family that loads, and the weights of every one
 fill over a quarter of a v5e's memory. No JAX: the counts are plain Python.
 """
@@ -34,7 +34,7 @@ def _benchmark_configs() -> list:
     return out
 
 
-@pytest.mark.parametrize("name", ["llama", "mimo_v2"])
+@pytest.mark.parametrize("name", ["llama", "mimo_v2", "command_a"])
 def test_a_family_loads_and_brings_every_required_function(name):
     mod = families.load({"name": "probe", "family": name})
     assert mod.__name__.endswith("families." + name)
