@@ -29,17 +29,26 @@ slot ``p % R`` (``cfg.ring_slots``), so a window layer's cache does not grow
 with the context. A ring is the same in a solo cache, a staging cache and a
 pool's slab, whatever the slab's context, so the engine's tree-mapped copies
 (insert, migrate, grow) move a ring whole.
+
+A layer's attention reads and scores its cache only as far as the step's
+queries reach (``_attend``): ``forward`` and ``forward_batched`` work out
+one scalar a step, the ``reach`` (``pos + T``, or the longest LIVE row's
+``pos + 1``), and every layer takes the shortest prefix of its slots, off a
+ladder of static lengths, that covers it. A ring that has wrapped, and a
+cache no longer than the ladder's least rung, are read whole.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from dllama_tpu.models import llama
 from dllama_tpu.models.config import ModelConfig
 from dllama_tpu.models.moe import (moe_ffn, moe_ffn_counted, pick_counts,
                                    route_topk)
+from dllama_tpu.ops import attention
 from dllama_tpu.ops.attention import gqa_attention
 from dllama_tpu.ops.norms import NORMS
 from dllama_tpu.ops.rope import apply_rope, rope_table
@@ -113,23 +122,77 @@ def _rope(cfg: ModelConfig, x, cos, sin):
         axis=-1)
 
 
-def _attend(cfg: ModelConfig, att: str, lp: dict):
-    """The attention of one sequence for this kind, under its own scope."""
+def _attend(cfg: ModelConfig, att: str, lp: dict, cidx, reach,
+            rows: bool = False, least: int = None):
+    """The attention of this kind over layer ``cidx`` of its cache stacks,
+    under its own scope: ``attend(q, k_cache, v_cache, pos)`` for one
+    sequence (``q`` [T, heads, hd] from ``pos``) or, with ``rows``, for B
+    sequences of one token each at ``pos[b]``.
+
+    It reads and scores a PREFIX of the layer's slots that covers ``reach``,
+    the step's one scalar: how many leading slots can hold a position that
+    some query of the step sees (``min(reach, S)`` is the extent; a ring
+    that has wrapped is read whole). Shapes are static, so the prefix is a
+    rung of ``attention.prefix_rungs`` (``least``, doubled, up to the S
+    slots) and a ``lax.switch`` takes the first that covers the reach; each
+    branch slices ``(cidx, all rows, :rung)`` out of the stacked cache and
+    runs the one-pass ``gqa_attention`` on it. A cache of no more than
+    ``least`` slots has one rung: no switch, the program it always was
+    (``reach`` may then be None: ``_laddered``)."""
     window = cfg.window if att == "window" else 0
     sink = lp.get("sink") if cfg.window_sink else None
 
-    def attend(q, k_slab, v_slab, pos):
-        with jax.named_scope(f"attention_{att}"):
-            return gqa_attention(q, k_slab, v_slab, pos, window=window,
-                                 sink=sink)
+    def attend(q, k_cache, v_cache, pos):
+        S = k_cache.shape[-3]
+
+        def one(q, k_slab, v_slab, pos):
+            with jax.named_scope(f"attention_{att}"):
+                return gqa_attention(q, k_slab, v_slab, pos, window=window,
+                                     sink=sink, ring=S)
+
+        def over(rung):
+            def branch(q, k_cache, v_cache, pos):
+                slabs = llama._layer_slabs(k_cache, v_cache, cidx, rung)
+                if not rows:
+                    return one(q, *slabs, pos)
+                return jax.vmap(
+                    lambda qb, ks, vs, p: one(qb[None], ks, vs, p)[0])(
+                    q, *slabs, pos)
+            return branch
+
+        rungs = attention.prefix_rungs(
+            S, attention.LEAST_RUNG if least is None else least)
+        if len(rungs) == 1:
+            return over(None)(q, k_cache, v_cache, pos)
+        # a branch takes the stacked caches as they are carried and slices
+        # inside: pinned, so that no branch turns a whole cache around
+        k_cache, v_cache = llama._plain_layout(k_cache, v_cache)
+        return jax.lax.switch(attention.covering_rung(reach, rungs),
+                              [over(r) for r in rungs],
+                              q, k_cache, v_cache, pos)
 
     return attend
 
 
-def _solo_core(cfg: ModelConfig, att: str, lp: dict, rope: dict, pos, cidx):
+def _laddered(cache: dict) -> bool:
+    """Whether some layer's cache is longer than the ladder's least rung. A
+    step works out its reach only then: a program whose every cache has one
+    rung traces to exactly what it did before there was a ladder."""
+    return any(a.shape[-3] > attention.LEAST_RUNG for a in cache.values())
+
+
+def ring_slots_scored(cfg: ModelConfig, reach):
+    """The slots of a ring that a step of reach ``reach`` scores, a row a
+    window layer: the host's mirror (numpy) of the rung ``_attend`` takes."""
+    rungs = attention.prefix_rungs(cfg.ring_slots, attention.LEAST_RUNG)
+    return np.asarray(rungs)[attention.covering_rung(np.asarray(reach), rungs)]
+
+
+def _solo_core(cfg: ModelConfig, att: str, lp: dict, rope: dict, pos, cidx,
+               reach):
     """The core (``llama._attention``) of one sequence's T tokens at
     ``pos..pos+T``: layer ``cidx`` of this kind's cache stacks is written
-    and read."""
+    and read as far as ``reach`` = ``pos + T``."""
     ck, sk = ROPE_KEYS[att]
 
     def core(q, k, v, k_cache, v_cache, layer):
@@ -154,16 +217,17 @@ def _solo_core(cfg: ModelConfig, att: str, lp: dict, rope: dict, pos, cidx):
         else:
             k_cache, v_cache = llama._write_kv_seq(k_cache, v_cache, k, v,
                                                    cidx, pos)
-        out = _attend(cfg, att, lp)(
-            q, *llama._layer_slabs(k_cache, v_cache, cidx), pos)
+        out = _attend(cfg, att, lp, cidx, reach)(q, k_cache, v_cache, pos)
         return out, k_cache, v_cache
 
     return core
 
 
-def _rows_core(cfg: ModelConfig, att: str, lp: dict, rope: dict, pos, cidx):
+def _rows_core(cfg: ModelConfig, att: str, lp: dict, rope: dict, pos, cidx,
+               reach):
     """The core of B independent sequences, one token each at ``pos[b]``;
-    the caches carry the row axis after the layer axis."""
+    the caches carry the row axis after the layer axis. ``reach``: the
+    longest counted row's ``pos + 1`` (``forward_batched``)."""
     ck, sk = ROPE_KEYS[att]
 
     def core(q, k, v, k_cache, v_cache, layer):
@@ -183,9 +247,8 @@ def _rows_core(cfg: ModelConfig, att: str, lp: dict, rope: dict, pos, cidx):
         else:
             k_cache, v_cache = llama._write_kv_rows(
                 k_cache, v_cache, k[:, None], v[:, None], cidx, pos)
-        attend = _attend(cfg, att, lp)
-        out = jax.vmap(lambda qb, ks, vs, p: attend(qb[None], ks, vs, p)[0])(
-            q, *llama._layer_slabs(k_cache, v_cache, cidx), pos)
+        out = _attend(cfg, att, lp, cidx, reach, rows=True)(
+            q, k_cache, v_cache, pos)
         return out, k_cache, v_cache
 
     return core
@@ -208,7 +271,7 @@ def _ffn(cfg: ModelConfig, ffn: str, lp: dict, x, norm_w, layer, live):
 
 
 def _run_step(cfg: ModelConfig, stack: dict, rope: dict, pos, core_of, live,
-              kind: tuple, p0: int, c0: int):
+              reach, kind: tuple, p0: int, c0: int):
     """The scan body of one run of layers of ``kind``: layer ``p0 + i`` of
     the kind's parameter stack, ``c0 + i`` of its attention kind's caches;
     ``llama._attention`` around this kind's core, then ``_ffn``: on the
@@ -223,7 +286,7 @@ def _run_step(cfg: ModelConfig, stack: dict, rope: dict, pos, core_of, live,
         x, cache, picks = carry
         idx = jnp.int32(p0) + i
         lp = llama._layer_params(stack, idx)
-        core = core_of(cfg, att, lp, rope, pos, jnp.int32(c0) + i)
+        core = core_of(cfg, att, lp, rope, pos, jnp.int32(c0) + i, reach)
         att_out, k_cache, v_cache = llama._attention(
             cfg, lp, x, core, cache[kk], cache[vk], idx, widths=widths)
         cache = dict(cache, **{kk: k_cache, vk: v_cache})
@@ -242,12 +305,14 @@ def _run_step(cfg: ModelConfig, stack: dict, rope: dict, pos, core_of, live,
 
 
 def _run_layers(cfg: ModelConfig, params: dict, rope: dict, x, cache: dict,
-                pos, core_of, live=None):
-    """Every run of like layers in turn. -> (x, cache, picks [4] or None)"""
+                pos, core_of, reach, live=None):
+    """Every run of like layers in turn; ``reach`` is the step's one scalar
+    for every layer's attention (``_attend``).
+    -> (x, cache, picks [4] or None)"""
     carry = (x, cache, None if live is None else jnp.zeros((4,), jnp.int32))
     for kind, p0, c0, count in cfg.plan_runs:
         step = _run_step(cfg, params["layers"][kind_name(kind)], rope, pos,
-                         core_of, live, kind, p0, c0)
+                         core_of, live, reach, kind, p0, c0)
         if count == 1:
             carry, _ = step(carry, jnp.int32(0))
         else:
@@ -261,7 +326,9 @@ def forward(cfg: ModelConfig, params: dict, rope: dict, tokens, cache: dict,
     """T tokens of one sequence from ``pos`` -> (logits [T, vocab] f32, or
     [1, vocab] at row ``last_pos``; the new cache tree)."""
     x = llama.embed(cfg, params, tokens)
-    x, cache, _ = _run_layers(cfg, params, rope, x, cache, pos, _solo_core)
+    reach = pos + x.shape[0] if _laddered(cache) else None
+    x, cache, _ = _run_layers(cfg, params, rope, x, cache, pos, _solo_core,
+                              reach)
     return llama._head(cfg, params, x, last_pos=last_pos), cache
 
 
@@ -275,7 +342,12 @@ def forward_batched(cfg: ModelConfig, params: dict, rope: dict, tokens,
     READ (``moe.moe_ffn_counted``). Rows that are not live activate no
     expert where the expert layer runs only the picked experts."""
     x = llama.embed(cfg, params, tokens)
+    reach = None
+    if _laddered(cache):
+        # a row that is not live may hold a stale, longer position: not counted
+        reach = (pos + 1 if live is None
+                 else jnp.where(live, pos + 1, 0)).max()
     x, cache, picks = _run_layers(cfg, params, rope, x, cache, pos,
-                                  _rows_core, live)
+                                  _rows_core, reach, live)
     logits = llama._head(cfg, params, x)
     return (logits, cache) if live is None else (logits, cache, picks)

@@ -22,6 +22,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from dllama_tpu.formats.weights import WeightFileReader
 from dllama_tpu.models.config import ModelConfig
@@ -990,14 +991,40 @@ def _write_rows_at(k_cache, v_cache, k, v, layer, rows, cols):
                 v_cache.at[idx].set(v.astype(v_cache.dtype), mode="drop"))
 
 
-def _layer_slabs(k_cache, v_cache, layer):
+def _plain_layout(*arrays) -> tuple:
+    """Each K/V array held to the layout it is carried in, where that is
+    known to be plain row-major: heads a whole number of 128 lanes wide. The
+    v5e compiler asks a slice's operand for the layout the slice's consumer
+    likes; where the operand is a conditional's parameter it then turns the
+    WHOLE stacked cache around before every slice (0.8 GB a window layer a
+    step at Command A+'s sizes, PERF.md PR 32). Pinning the stacked caches
+    and the slice leaves the one copy of the slice that a plain read makes.
+    A head of another width (MiMo's 192-wide keys) is carried with the slots
+    minor, which is what the contraction reads: it needs no pin, and one
+    would cost a copy in and out of every program."""
+    return tuple(
+        with_layout_constraint(a, Layout(major_to_minor=tuple(range(a.ndim))))
+        if a.shape[-1] % 128 == 0 else a for a in arrays)
+
+
+def _layer_slabs(k_cache, v_cache, layer, slots: int = None):
     """The layer's ``[(B,) S, kv, hd]`` K and V out of the stacked caches, to
-    be read only: on the v5e this is the one pass over the slab's bytes that
-    full-context attention needs (the slice is staged for the score and value
-    contractions, which then read no HBM again)."""
+    be read only: on the v5e this is the one pass over those bytes that
+    attention needs (the slice is staged for the score and value
+    contractions, which then read no HBM again). With ``slots``: only the
+    first ``slots`` of the S, for a caller whose queries see no further
+    (``layer_plan._attend``, inside a conditional: see ``_plain_layout``)."""
     with jax.named_scope("kv_slab_read"):
-        return (jax.lax.dynamic_index_in_dim(k_cache, layer, 0, keepdims=False),
-                jax.lax.dynamic_index_in_dim(v_cache, layer, 0, keepdims=False))
+        if slots is None:
+            return (jax.lax.dynamic_index_in_dim(k_cache, layer, 0,
+                                                 keepdims=False),
+                    jax.lax.dynamic_index_in_dim(v_cache, layer, 0,
+                                                 keepdims=False))
+        zero = jnp.int32(0)
+        return _plain_layout(*(jax.lax.dynamic_slice(
+            cache, (layer, *(zero,) * (cache.ndim - 1)),
+            (1, *cache.shape[1:-3], slots, *cache.shape[-2:]))[0]
+            for cache in (k_cache, v_cache)))
 
 
 def _solo_core(cfg: ModelConfig, rope: dict, pos, flash: bool = False):

@@ -16,11 +16,41 @@ a RING cache (``window``: slot ``s`` holds the latest position ``p <= pos +
 T - 1`` with ``p % S == s``, and query ``i`` sees ``i - window < p <= i``),
 and a sink (one learned score a head that joins the softmax's denominator
 and carries no value).
+
+A caller that knows how far its queries reach may hand in a PREFIX of the
+cache (``prefix_rungs``, ``covering_rung``: a ladder of static prefix lengths
+and the first that covers a reach) and, for a ring, the ring's whole slot
+count beside it (``ring``): a slot beyond a prefix that covers every position
+the queries see is one the mask gives weight 0, so the result is the same
+and the slots left out are neither read nor scored.
 """
 
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
+
+#: the shortest prefix of a cache that attention is cut to: a cache of no
+#: more slots has a ladder of one rung and is read whole, as ever
+LEAST_RUNG = 1024
+
+
+def prefix_rungs(slots: int, least: int) -> tuple:
+    """The prefix lengths a cache of ``slots`` is read at: ``least`` doubled
+    while it stays under ``slots``, then ``slots`` itself."""
+    rungs = []
+    while least < slots:
+        rungs.append(least)
+        least *= 2
+    return (*rungs, slots)
+
+
+def covering_rung(reach, rungs: tuple):
+    """Index of the first rung with ``reach`` slots or more (the last where
+    none has): ``reach`` is how many leading slots can hold a position some
+    query sees. numpy or traced, any shape of ``reach``."""
+    return (reach[..., None] > np.asarray(rungs[:-1], np.int32)).sum(
+        axis=-1, dtype=np.int32)
 
 
 def gqa_attention(
@@ -30,6 +60,7 @@ def gqa_attention(
     pos: jnp.ndarray,  # scalar int32: position of q[0] in the sequence
     window: int = 0,  # > 0: the caches are rings of S slots, see above
     sink: jnp.ndarray | None = None,  # [n_heads] f32
+    ring: int = 0,  # > 0: the caches are the first S of a ring of ``ring``
 ) -> jnp.ndarray:
     """Masked GQA attention. Returns [T, n_heads, v_head_size].
 
@@ -51,7 +82,7 @@ def gqa_attention(
     if window:
         # the position each ring slot holds: the latest one written into it
         last = pos + (T - 1)
-        key_idx = last - jnp.mod(last - key_idx, S)
+        key_idx = last - jnp.mod(last - key_idx, ring or S)
         mask = ((key_idx <= query_pos) & (key_idx > query_pos - window)
                 & (key_idx >= 0))
     else:

@@ -298,7 +298,9 @@ class Engine:
             self._m_ring_scored = metrics.counter(
                 "dllama_kv_ring_scored_slots_total",
                 "Ring slots the window attention scored for those rows, "
-                "steps and layers (the whole ring, ModelConfig.ring_slots)")
+                "steps and layers: a step reads the rings as far as its "
+                "longest live row reaches, up to a rung of 1024, 2048, ... "
+                "ModelConfig.ring_slots (the whole ring once one has wrapped)")
         else:
             self._m_ring_live = self._m_ring_scored = None
             self._m_moe_picks = self._m_moe_active = None
@@ -3365,19 +3367,24 @@ class BatchSession:
             self.chunk * eng.cfg.plan_count(ffn="moe"))
 
     def _account_ring(self, live_pos) -> None:
-        """One chunk's window attention, from numbers the session holds: the
-        ring slots that held a position a live row's query saw (``live_pos``:
-        the rows' positions at the chunk's first step) and the slots the
-        program scored for them, the whole ring a row a step a layer."""
+        """One chunk's window attention, from numbers the session holds
+        (``live_pos``: the live rows' positions at the chunk's first step):
+        the ring slots that held a position a live row's query saw, and the
+        slots the program scored for them: a step reads every ring as far
+        as its longest live row reaches, up to a rung
+        (``layer_plan.ring_slots_scored``, the program's own rule)."""
         eng, cfg = self.eng, self.eng.cfg
         layers = cfg.plan_count("window")
         if eng._m_ring_live is None or not layers:
             return
-        seen = np.minimum(live_pos[:, None] + np.arange(self.chunk)[None, :] + 1,
-                          cfg.window)
-        eng._m_ring_live.inc(int(seen.sum()) * layers)
+        at = live_pos[:, None] + np.arange(self.chunk)[None, :]
+        eng._m_ring_live.inc(
+            int(np.minimum(at + 1, cfg.window).sum()) * layers)
+        # the program pins a row's position at the context's last slot
+        reach = np.minimum(at.max(axis=0), cfg.seq_len - 1) + 1
         eng._m_ring_scored.inc(
-            len(live_pos) * self.chunk * cfg.ring_slots * layers)
+            int(layer_plan.ring_slots_scored(cfg, reach).sum())
+            * len(live_pos) * layers)
 
     def _observe_chunk(self, t0: float, t1: float, live: int) -> None:
         """One decode launch's wall time (its dispatch, wait and fetch

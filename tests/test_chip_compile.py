@@ -27,7 +27,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 
 from dllama_tpu.models import llama
 from dllama_tpu.models.config import ModelConfig
-from dllama_tpu.ops import flash_decode, fused_rope_cache, qmatmul
+from dllama_tpu.ops import attention, flash_decode, fused_rope_cache, qmatmul
 from dllama_tpu.parallel import quant_tp
 from dllama_tpu.parallel.mesh import TP
 from dllama_tpu.parallel.sharding import cache_spec
@@ -336,7 +336,12 @@ def test_layer_plan_programs_compile_at_published_widths(one_chip,
     configuration with a layer plan, as the compile rehearsal
     (``benchmarks/rehearse_compile.py``) takes them from the family's
     ``rehearsal``: whatever the v5e compiler refuses of them is found here,
-    not on the chip. Both fit one chip beside their weights."""
+    not on the chip. Both fit one chip beside their weights. A cache longer
+    than ``attention.LEAST_RUNG`` is read through the ladder of prefixes
+    (``layer_plan._attend``): its least rung is in the program, and no
+    branch turns a whole stacked cache around to slice it (``llama.
+    _plain_layout``: at most the copy in and the copy out that the parent's
+    program has of a cache leaf, never one a layer)."""
     import json
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -363,6 +368,27 @@ def test_layer_plan_programs_compile_at_published_widths(one_chip,
     m = compiled.memory_analysis()
     assert (m.argument_size_in_bytes + m.temp_size_in_bytes
             + m.output_size_in_bytes - m.alias_size_in_bytes) < HBM_BYTES
+    def dims(instruction) -> tuple:
+        return tuple(int(d) for d in
+                     instruction[2][1:].split("]")[0].split(",") if d)
+
+    leaves = jax.tree.leaves(args[3])  # the cache tree
+    instructions = _instructions(text)
+    for leaf in leaves:
+        whole = [i for i in instructions
+                 if i[3] == "copy" and dims(i) == leaf.shape]
+        assert len(whole) <= 2, (leaf.shape, len(whole))
+    laddered = {leaf.shape[-3] for leaf in leaves
+                if leaf.shape[-3] > attention.LEAST_RUNG}
+    reads = {dims(i)[-3] for i in instructions
+             if "kv_slab_read" in i[4] and i[3] in ("dynamic-slice", "slice")}
+    if config == "command-a-plus-d8-e16-q40":
+        assert laddered == {8192}  # the rings; the staging cache's slabs too
+    if laddered:
+        assert "conditional(" in text
+        assert {attention.LEAST_RUNG, *laddered} <= reads, reads
+    else:  # MiMo's pooled step: a ring of 256, slabs of 1024: as it was
+        assert "conditional(" not in text
 
 
 # ---------------------------------------------------------------------------

@@ -240,6 +240,104 @@ def test_batch_session_serves_the_references_greedy_tokens(tiny):
     sess.close()
 
 
+@pytest.mark.parametrize("which,wrapped", [((0,), "no"), ((0, 1), "some"),
+                                           ((2, 3), "every"), ((0, 3), "some")],
+                         ids=["one row before the wrap",
+                              "two rows before the wrap",
+                              "both rows wrapped", "one row of two wrapped"])
+def test_ring_counter_counts_the_rungs_the_program_took(tiny, monkeypatch,
+                                                        which, wrapped):
+    """With the least rung below the ring's 16 slots (4: rungs 4, 8, 16; the
+    pool's slab of 32 has 4, 8, 16, 32) the served tokens are still the
+    reference's argmax, and ``dllama_kv_ring_scored_slots_total`` is what the
+    program scored, recomputed here from every launch's live positions: under
+    the whole ring a row a step a layer while no live row has reached the
+    ring's end, the whole ring once one has; the live slots are counted as
+    ever."""
+    from dllama_tpu.ops import attention
+    from dllama_tpu.runtime.generate import BatchSession
+
+    monkeypatch.setattr(attention, "LEAST_RUNG", 4)
+    launches = []
+    account = BatchSession._account_ring
+    monkeypatch.setattr(
+        BatchSession, "_account_ring",
+        lambda self, live_pos: (launches.append(np.array(live_pos)),
+                                account(self, live_pos))[1])
+    cfg, conf = tiny["cfg"], tiny["conf"]
+    reg = observability.MetricsRegistry()
+    eng = Engine(cfg, tiny["params"], SamplerConfig(temperature=0.0),
+                 cache_dtype=jnp.float32, metrics=reg)
+    chunk, budget = 2, 6
+    sess = eng.batch_session(3, chunk=chunk, bucket_kv=True, min_bucket=32,
+                             prefill_chunk=8)
+    prompts = [tiny["seqs"][i][:LENGTHS[i]] for i in which]
+    handles = [sess.admit_begin(p, budget) for p in prompts]
+    served = {h: [] for h in handles}
+    for _ in range(40):
+        sess.prefill_step()
+        for h, toks in sess.step_chunk().items():
+            served[h].extend(toks)
+        if all(sess.is_done(h) for h in handles):
+            break
+    assert all(len(served[h]) == budget for h in handles)
+    seqs = [p + served[h] for p, h in zip(prompts, handles)]
+    ref = reference.logits_at(
+        tiny["planes"], conf, seqs,
+        [[len(p) - 1 + j for j in range(budget)] for p in prompts])
+    for h, lg in zip(handles, ref):
+        assert lg.argmax(axis=1).tolist() == served[h]
+    layers, ring = cfg.plan_count("window"), cfg.ring_slots
+    want = seen = row_steps = 0
+    for live_pos in launches:
+        for s in range(chunk):
+            reach = min(int(live_pos.max()) + s + 1, ring)
+            want += len(live_pos) * min(r for r in (4, 8, 16) if r >= reach)
+            seen += int(np.minimum(live_pos + s + 1, cfg.window).sum())
+        row_steps += len(live_pos) * chunk
+    text = reg.render()
+    scored = _sample(text, "dllama_kv_ring_scored_slots_total")
+    assert scored == want * layers and launches
+    assert _sample(text, "dllama_kv_ring_live_slots_total") == seen * layers
+    whole = row_steps * ring * layers
+    at_the_end = [bool(p.max() + chunk >= ring) for p in launches]
+    assert {"no": not any(at_the_end), "every": all(at_the_end),
+            "some": any(at_the_end) and not all(at_the_end)}[wrapped]
+    assert (scored == whole) if wrapped == "every" else (scored < whole)
+    sess.close()
+
+
+def test_a_stale_longer_row_does_not_widen_the_reach(tiny, monkeypatch):
+    """A pool row that is not live may hold a position left by an earlier
+    request: ``forward_batched`` counts live rows only. Values poisoned from
+    slot 4 on leave the live rows' logits (positions 2 and 3: rung 4) as
+    they were beside a row that stands at 13 and is not live; counted as
+    live, that row takes the step to the whole ring and the poison shows."""
+    from dllama_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "LEAST_RUNG", 4)
+    cfg = tiny["cfg"]
+    eng = Engine(cfg, tiny["params"], SamplerConfig(temperature=0.0),
+                 cache_dtype=jnp.float32, metrics=None)
+    rng = np.random.default_rng(5)
+    cache = {k: jnp.asarray(rng.standard_normal(a.shape).astype(np.float32))
+             for k, a in llama.init_batch_cache(cfg, 3, jnp.float32,
+                                                seq_len=32).items()}
+    poisoned = dict(cache, **{k: cache[k].at[:, :, 4:].set(jnp.nan)
+                              for k in ("v", "wv")})
+    step = jax.jit(lambda t, c, ps, lv: llama.forward_batched(
+        cfg, eng.params, eng.rope, t, c, ps, live=lv)[0])
+    toks = jnp.asarray([300, 301, 302], jnp.int32)
+    pos = jnp.asarray([2, 13, 3], jnp.int32)
+    live = jnp.asarray([True, False, True])
+    clean = np.asarray(step(toks, cache, pos, live))
+    got = np.asarray(step(toks, poisoned, pos, live))
+    assert np.isfinite(got[[0, 2]]).all()
+    np.testing.assert_allclose(got[[0, 2]], clean[[0, 2]], rtol=1e-4, atol=1e-5)
+    assert np.isnan(np.asarray(step(toks, poisoned, pos,
+                                    jnp.ones((3,), jnp.bool_)))[[0, 2]]).any()
+
+
 def test_bfloat16_activations_fail_the_tolerance(tiny):
     conf = tiny["conf"]
     cfg = families.load(conf).model_config(

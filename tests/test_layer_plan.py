@@ -18,6 +18,7 @@ activations read (test_bfloat16_activations_fail_the_tolerance).
 
 import json
 import os
+import types
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +29,8 @@ from benchmarks import families
 from benchmarks.families.mimo_v2 import reference, shapes, weights
 from dllama_tpu import observability
 from dllama_tpu.models import layer_plan, llama, moe
+from dllama_tpu.ops import attention
+from dllama_tpu.ops.attention import gqa_attention
 from dllama_tpu.runtime.generate import Engine
 from dllama_tpu.runtime.sampler import SamplerConfig
 
@@ -350,3 +353,179 @@ def test_a_plan_of_one_kind_is_the_uniform_model():
 
     same(solo(plan), solo(uni))
     same(pooled(plan), pooled(uni))
+
+
+# ---------------------------------------------------------------------------
+# attention as far as the step's queries reach (``layer_plan._attend``): a
+# ladder of prefixes of the cache, here with a least rung of 4 handed in, so
+# that a cache of 16 slots has the rungs 4, 8, 16
+# ---------------------------------------------------------------------------
+
+_SLOTS, _LEAST, _WINDOW, _LAYERS, _CIDX = 16, 4, 8, 3, 1
+_HEADS, _KV, _HD, _VHD = 4, 2, 8, 4  # values narrower than keys
+
+
+def _ladder_case(att, rows, pos, T, sink, seed=0):
+    """Random q and stacked caches (every slot filled: what a slot holds
+    beyond the reach is stale, and the mask's to leave out) -> the operands
+    of ``_attend`` and of the one-pass reference."""
+    rng = np.random.default_rng(seed)
+    lead = (len(pos),) if rows else ()
+    q = rng.standard_normal((*(lead or (T,)), _HEADS, _HD)).astype(np.float32)
+    k = rng.standard_normal((_LAYERS, *lead, _SLOTS, _KV, _HD)).astype(np.float32)
+    v = rng.standard_normal((_LAYERS, *lead, _SLOTS, _KV, _VHD)).astype(np.float32)
+    cfg = types.SimpleNamespace(window=_WINDOW, window_sink=sink)
+    lp = {"sink": rng.standard_normal((_HEADS,)).astype(np.float32)}
+    pos = np.asarray(pos if rows else pos[0], np.int32)
+    return cfg, lp, jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), jnp.asarray(pos)
+
+
+def _one_pass(cfg, lp, att, rows, q, k, v, pos):
+    """Attention as it was before the ladder: the layer's whole slab."""
+    kw = dict(window=cfg.window if att == "window" else 0,
+              sink=lp["sink"] if cfg.window_sink else None)
+    if not rows:
+        return gqa_attention(q, k[_CIDX], v[_CIDX], pos, **kw)
+    return jax.vmap(lambda qb, ks, vs, p: gqa_attention(
+        qb[None], ks, vs, p, **kw)[0])(q, k[_CIDX], v[_CIDX], pos)
+
+
+#: (name, kind, rows?, positions (rows: one a row; a piece: its first), T)
+_REACHES = [
+    # B rows of one token: the reach is the longest row's pos + 1
+    ("rows below the least rung", True, (0, 2, 1), 1),
+    ("rows on the least rung", True, (3, 0, 2), 1),
+    ("rows one past the least rung", True, (1, 4, 2), 1),
+    ("rows on the second rung", True, (7, 5, 0), 1),
+    ("rows one past the second rung", True, (2, 8, 6), 1),
+    ("rows at the last slot: the wrap", True, (15, 3, 9), 1),
+    ("rows one past the wrap", True, (4, 16, 11), 1),
+    ("rows wrapped twice", True, (40, 3, 33), 1),
+    # a piece of T tokens of one sequence: the reach is pos + T
+    ("a piece below the least rung", False, (0,), 3),
+    ("a piece on the least rung", False, (1,), 3),
+    ("a piece one past the least rung", False, (1,), 4),
+    ("a piece on the second rung", False, (3,), 5),
+    ("a piece that ends at the wrap", False, (10,), 6),
+    ("a piece across the wrap", False, (13,), 6),
+    ("a piece wrapped twice", False, (37,), 5),
+    ("one token of one sequence", False, (5,), 1),
+]
+_LADDER = [(f"{att}, {name}" + (", sink" if sink else ""), att, rows, pos, T, sink)
+           for att in ("full", "window")
+           for name, rows, pos, T in _REACHES
+           for sink in ((False, True) if att == "window" else (False,))
+           # a full layer's slab holds position p in slot p: no row past it
+           if att == "window" or max(pos) + T <= _SLOTS]
+
+
+@pytest.mark.parametrize("name,att,rows,pos,T,sink", _LADDER,
+                         ids=[c[0] for c in _LADDER])
+def test_attention_as_far_as_the_reach_equals_the_one_pass(name, att, rows,
+                                                           pos, T, sink):
+    """A prefix of the cache that covers the step's reach gives what the
+    whole slab gives, to the order of float32 sums: full and window kinds,
+    the reach below, on and one past a rung, a ring before its wrap, at it
+    and wrapped twice, with and without a sink, values narrower than keys,
+    rows of one token and a piece of several."""
+    cfg, lp, q, k, v, p = _ladder_case(att, rows, pos, T, sink)
+    reach = jnp.int32(max(pos) + T)
+    got = jax.jit(lambda q, k, v, p, r: layer_plan._attend(
+        cfg, att, lp, jnp.int32(_CIDX), r, rows=rows, least=_LEAST)(
+        q, k, v, p))(q, k, v, p, reach)
+    want = _one_pass(cfg, lp, att, rows, q, k, v, p)
+    assert got.shape == want.shape and got.shape[-1] == _VHD
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5,
+                               atol=2e-6)
+
+
+@pytest.mark.parametrize("reach", range(1, 20))
+def test_the_program_reads_the_rung_the_host_counts(reach, monkeypatch):
+    """``ring_slots_scored`` (what ``BatchSession._account_ring`` exports as
+    ``dllama_kv_ring_scored_slots_total``) is the prefix the program reads:
+    a NaN planted in the values at the rung's last slot reaches the output
+    (a masked slot's weight is 0, and 0 x NaN is NaN: the slot was read),
+    one planted in every slot from the rung on does not."""
+    monkeypatch.setattr(attention, "LEAST_RUNG", _LEAST)
+    rung = int(layer_plan.ring_slots_scored(
+        types.SimpleNamespace(ring_slots=_SLOTS), np.int32(reach)))
+    assert rung == min(r for r in (4, 8, 16) if r >= min(reach, _SLOTS))
+    pos = (reach - 1, 0)
+    cfg, lp, q, k, v, p = _ladder_case("window", True, pos, 1, False)
+    run = jax.jit(lambda q, k, v, p, r: layer_plan._attend(
+        cfg, "window", lp, jnp.int32(_CIDX), r, rows=True)(q, k, v, p))
+    inside = v.at[_CIDX, :, rung - 1].set(jnp.nan)
+    assert np.isnan(np.asarray(run(q, k, inside, p, jnp.int32(reach)))).all()
+    if rung < _SLOTS:
+        beyond = v.at[_CIDX, :, rung:].set(jnp.nan)
+        got = run(q, k, beyond, p, jnp.int32(reach))
+        np.testing.assert_allclose(
+            np.asarray(got),
+            np.asarray(_one_pass(cfg, lp, "window", True, q, k, v, p)),
+            rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("att,rows", [("window", True), ("window", False),
+                                      ("full", True), ("full", False)])
+def test_a_cache_within_the_least_rung_lowers_as_it_always_did(att, rows):
+    """The controls' guarantee: a cache of no more slots than the least rung
+    (MiMo's ring of 256, every slab of 1024) has a ladder of one rung and
+    lowers to the text of the call as it was before the ladder, no
+    conditional and no layout constraint in it; a longer one lowers to a
+    ``case``."""
+    assert attention.LEAST_RUNG >= 1024 >= _SLOTS
+    cfg, lp, q, k, v, p = _ladder_case(att, rows, (3, 9, 5), 2, True)
+    cidx, reach = jnp.int32(_CIDX), jnp.int32(11)
+
+    def new(q, k, v, p, r, least=None):
+        return layer_plan._attend(cfg, att, lp, cidx, r, rows=rows,
+                                  least=least)(q, k, v, p)
+
+    def old(q, k, v, p, r):
+        window = cfg.window if att == "window" else 0
+
+        def attend(q, k_slab, v_slab, pos):
+            with jax.named_scope(f"attention_{att}"):
+                return gqa_attention(q, k_slab, v_slab, pos, window=window,
+                                     sink=lp["sink"])
+
+        with jax.named_scope("kv_slab_read"):
+            slabs = (jax.lax.dynamic_index_in_dim(k, cidx, 0, keepdims=False),
+                     jax.lax.dynamic_index_in_dim(v, cidx, 0, keepdims=False))
+        if not rows:
+            return attend(q, *slabs, p)
+        return jax.vmap(lambda qb, ks, vs, pb: attend(qb[None], ks, vs, pb)[0])(
+            q, *slabs, p)
+
+    def text(fn, **kw):
+        low = jax.jit(lambda *a: fn(*a, **kw)).lower(q, k, v, p, reach)
+        return low.as_text().replace("jit__lambda_", "jit_fn")
+
+    assert text(new) == text(old)
+    assert "case" not in text(new) and "LayoutConstraint" not in text(new)
+    assert "stablehlo.case" in text(new, least=_LEAST)
+
+
+def test_a_uniform_models_attention_lowers_as_it_always_did():
+    """``gqa_attention`` called as ``llama._rows_core`` calls it (no window,
+    no ring) and ``llama._layer_slabs`` without ``slots`` trace to what they
+    did: the new arguments change nothing where they are not given."""
+    cfg, lp, q, k, v, p = _ladder_case("full", True, (3, 9, 5), 1, False)
+    v = k  # a uniform model's values are as wide as its keys
+    layer = jnp.int32(_CIDX)
+
+    def new(q, k, v, p):
+        return jax.vmap(lambda qb, ks, vs, pb: gqa_attention(
+            qb[None], ks, vs, pb)[0])(q, *llama._layer_slabs(k, v, layer), p)
+
+    def old(q, k, v, p):
+        with jax.named_scope("kv_slab_read"):
+            slabs = (jax.lax.dynamic_index_in_dim(k, layer, 0, keepdims=False),
+                     jax.lax.dynamic_index_in_dim(v, layer, 0, keepdims=False))
+        return jax.vmap(lambda qb, ks, vs, pb: gqa_attention(
+            qb[None], ks, vs, pb, window=0, sink=None, ring=0)[0])(
+            q, *slabs, p)
+
+    texts = [jax.jit(f).lower(q, k, v, p).as_text().replace(
+        f"jit_{f.__name__}", "jit_fn") for f in (new, old)]
+    assert texts[0] == texts[1] and "case" not in texts[0]
