@@ -25,12 +25,16 @@ Three cooperating pieces, all stdlib-only (matching the repo's no-deps style):
   ``BatchSession``) is one ``with phase(...)`` whose single pair of clock
   reads feeds a ``jax.profiler`` host span (same clock as the device plane),
   ``dllama_tick_phase_seconds_total`` and, under ``DLLAMA_TRACE``, the
-  scheduler track. JAX is looked up lazily: this module stays stdlib-only.
+  scheduler track; a pair of thread CPU clock reads beside it feeds
+  ``dllama_tick_phase_cpu_seconds_total``. ``watch_gc`` times the garbage
+  collector's pauses by the tick phase they stalled. JAX is looked up
+  lazily: this module stays stdlib-only.
 """
 
 from __future__ import annotations
 
 import collections
+import gc
 import hashlib
 import itertools
 import json
@@ -70,6 +74,7 @@ __all__ = [
     "scheduler_trace_event",
     "phase",
     "tick",
+    "watch_gc",
     "SCHEDULER_TID",
     "LATENCY_BUCKETS_MS",
     "TOKEN_BUCKETS",
@@ -370,6 +375,19 @@ class MetricsRegistry:
     def __init__(self) -> None:
         self._lock = threading.RLock()
         self._metrics: Dict[str, _Metric] = {}
+        #: called under the lock before every render / snapshot: families
+        #: whose amounts a lock-free writer gathers elsewhere fold them in
+        self._on_read: List[Callable[[], None]] = []
+
+    def on_read(self, fold: Callable[[], None]) -> None:
+        with self._lock:
+            self._on_read.append(fold)
+
+    def _sorted_metrics(self) -> List[_Metric]:
+        with self._lock:
+            for fold in self._on_read:
+                fold()
+            return sorted(self._metrics.values(), key=lambda m: m.name)
 
     def _get_or_create(self, cls, name: str, help: str,
                        labelnames: Iterable[str], **kw) -> _Metric:
@@ -404,9 +422,7 @@ class MetricsRegistry:
     def render(self) -> str:
         """Prometheus text exposition format 0.0.4."""
         lines: List[str] = []
-        with self._lock:
-            metrics = sorted(self._metrics.values(), key=lambda m: m.name)
-        for m in metrics:
+        for m in self._sorted_metrics():
             lines.append(f"# HELP {m.name} {m.help}")
             lines.append(f"# TYPE {m.name} {m.kind}")
             m.render_into(lines)
@@ -415,9 +431,7 @@ class MetricsRegistry:
     def snapshot(self) -> Dict[str, dict]:
         """JSON-friendly dump for /stats: histograms carry p50/p95/p99."""
         out: Dict[str, dict] = {}
-        with self._lock:
-            metrics = sorted(self._metrics.values(), key=lambda m: m.name)
-        for m in metrics:
+        for m in self._sorted_metrics():
             out[m.name] = {"kind": m.kind, "help": m.help,
                            "values": m.snapshot_values()}
         return out
@@ -630,12 +644,37 @@ _M_PHASE_SECONDS = _DEFAULT.counter(
     "Seconds the scheduler thread spent in each phase of its ticks (side="
     "device: nothing but a blocked wait on the device; host: everything else)",
     ("phase", "layer", "side"))
+_M_PHASE_CPU = _DEFAULT.counter(
+    "dllama_tick_phase_cpu_seconds_total",
+    "CPU seconds (time.thread_time) of the scheduler thread in each phase of "
+    "its ticks; a phase's seconds minus these are time it was off the CPU: "
+    "waiting for the interpreter lock, another lock or a core",
+    ("phase", "layer", "side"))
 _M_TICKS = _DEFAULT.counter(
     "dllama_ticks_total",
     "Passes of the continuous scheduler loop that launched device work")
+_M_GC_PAUSE = _DEFAULT.counter(
+    "dllama_gc_pause_seconds_total",
+    "Seconds the garbage collector held the process, by generation and by the "
+    "tick phase the scheduler thread had open (none: no leaf of a tick)",
+    ("generation", "during"))
 
 _tick_seq = itertools.count(1)
 _tls = threading.local()  # .tick: the tick span open on this thread
+
+# The leaf of a tick open on the scheduler thread, or "none": two plain
+# stores a leaf (``phase``), read by the collector callback on any thread.
+_during = "none"
+# The collector callback's own state. It runs on whichever thread
+# allocates, maybe inside a hold of the registry's lock, so it takes no lock
+# and writes only these; collections never overlap. ``_gc_seconds`` are
+# running totals, folded into the counter (``_fold_gc``) at a tick's exit
+# and at render, where ``_gc_folded`` keeps what the counter already holds.
+_gc_t0 = 0.0
+_gc_span = None
+_gc_trace = None  # TraceAnnotation, looked up once by ``watch_gc``
+_gc_seconds: Dict[Tuple[str, str], float] = {}
+_gc_folded: Dict[Tuple[str, str], float] = {}
 
 
 def _annotation():
@@ -658,18 +697,25 @@ class phase:
     is recording (the span then lies in the xplane's ``/host:CPU`` plane, on
     the device plane's clock), and — inside a ``tick`` on the same thread —
     ``dllama_tick_phase_seconds_total{phase,layer,side}`` plus, with
-    ``DLLAMA_TRACE`` set, a complete-event on the scheduler track. A leaf
+    ``DLLAMA_TRACE`` set, a complete-event on the scheduler track. On such a
+    leaf a pair of thread CPU clock reads just outside that pair (``p.c0``,
+    ``p.c1``, ``time.thread_time``: a system call, whose own time is thus
+    not in the phase's wall seconds) feeds
+    ``dllama_tick_phase_cpu_seconds_total`` under the same labels. A leaf
     carries its tick's number (``tick=`` in the span's arguments); ``args``
-    say what caused it (the request's ``span_id`` on the prefill phases).
+    say what caused it (the request's ``span_id`` on the prefill phases),
+    and while it is open the collector callback counts a pause under its
+    name.
 
     ``side="device"`` is for a phase that is nothing but a blocked wait on
     the device, and is never merged with host work. Without ``layer``, or
     outside a tick, the span only annotates (``sse_write`` on a handler
     thread, ``scheduler_window``). With no profiler session and no
-    ``DLLAMA_TRACE`` a span costs its two clock reads and one dict add. An
+    ``DLLAMA_TRACE`` a leaf costs its four clock reads and two dict adds. An
     exception inside the span ends it like any exit and propagates."""
 
-    __slots__ = ("name", "layer", "side", "args", "t0", "t1", "_tick", "_ann")
+    __slots__ = ("name", "layer", "side", "args", "t0", "t1", "c0", "c1",
+                 "_tick", "_ann")
 
     def __init__(self, name: str, layer: Optional[str] = None,
                  side: str = "host", **args: object):
@@ -677,7 +723,7 @@ class phase:
         self.layer = layer
         self.side = side
         self.args = args
-        self.t0 = self.t1 = 0.0
+        self.t0 = self.t1 = self.c0 = self.c1 = 0.0
         self._tick: Optional["tick"] = None
         self._ann = None
 
@@ -690,20 +736,28 @@ class phase:
         return self.args if t is None else dict(self.args, tick=t.seq)
 
     def __enter__(self) -> "phase":
+        global _during
         self._tick = getattr(_tls, "tick", None)
         cls = _annotation()
         if cls is not None and cls.is_enabled():
             self._ann = cls(self.name, **self._span_args())
             self._ann.__enter__()
+        if self._tick is not None and self.layer is not None:
+            _during = self.name
+            self.c0 = time.thread_time()
         self.t0 = time.monotonic()
         return self
 
     def __exit__(self, et, ev, tb) -> bool:
+        global _during
         self.t1 = time.monotonic()
+        t = self._tick if self.layer is not None else None
+        if t is not None:
+            self.c1 = time.thread_time()
+            _during = "none"
         if self._ann is not None:
             self._ann.__exit__(et, ev, tb)
-        t = self._tick
-        if t is not None and self.layer is not None:
+        if t is not None:
             t._leaf(self)
         return False
 
@@ -713,18 +767,20 @@ class tick(phase):
 
     Allocates the pass's sequence number, is the current tick of its thread
     while open (leaf phases find it there and take its number), gathers
-    their seconds lock-free, and on exit adds them to the counter family
-    under one lock hold, counts the pass in ``dllama_ticks_total`` if any
+    their wall and CPU seconds lock-free, and on exit adds them to the two
+    counter families (and folds in the collector's pauses) under one lock
+    hold, counts the pass in ``dllama_ticks_total`` if any
     ``side="device"`` phase ran in it (it launched), and writes the pass's
     events to the ``DLLAMA_TRACE`` file in one batch."""
 
-    __slots__ = ("seq", "_seconds", "_events", "launched")
+    __slots__ = ("seq", "_seconds", "_cpu", "_events", "launched")
 
     def __init__(self):
         super().__init__("tick")
         self.seq = 0
         self.launched = False
         self._seconds: Dict[Tuple[str, ...], float] = {}
+        self._cpu: Dict[Tuple[str, ...], float] = {}
         self._events: Optional[List[dict]] = None
 
     def _span_args(self) -> dict:
@@ -733,6 +789,7 @@ class tick(phase):
     def _leaf(self, p: phase) -> None:
         key = (p.name, p.layer, p.side)
         self._seconds[key] = self._seconds.get(key, 0.0) + (p.t1 - p.t0)
+        self._cpu[key] = self._cpu.get(key, 0.0) + (p.c1 - p.c0)
         if p.side == "device":
             self.launched = True
         if self._events is not None:
@@ -751,8 +808,11 @@ class tick(phase):
     def __exit__(self, et, ev, tb) -> bool:
         _tls.tick = None
         super().__exit__(et, ev, tb)
-        if self._seconds:
-            _M_PHASE_SECONDS.add_keyed(self._seconds)
+        with _DEFAULT._lock:  # the registry's RLock, which every family shares
+            if self._seconds:
+                _M_PHASE_SECONDS.add_keyed(self._seconds)
+                _M_PHASE_CPU.add_keyed(self._cpu)
+            _fold_gc()
         if self.launched:
             _M_TICKS.inc()
         if self._events is not None:
@@ -760,6 +820,54 @@ class tick(phase):
                 [scheduler_trace_event("tick", self.t0, self.t1,
                                        self._span_args())] + self._events)
         return False
+
+
+def _on_gc(what: str, info: dict) -> None:
+    """``gc.callbacks`` hook: one collection, ``"start"`` to ``"stop"``, adds
+    its seconds under (generation, the tick leaf open at its stop). While a
+    profiler session records, the pause is a ``gc`` span on the collecting
+    thread's line of ``/host:CPU``, with no ``tick`` argument: it is no leaf,
+    and the leaves still tile their tick."""
+    global _gc_t0, _gc_span
+    if what == "start":
+        cls = _gc_trace
+        if cls is not None and cls.is_enabled():
+            _gc_span = cls("gc", generation=info["generation"])
+            _gc_span.__enter__()
+        _gc_t0 = time.monotonic()
+        return
+    seconds = time.monotonic() - _gc_t0
+    span, _gc_span = _gc_span, None
+    if span is not None:
+        span.__exit__(None, None, None)
+    key = (str(info["generation"]), _during)
+    _gc_seconds[key] = _gc_seconds.get(key, 0.0) + seconds
+
+
+def _fold_gc() -> None:
+    """Add what ``_on_gc`` counted since the last fold to
+    ``dllama_gc_pause_seconds_total``. Under the registry's lock (a tick's
+    exit, a render): folds never overlap, and a pause counted while one
+    runs is in the next."""
+    now = _gc_seconds.copy()  # one C call: no collection can land inside it
+    fresh = {k: s - _gc_folded.get(k, 0.0) for k, s in now.items()
+             if s > _gc_folded.get(k, 0.0)}
+    if fresh:
+        _gc_folded.update(now)
+        _M_GC_PAUSE.add_keyed(fresh)
+
+
+_DEFAULT.on_read(_fold_gc)
+
+
+def watch_gc() -> None:
+    """Time the garbage collector's pauses from now on (once a process:
+    the server calls it where it takes its registry)."""
+    global _gc_trace
+    with _DEFAULT._lock:
+        _gc_trace = _annotation()
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
 
 
 def sanitize_request_id(raw: Optional[str]) -> str:

@@ -373,10 +373,6 @@ class Batcher:
             "dllama_class_resident_rows",
             "Rows resident in the decode slot pool, by SLO class",
             ("slo_class",))
-        self._m_class_preempted = reg.gauge(
-            "dllama_class_preempted_rows",
-            "Preempted rows parked awaiting re-admission, by SLO class",
-            ("slo_class",))
         #: latest per-lane scheduler snapshot ({class: {waiting, resident,
         #: preempted}}), published each chunk tick for /ready (the router's
         #: class-aware scoring reads it there)
@@ -473,7 +469,6 @@ class Batcher:
         for name, row in stats.items():
             self._m_class_queue.set(row["waiting"], slo_class=name)
             self._m_class_resident.set(row["resident"], slo_class=name)
-            self._m_class_preempted.set(row["preempted"], slo_class=name)
         with self._lock:
             self._class_stats = stats
 
@@ -1325,6 +1320,7 @@ class ServerState:
         # the same registry instance.
         self.metrics = (metrics if metrics is not None
                         else observability.default_registry())
+        observability.watch_gc()
         #: the process's flight-recorder ring (GET /debug/flight; dumped on
         #: crash/504/SIGTERM). The process-global instance by default so the
         #: lifecycle layer's module-level hooks land in the same ring;
@@ -1362,6 +1358,11 @@ class ServerState:
             "Admission to the start of the request's first prefill piece: "
             "its wait for a turn behind the other rows' pieces (0 for a "
             "request admitted without chunked prefill)")
+        self._m_handler_cpu = reg.counter(
+            "dllama_handler_cpu_seconds_total",
+            "CPU seconds (time.thread_time) the HTTP handler threads spent "
+            "in POST requests, counted as each ends: interpreter time the "
+            "front takes from the scheduler thread")
         # per-SLO-class latency series: the workload harness's per-class
         # SLO gates (and `cli top`'s lane view) read these off the
         # federated /metrics/fleet
@@ -1408,10 +1409,6 @@ class ServerState:
             "dllama_kv_transfer_bytes_total",
             "Framed KV page-stream wire bytes, by direction (in/out)",
             ("direction",))
-        self._m_kv_pages = reg.counter(
-            "dllama_kv_transfer_pages_total",
-            "KV pages shipped on the transfer wire, by direction (in/out)",
-            ("direction",))
         # mid-stream failover: periodic session checkpoints shipped in-band
         # to the router. outcome="error" moves when the ckpt_write fault
         # site fires (or a live export fails) — a failed checkpoint only
@@ -1437,15 +1434,6 @@ class ServerState:
             tp_overlap=("on" if getattr(engine, "tp_overlap_active", False)
                         else "off"),
             tp_reduce=getattr(engine, "tp_reduce", "off"))
-        reg.gauge("dllama_batch_queue_depth",
-                  "Arrivals waiting for the batch scheduler").set_function(
-            lambda: float(self.batcher.queue_depth())
-            if self.batcher is not None else 0.0)
-        reg.gauge("dllama_slots_occupied",
-                  "Occupied slots of the live pooled decode session"
-                  ).set_function(
-            lambda: float(self.batcher.occupancy()[0])
-            if self.batcher is not None else 0.0)
         # --batch-window > 0: requests (greedy or sampled, streaming or
         # not) that arrive within the window share a continuously batched
         # slot-pool decode (Batcher) — single-device or tensor-parallel
@@ -1938,6 +1926,13 @@ class OpenAIHandler(BaseHTTPRequestHandler):
             self._error(404, f"unknown path {self.path}")
 
     def do_POST(self):
+        cpu0 = time.thread_time()
+        try:
+            self._post()
+        finally:
+            self.state._m_handler_cpu.inc(time.thread_time() - cpu0)
+
+    def _post(self):
         self._begin_request()
         if self.path in ("/v1/chat/completions", "/chat/completions"):
             handle, binary = self._handle_completions, False
@@ -2655,7 +2650,6 @@ class OpenAIHandler(BaseHTTPRequestHandler):
             stop_state=({"stops": stops, "hold": "", "stopped": False}
                         if stops else None))
         st._m_kv_bytes.inc(len(payload), direction="out")
-        st._m_kv_pages.inc(float(snap["n_blocks"]), direction="out")
         trace.tokens_out = len(emitted)
         trace.finish_reason = "migrated"
         self.send_response(200)
@@ -2685,7 +2679,6 @@ class OpenAIHandler(BaseHTTPRequestHandler):
             self._error(422, f"rejected KV stream: {e}")
             return
         st._m_kv_bytes.inc(len(body), direction="in")
-        st._m_kv_pages.inc(float(snap["n_blocks"]), direction="in")
         extra = snap.get("extra") or {}
         stream = bool(extra.get("stream"))
         carried = [int(t) for t in extra.get("emitted_tokens") or []]
@@ -2780,7 +2773,6 @@ class OpenAIHandler(BaseHTTPRequestHandler):
             self._error(422, f"not a resumable checkpoint: {e}")
             return
         st._m_kv_bytes.inc(len(body), direction="in")
-        st._m_kv_pages.inc(float(snap["n_blocks"]), direction="in")
         prompt_tokens = list(snap["prompt"])
         trace.tokens_in = len(prompt_tokens)
         detector = (StopDetector.from_state(snap["stop_state"])

@@ -56,16 +56,9 @@ _M_CLASS_INFLIGHT = _REG.gauge(
     "dllama_class_inflight",
     "Requests currently admitted past the gate, by SLO class",
     ("slo_class",))
-_M_CLASS_REJECTIONS = _REG.counter(
-    "dllama_class_rejections_total",
-    "Requests rejected at the admission gate, by SLO class and reason",
-    ("slo_class", "reason"))
 _M_KV_RESERVED = _REG.gauge(
     "dllama_kv_tokens_reserved",
     "KV token-slots reserved against the session's modeled HBM budget")
-_M_KV_BUDGET = _REG.gauge(
-    "dllama_kv_tokens_budget",
-    "The session's modeled HBM budget in KV token-slots (max_batch*seq_len)")
 _M_KV_ROWS = _REG.gauge(
     "dllama_kv_bucket_rows",
     "Rows resident per KV bucket context length",
@@ -366,16 +359,12 @@ class AdmissionGate:
         with self._lock:
             if self._draining:
                 _M_REJECTIONS.inc(reason="draining")
-                _M_CLASS_REJECTIONS.inc(slo_class=slo_class,
-                                        reason="draining")
                 self._flight.record("reject", reason="draining")
                 raise ServerDraining()
             lane_cap = self.class_capacity(slo_class)
             lane_depth = self._class_inflight.get(slo_class, 0)
             if self._inflight >= self.capacity or lane_depth >= lane_cap:
                 _M_REJECTIONS.inc(reason="queue_full")
-                _M_CLASS_REJECTIONS.inc(slo_class=slo_class,
-                                        reason="queue_full")
                 self._flight.record("reject", reason="queue_full",
                                     depth=self._inflight,
                                     slo_class=slo_class)
@@ -454,7 +443,6 @@ class KVBudget:
         self._reserved = 0
         self._rows: dict = {}  # bucket ctx -> resident rows
         self.pages: paged_kv.PageAllocator = None  # paged mode (attach_pages)
-        _M_KV_BUDGET.set(self.total_tokens)
         _M_KV_RESERVED.set(0)
 
     def attach_pages(self, num_pages: int,

@@ -5,6 +5,7 @@ handled surface, `/root/reference/src/apps/dllama-api/dllama-api.cpp:202-322`)."
 import http.client
 import json
 import threading
+import time
 
 import pytest
 
@@ -187,6 +188,34 @@ def test_completion_basic(server):
     assert obj["usage"]["total_tokens"] == (
         obj["usage"]["prompt_tokens"] + obj["usage"]["completion_tokens"]
     )
+
+
+def _handler_cpu_seconds(port) -> float:
+    _, text = request(port, "GET", "/metrics")
+    for line in text.decode().splitlines():
+        if line.startswith("dllama_handler_cpu_seconds_total "):
+            return float(line.split()[1])
+    raise AssertionError("no dllama_handler_cpu_seconds_total on /metrics")
+
+
+def test_a_request_adds_its_handler_threads_cpu_seconds(server):
+    before = _handler_cpu_seconds(server)
+    status, _ = request(server, "POST", "/v1/chat/completions", chat_body())
+    assert status == 200
+    # counted as do_POST ends, a moment after the client has its answer
+    deadline = time.monotonic() + 30
+    while (_handler_cpu_seconds(server) <= before
+           and time.monotonic() < deadline):
+        time.sleep(0.01)
+    assert _handler_cpu_seconds(server) > before
+
+
+def test_metrics_carry_no_family_that_nobody_reads(server):
+    _, text = request(server, "GET", "/metrics")
+    for gone in ("dllama_batch_queue_depth", "dllama_slots_occupied",
+                 "dllama_class_preempted_rows", "dllama_class_rejections_total",
+                 "dllama_kv_tokens_budget", "dllama_kv_transfer_pages_total"):
+        assert gone not in text.decode()
 
 
 def test_completion_deterministic_at_temp0(server):

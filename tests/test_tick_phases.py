@@ -30,6 +30,7 @@ from tests.test_api_server import make_tokenizer
 from tests.test_llama_forward import tiny_cfg
 
 FAMILY = "dllama_tick_phase_seconds_total"
+CPU_FAMILY = "dllama_tick_phase_cpu_seconds_total"
 #: the leaf phases of a tick that prefills a piece and decodes a chunk, with
 #: the (layer, side) each is counted under (PERF.md section 3 has the
 #: boundaries); ``go_live`` only in the tick that ends a request's prefill
@@ -51,9 +52,9 @@ PHASES = {
 CHUNK = 4
 
 
-def phase_seconds() -> dict:
+def phase_seconds(family: str = FAMILY) -> dict:
     """{(phase, layer, side): seconds} of the default registry, now."""
-    snap = ob.default_registry().snapshot()[FAMILY]["values"]
+    snap = ob.default_registry().snapshot()[family]["values"]
     return {(v["labels"]["phase"], v["labels"]["layer"], v["labels"]["side"]):
             v["value"] for v in snap}
 
@@ -152,13 +153,19 @@ def test_an_exception_ends_the_span_and_propagates():
 
 def test_outside_a_tick_and_without_a_layer_a_span_only_annotates():
     before = phase_seconds()
+    cpu_before = phase_seconds(CPU_FAMILY)
     with ob.phase("sse_write", span_id=3) as p:
+        pass
+    with ob.phase("t_no_tick", "engine"):
         pass
     with ob.tick():
         with ob.phase("t_no_layer"):
             pass
     assert p.t1 >= p.t0 > 0.0
     assert delta(phase_seconds(), before) == {}
+    # no CPU seconds either, and no leaf left open for the collector hook
+    assert delta(phase_seconds(CPU_FAMILY), cpu_before) == {}
+    assert ob._during == "none"
 
 
 def test_nothing_is_written_and_no_file_opened_without_dllama_trace(
@@ -204,11 +211,14 @@ def test_observability_is_importable_and_spans_work_without_jax():
         "    with ob.phase('p', 'engine', 'device'):\n"
         "        pass\n"
         "assert 'jax' not in sys.modules\n"
-        "print(ob.default_registry().render().count('phase=\"p\"'))\n")
+        "text = ob.default_registry().render()\n"
+        "print(text.count('_seconds_total{phase=\"p\"'),\n"
+        "      text.count('_cpu_seconds_total{phase=\"p\"'))\n")
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=120)
     assert out.returncode == 0, out.stderr
-    assert out.stdout.strip() == "1"
+    # one series of wall seconds and one of CPU seconds
+    assert out.stdout.strip() == "2 1"
 
 
 # ---------------------------------------------------------------------------
