@@ -769,9 +769,12 @@ class tick(phase):
     while open (leaf phases find it there and take its number), gathers
     their wall and CPU seconds lock-free, and on exit adds them to the two
     counter families (and folds in the collector's pauses) under one lock
-    hold, counts the pass in ``dllama_ticks_total`` if any
-    ``side="device"`` phase ran in it (it launched), and writes the pass's
-    events to the ``DLLAMA_TRACE`` file in one batch."""
+    hold, counts the pass in ``dllama_ticks_total`` if it dispatched
+    device work (``launched``: a decode launch or a prefill piece; a pass
+    that only waits for or reads a launch dispatched before it is not
+    counted, so that a scheduler which keeps one launch queued behind the
+    running one counts one tick a launch), and writes the pass's events to
+    the ``DLLAMA_TRACE`` file in one batch."""
 
     __slots__ = ("seq", "_seconds", "_cpu", "_events", "launched")
 
@@ -790,8 +793,6 @@ class tick(phase):
         key = (p.name, p.layer, p.side)
         self._seconds[key] = self._seconds.get(key, 0.0) + (p.t1 - p.t0)
         self._cpu[key] = self._cpu.get(key, 0.0) + (p.c1 - p.c0)
-        if p.side == "device":
-            self.launched = True
         if self._events is not None:
             self._events.append(scheduler_trace_event(
                 p.name, p.t0, p.t1, p._span_args()))
@@ -820,6 +821,14 @@ class tick(phase):
                 [scheduler_trace_event("tick", self.t0, self.t1,
                                        self._span_args())] + self._events)
         return False
+
+
+def launched() -> None:
+    """Count the tick open on this thread in ``dllama_ticks_total``: it
+    dispatched device work. Outside a tick, nothing."""
+    t = getattr(_tls, "tick", None)
+    if t is not None:
+        t.launched = True
 
 
 def _on_gc(what: str, info: dict) -> None:

@@ -15,6 +15,7 @@ by design, all TPU-motivated:
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import time
 from functools import partial
@@ -225,6 +226,18 @@ class Engine:
             self._m_migrations = metrics.counter(
                 "dllama_kv_migrations_total",
                 "Pooled rows migrated to the next larger KV bucket")
+            self._m_ahead = metrics.counter(
+                "dllama_decode_ahead_total",
+                "Pooled decode launches by what the launch before them was "
+                "doing at their dispatch: state=\"queued\" still running (the "
+                "new one waits behind it), \"late\" already ended (the "
+                "device idled), \"idle\" none in flight",
+                labelnames=("state",))
+            self._m_overrun = metrics.counter(
+                "dllama_decode_overrun_rows_total",
+                "Rows a pooled decode launch carried one chunk past a stop "
+                "token or a quarantine that the launch before it found "
+                "(their tokens are dropped)")
             self._m_quarantine = metrics.counter(
                 "dllama_numeric_quarantines_total",
                 "Rows/streams stopped by the numeric-health watchdog")
@@ -328,6 +341,7 @@ class Engine:
             self._m_kv_resident = None
             self._m_prefill = self._m_step = self._m_chunk = None
             self._m_prefill_chunk = self._m_migrations = None
+            self._m_ahead = self._m_overrun = None
             self._m_live_rows = None
             self._m_prefill_tokens = self._m_ride_slots = None
             self._m_quarantine = None
@@ -593,6 +607,18 @@ class Engine:
             logits = jnp.where(poison, nan, logits)
             return logits, ok & jnp.all(jnp.isfinite(logits))
 
+        def _take_fix(tokens, keys, fix):
+            """A slot pool's launch: ``tokens`` and ``keys`` are the last
+            launch's own, still on the device; ``fix`` int32 [B, 4] holds
+            (flag, token, key's two words as int32 bits) for the rows that
+            went live or moved since, which take those instead."""
+            new = fix[:, 0] != 0
+            tokens = jnp.where(new, fix[:, 1], tokens)
+            keys = jnp.where(
+                new[:, None],
+                jax.lax.bitcast_convert_type(fix[:, 2:], jnp.uint32), keys)
+            return tokens, keys
+
         @partial(jax.jit, donate_argnums=(2,))
         def _decode_step(params, rope, cache, token, pos, key, temp, topp, poison):
             logits, cache = fwd(cfg, params, rope, token[None], cache, pos)
@@ -646,8 +672,8 @@ class Engine:
             @partial(jax.jit, donate_argnums=(2,),
                      static_argnames=("n_steps",))
             def _decode_loop_batch(params, rope, cache, tokens, pos, keys,
-                                   temps, topps, poison, ride=None, *,
-                                   n_steps):
+                                   temps, topps, poison, ride=None, fix=None,
+                                   *, n_steps):
                 """N batched decode steps fused into one program: every step
                 streams the weights ONCE for all B sequences
                 (llama.forward_batched) and samples each row on device. A row
@@ -678,7 +704,15 @@ class Engine:
                 rows only. One operand, so that a launch copies one more
                 array to the device, not four. A caller without a slot pool
                 (a fixed batch, the ``--tp`` wrappers' ``fwd_b``) names no
-                riders and traces the program as it was."""
+                riders and traces the program as it was.
+
+                ``fix`` int32 [B, 4]: the slot pool hands the last launch's
+                tokens and keys back as they are on the device and names the
+                rows that take new ones (``_take_fix``); the program then
+                also returns its last tokens, the next launch's. A caller
+                that names none traces the program as it was."""
+                if fix is not None:
+                    tokens, keys = _take_fix(tokens, keys, fix)
 
                 def body(carry, ride_s):
                     cache, toks, pos_, keys_, ok = carry
@@ -701,6 +735,8 @@ class Engine:
                      jnp.ones(tokens.shape, jnp.bool_)),
                     ride, length=n_steps,
                 )
+                if fix is not None:
+                    return out, cache, keys, ok, toks
                 return out, cache, keys, ok  # out [n_steps, B], ok [B]
 
             return _decode_loop_batch
@@ -717,7 +753,11 @@ class Engine:
             @partial(jax.jit, donate_argnums=(2,),
                      static_argnames=("n_steps",))
             def _decode_loop_batch(params, rope, cache, tokens, pos, keys,
-                                   temps, topps, poison, live, n_steps):
+                                   temps, topps, poison, live, fix=None, *,
+                                   n_steps):
+                if fix is not None:
+                    tokens, keys = _take_fix(tokens, keys, fix)
+
                 def body(carry, _):
                     cache, toks, pos_, keys_, ok, picks = carry
                     logits, cache, got = llama.forward_batched(
@@ -738,18 +778,21 @@ class Engine:
                      jnp.zeros((layer_plan.pick_width(cfg),), jnp.int32)),
                     length=n_steps,
                 )
+                if fix is not None:
+                    return out, cache, keys, ok, toks, picks
                 return out, cache, keys, ok, picks
 
             def run(params, rope, cache, tokens, pos, keys, temps, topps,
-                    poison, n_steps, live=None):
+                    poison, n_steps, live=None, fix=None):
                 """A caller that names no live rows (a fixed batch) counts
                 all of them and gets the four values of a uniform model's
-                program; the slot pool names them and gets the picks too."""
+                program; the slot pool names them and gets the picks too
+                (after the last tokens, where it hands a ``fix``)."""
                 mask = (jnp.ones(tokens.shape, jnp.bool_) if live is None
                         else live)
                 out = _decode_loop_batch(params, rope, cache, tokens, pos,
                                          keys, temps, topps, poison, mask,
-                                         n_steps=n_steps)
+                                         fix, n_steps=n_steps)
                 return out[:4] if live is None else out
 
             return run
@@ -2014,6 +2057,65 @@ class _SlotState:
     span_id: int = 0  # the request's trace track, on its prefill phase spans
 
 
+def _drop(tree) -> None:
+    """Free a pytree's device buffers once the work that writes them (a
+    piece or a launch not waited for) has ended."""
+    leaves = jax.tree.leaves(tree)
+    jax.block_until_ready(leaves)
+    for leaf in leaves:
+        leaf.delete()
+
+
+def host_prng_key(seed: int) -> np.ndarray:
+    """``jax.random.PRNGKey(seed)`` worked out on the host, with no device
+    program and no sync: the default threefry key of a seed is its two
+    32-bit words, the high one 0 unless 64-bit types are on."""
+    if jax.config.jax_default_prng_impl != "threefry2x32":
+        return np.asarray(jax.random.PRNGKey(seed), np.uint32)
+    seed = int(seed)
+    hi = (seed >> 32) & 0xFFFFFFFF if jax.config.jax_enable_x64 else 0
+    return np.array([hi, seed & 0xFFFFFFFF], np.uint32)
+
+
+class _Part:
+    """One pool's program of a decode launch, from its dispatch to its
+    collection: the rows it advanced as they stood then (row, handle, their
+    ``_SlotState``, tokens offered them before it), the prompts that rode
+    it (handle, state, pending prompt, cursor after, prefix complete)."""
+
+    __slots__ = ("pool", "rows", "riders", "out", "ok", "keys", "picks",
+                 "live_pos", "t0")
+
+    def __init__(self, pool, rows: list, riders: list, t0: float):
+        self.pool = pool
+        self.rows = rows
+        self.riders = riders
+        self.t0 = t0
+        self.out = self.ok = self.keys = self.live_pos = None
+        self.picks: list = []
+
+
+class _Launch:
+    """One decode launch: a program per occupied pool, dispatched together.
+    ``state`` is what the launch before it was doing at the dispatch
+    (``queued``: still running, ``late``: ended, ``idle``: none in flight);
+    ``start`` when the device began it, as far as the host can tell (None
+    while unknown), ``end`` when it ended where the host waited for it, or
+    else ``bound``, a time by which it had ended; ``kind`` (riders, the
+    bucket of the standalone piece before it, 0 for none) groups launches
+    of like length for ``BatchSession.due``."""
+
+    __slots__ = ("parts", "state", "kind", "dispatched", "start", "end",
+                 "bound")
+
+    def __init__(self, state: str, piece: int):
+        self.parts: list = []
+        self.state = state
+        self.kind = (False, piece)
+        self.dispatched = 0.0
+        self.start = self.end = self.bound = None
+
+
 class _PendingPrefill:
     """A chunked admission's in-flight prompt state (admit_begin)."""
 
@@ -2051,7 +2153,7 @@ class _BucketPool:
     the session migrates rows out before they outgrow it."""
 
     __slots__ = ("ctx", "cap", "cache", "tokens", "pos", "keys", "temps",
-                 "topps", "rows")
+                 "topps", "rows", "dev", "fix", "sampling", "live")
 
     def __init__(self, eng: Engine, ctx: int, cap: int):
         self.ctx = ctx
@@ -2064,6 +2166,19 @@ class _BucketPool:
         self.temps = np.zeros((cap,), np.float32)
         self.topps = np.ones((cap,), np.float32)
         self.rows: list = [None] * cap  # handle occupying each row
+        #: a session whose launches run ahead (``BatchSession.ahead``): the
+        #: last launch's tokens and keys, on the device, which the next
+        #: launch takes as they are; None until the first launch, and after
+        #: ``BatchSession._pull`` brought them back to ``tokens`` / ``keys``
+        self.dev = None
+        #: rows whose next launch takes ``tokens`` / ``keys`` from the host
+        #: (they went live or moved since the last launch)
+        self.fix = np.zeros((cap,), np.bool_)
+        #: (temps, topps) on the device as last uploaded, None once changed
+        self.sampling = None
+        #: a layer plan's (live mask on the host, on the device) as last
+        #: uploaded
+        self.live = None
 
     def grow(self, eng: Engine) -> None:
         """Double the pool's capacity in place: rows keep their indices (no
@@ -2086,6 +2201,9 @@ class _BucketPool:
             [self.topps, np.ones((pad,), np.float32)])
         self.rows.extend([None] * pad)
         self.cap = new_cap
+        # the caller brought the device's tokens and keys back first
+        self.fix = np.zeros((new_cap,), np.bool_)
+        self.sampling = self.live = None
 
 
 class _RowPages:
@@ -2226,6 +2344,25 @@ class BatchSession:
         #: time.monotonic as ``piece_span``'s
         self.rode: list = []
         self._no_ride = None  # the ride operand of a launch nobody rides
+        #: a slot-pool session on one device runs its launches AHEAD of
+        #: their collection (``launch`` / ``collect``): the tokens and keys
+        #: a launch leaves stay on the device for the next, a standalone
+        #: piece is not waited for, and the scheduler keeps one launch
+        #: queued behind the running one. The paged loop and the ``--tp``
+        #: programs keep the host's copies and run one launch at a time
+        self.ahead = not self.paged and eng.mesh is None
+        self._flight: collections.deque = collections.deque()
+        self._ahead_tick = False  # step_chunk called from step_ahead
+        self._no_fix: dict = {}  # cap -> the fix operand that changes no row
+        #: the bucket of a standalone piece dispatched since the last decode
+        #: launch (0: none): a 64-token piece runs longer than an 8-token one
+        self._piece_pending = 0
+        #: launch kind -> its last seconds on the device, where the host saw
+        #: both ends or knew it had ended by then
+        self._period: dict = {}
+        #: when the last collected launch was seen to end, and when the
+        #: last launch's dispatch returned (time.monotonic)
+        self._last_end = self.dispatched = 0.0
         S = eng.cfg.seq_len
         if self.paged:
             # page size must divide the model context so logical blocks tile
@@ -2341,8 +2478,19 @@ class BatchSession:
     @property
     def num_live(self) -> int:
         """Rows the next step_chunk will actually advance."""
-        return sum(1 for st in self._slots.values()
-                   if not st.done and not st.prefilling)
+        return sum(1 for st in self._slots.values() if self._decodes(st))
+
+    @staticmethod
+    def _decodes(st: _SlotState) -> bool:
+        """Whether the next launch advances the row: prefilled, not done,
+        and its budget not spent by the launches already dispatched (a
+        launch that runs ahead of its collection knows its count)."""
+        return not st.done and not st.prefilling and st.offered < st.budget
+
+    @property
+    def in_flight(self) -> int:
+        """Decode launches dispatched and not yet collected."""
+        return len(self._flight)
 
     @property
     def pending_prefills(self) -> list:
@@ -2593,9 +2741,23 @@ class BatchSession:
             if pool.rows[r] is None:
                 return pool, r
         r = pool.cap
+        self._pull(pool)
         pool.grow(self.eng)
         self._publish_kv_resident()
         return pool, r
+
+    def _pull(self, pool: _BucketPool) -> None:
+        """Bring a pool's tokens and keys from the device to its host
+        arrays, reading the launch in flight first (a growing pool and a
+        migrating row need them there); rows waiting for an override keep
+        theirs. The pool's next launch uploads them all."""
+        if pool.dev is None:
+            return
+        toks, keys = (np.asarray(a) for a in pool.dev)
+        keep = ~pool.fix
+        pool.tokens[keep] = toks[keep]
+        pool.keys[keep] = keys[keep]
+        pool.dev = None
 
     def kv_resident_bytes(self) -> dict:
         """Bytes of the slot pools' caches by attention kind: a uniform
@@ -2843,7 +3005,9 @@ class BatchSession:
         tokens (default: the session's prefill_chunk; the whole remaining
         prefix when neither is set) — one bucketed prefill forward into the
         admission's own single cache, synced before returning so the call
-        bounds the scheduler tick. Returns (handle, finished); ``finished``
+        bounds the scheduler tick (not where the session runs ahead: the
+        device runs it in order, before the next decode launch). Returns
+        (handle, finished); ``finished``
         True means the row just went live (its slab is written; the next
         step_chunk decodes it). Returns None when nothing is pending.
         Picks the OLDEST pending admission when ``handle`` is None — FIFO,
@@ -2896,11 +3060,20 @@ class BatchSession:
             _, pf.cache = self.eng._prefill_piece(
                 pf.cache, piece, pf.cursor,
                 bucket=prefill_bucket(n) if self.ride_t else None)
-        with observability.phase("prefill_wait", "engine", "device",
-                                 span_id=st.span_id) as wait:
-            jax.block_until_ready(pf.cache)
-        # the piece's wall time IS its two phases: one pair of clock reads
-        dt = (wait.t1 - dispatch.t0) * 1000.0
+            observability.launched()
+        ended = dispatch
+        if self.ahead:
+            # not waited for: the piece, its insert and the decode launch
+            # after it queue on the device in that order, so a row whose
+            # prefix ends here decodes in that launch
+            self._piece_pending = self.eng._last_prefill_bucket
+        else:
+            with observability.phase("prefill_wait", "engine", "device",
+                                     span_id=st.span_id) as ended:
+                jax.block_until_ready(pf.cache)
+        # the piece's wall time IS its phases (where it is not waited for,
+        # its dispatch): one pair of clock reads
+        dt = (ended.t1 - dispatch.t0) * 1000.0
         with observability.phase("prefill_land", "engine",
                                  span_id=st.span_id) as last:
             self.prefill_ms += dt
@@ -2944,9 +3117,11 @@ class BatchSession:
         decodes, or when a prompt already rides in it: a rider whose pool
         lost its last live row has the chunk launched for it."""
         for h in pool.rows:
-            if h is None or self._slots[h].done:
+            if h is None:
                 continue
-            if not self._slots[h].prefilling or self._prefills[h].rides:
+            st = self._slots[h]
+            if self._decodes(st) or (st.prefilling and not st.done
+                                     and self._prefills[h].rides):
                 return True
         return False
 
@@ -2994,6 +3169,7 @@ class BatchSession:
         if not self.paged:
             raise RuntimeError(
                 "export_row needs a paged session (--kv-pages)")
+        self._settle()
         st = self._state(handle)
         if st.prefilling:
             raise RuntimeError(f"slot {handle} is still prefilling")
@@ -3134,9 +3310,11 @@ class BatchSession:
         return handle
 
     def _go_live_phase(self, handle: int, pf: _PendingPrefill):
-        """The ``go_live`` phase of the tick that ends a prompt, by either
-        way: the row's decode state (its PRNGKey is a device program and a
-        sync of its own) and the request's whole prefill time."""
+        """The ``go_live`` phase of the tick whose standalone piece ends a
+        prompt: the row's decode state and the request's whole prefill
+        time. (A prompt that rides goes live as the launch that ends it is
+        dispatched, and its prefill time is counted as that launch is
+        collected.)"""
         st = self._slots[handle]
         with observability.phase("go_live", "engine",
                                  span_id=st.span_id) as phase:
@@ -3149,14 +3327,17 @@ class BatchSession:
                  scfg: SamplerConfig) -> None:
         """Arm the row's decode state: pending last prompt token, position,
         fresh per-row sampler chain — the exact state a monolithic admit
-        leaves behind."""
+        leaves behind, its key worked out on the host (``host_prng_key``).
+        A slot pool's next launch takes the token and key from here."""
         pool, row = self._where[handle]
         pool.tokens[row] = int(prompt_tokens[-1])
         pool.pos[row] = len(prompt_tokens) - 1
-        pool.keys[row] = np.asarray(
-            jax.random.PRNGKey(scfg.seed), np.uint32)
+        pool.keys[row] = host_prng_key(scfg.seed)
         pool.temps[row] = scfg.temperature
         pool.topps[row] = scfg.topp
+        if not self.paged:
+            pool.fix[row] = True
+            pool.sampling = None
 
     def _migrate(self, handle: int) -> None:
         """Move a live row into the next bucket BEFORE it outgrows its
@@ -3164,13 +3345,16 @@ class BatchSession:
         history — into a row of the bigger pool and carry the host decode
         state (pending token, position, sampler chain) unchanged, so the
         stream continues bit-identically. Admission reserved the worst-case
-        bucket up front, so migration never oversubscribes the budget."""
+        bucket up front, so migration never oversubscribes the budget.
+        Where the token and key live on the device they are read from the
+        launch in flight first."""
         src, srow = self._where[handle]
         S = self.eng.cfg.seq_len
         need = min(S, int(src.pos[srow]) + self.chunk + 1)
         new_ctx = min(b for b in self.buckets
                       if b > src.ctx and b >= need)
         dst, drow = self._alloc_row(new_ctx)
+        self._pull(src)
         dst.cache = self.eng._bucket_cache_migrate(
             dst.cache, src.cache, jnp.int32(srow), jnp.int32(drow))
         dst.tokens[drow] = src.tokens[srow]
@@ -3178,6 +3362,8 @@ class BatchSession:
         dst.keys[drow] = src.keys[srow]
         dst.temps[drow] = src.temps[srow]
         dst.topps[drow] = src.topps[srow]
+        dst.fix[drow] = True
+        dst.sampling = None
         dst.rows[drow] = handle
         src.rows[srow] = None
         src.pos[srow] = src.ctx - 1
@@ -3198,17 +3384,19 @@ class BatchSession:
         are marked done (``is_done``) and skip future chunks until
         released; ``finish_reason`` says why. Returns {} without touching
         the device when nothing is live. Mid-prefill rows are skipped until
-        their prefill completes.
+        their prefill completes. A slot pool's chunk is ``launch`` then
+        ``collect``, read at once (with launches already in flight, every
+        one of them is read, in order).
 
         The prompt rides the chunk (``ride_t`` > 0): a pool that launches
         carries, in each step, up to ``ride_t`` tokens of the oldest pending
         prompt whose row lives in that pool and which did not begin in a
         staging cache; where its prefix ends the next prompt starts at the
         next step of the same chunk. The K/V land in the rows' own slabs.
-        After the launch the cursors advance and a row whose prefix is
-        complete goes live: it decodes from the next chunk. A pool whose
-        only work is a riding prompt launches for it. ``rode`` lists what
-        the riders of this call did.
+        As the launch is dispatched the cursors advance and a row whose
+        prefix it completes goes live: it decodes from the next chunk. A
+        pool whose only work is a riding prompt launches for it. ``rode``
+        lists what the riders of this call did.
 
         Bucketed sessions first migrate any live row that would outgrow its
         slab within this chunk, then run one program per occupied bucket,
@@ -3224,15 +3412,59 @@ class BatchSession:
         if self._closed:
             raise RuntimeError("batch session is closed")
         self.rode = []
-        if not any(not st.done and not st.prefilling
-                   for st in self._slots.values()) \
-                and not any(pf.rides for pf in self._prefills.values()):
-            return {}
-        faults.fire("step_chunk")
         if self.paged:
+            if not any(self._decodes(st) for st in self._slots.values()):
+                return {}
+            faults.fire("step_chunk")
             return self._step_chunk_paged()
-        S = self.eng.cfg.seq_len
+        if self._ahead_tick:
+            held = bool(self._flight)
+            self.launch()
+            return self.collect() if held else {}
+        self.launch()
         fresh: dict = {}
+        rode: list = []
+        while self._flight:
+            for h, toks in self.collect().items():
+                fresh.setdefault(h, []).extend(toks)
+            rode += self.rode
+        self.rode = rode
+        return fresh
+
+    def step_ahead(self) -> dict:
+        """One tick of a scheduler that keeps a launch queued behind the
+        running one: dispatch the next launch, THEN read the one before it,
+        which has ended by then or is about to, while the device goes on
+        with the new one. Returns the bursts of the launch it read, as
+        ``step_chunk`` does ({} where none was in flight: the new one is
+        read by the next call). It runs through ``step_chunk``, so that
+        every burst a session hands out passes one method."""
+        self._ahead_tick = True
+        try:
+            return self.step_chunk()
+        finally:
+            self._ahead_tick = False
+
+    def launch(self) -> Optional[_Launch]:
+        """Dispatch ONE fused chunk over every occupied pool and return
+        without waiting for it (None where nothing decodes or rides: the
+        device is not touched); ``collect`` reads it. What the next launch
+        needs is known here: positions are host arithmetic, the tokens and
+        keys stay on the device (``ahead``), a row whose budget this launch
+        spends is left out of the next, a riding prompt's cursor advances,
+        and a row whose prefix this launch completes goes live for the
+        next. Counts in ``dllama_decode_ahead_total`` what the launch
+        before it was doing at the dispatch."""
+        if self._closed:
+            raise RuntimeError("batch session is closed")
+        if not any(self._decodes(st) for st in self._slots.values()) \
+                and not any(pf.rides for pf in self._prefills.values()):
+            return None
+        faults.fire("step_chunk")
+        S = self.eng.cfg.seq_len
+        prev = self._flight[-1] if self._flight else None
+        rec = _Launch("idle" if prev is None else "queued",
+                      self._piece_pending)
         stepped: set = set()
         while True:
             todo = [c for c in sorted(self._pools) if c not in stepped]
@@ -3248,12 +3480,10 @@ class BatchSession:
                     # and nothing reads past them afterwards)
                     for r in range(pool.cap):
                         h = pool.rows[r]
-                        if h is None:
+                        if h is None or not self._decodes(self._slots[h]):
                             continue
                         st = self._slots[h]
-                        if st.done or st.prefilling:
-                            continue
-                        useful = min(self.chunk, st.budget - st.emitted)
+                        useful = min(self.chunk, st.budget - st.offered)
                         p = int(pool.pos[r])
                         if ((useful >= self.chunk and p + self.chunk >= ctx)
                                 or (useful < self.chunk
@@ -3261,51 +3491,204 @@ class BatchSession:
                             self._migrate(h)
                 live = [r for r in range(pool.cap)
                         if pool.rows[r] is not None
-                        and not self._slots[pool.rows[r]].done
-                        and not self._slots[pool.rows[r]].prefilling]
+                        and self._decodes(self._slots[pool.rows[r]])]
                 if not live and not (self.ride_t
                                      and self._pool_launches(pool)):
                     continue
-                riders = self._plan_ride(pool) if self.ride_t else ()
+                riders = self._plan_ride(pool) if self.ride_t else []
             with observability.phase("decode_dispatch", "engine") as dispatch:
-                plan = {}
-                if self.eng.cfg.layer_plan:
-                    # its program also counts what the live rows routed to
-                    mask = np.zeros((pool.cap,), np.bool_)
-                    mask[live] = True
-                    plan["live"] = jnp.asarray(mask)
-                    live_pos = pool.pos[live]  # before the launch moves them
-                if self.ride_t:
-                    plan["ride"] = self._ride_operand(riders)
-                chunk, pool.cache, keys, ok, *picks = self.eng.batch_loop(
-                    len(live))(
-                    pool.cache, jnp.asarray(pool.tokens),
-                    jnp.asarray(pool.pos), jnp.asarray(pool.keys),
-                    jnp.asarray(pool.temps), jnp.asarray(pool.topps),
-                    self.eng._poison_rows(pool.cap), n_steps=self.chunk,
-                    **plan)
-            with observability.phase("decode_wait", "engine", "device"):
-                arr = np.asarray(chunk)  # [chunk, cap]
-            # reads issued after the program ended: the device idles
+                if not rec.parts:
+                    if prev is not None and prev.parts[-1].out.is_ready():
+                        rec.state, prev.bound = "late", dispatch.t0
+                    if self.eng._m_ahead is not None:
+                        self.eng._m_ahead.inc(state=rec.state)
+                rec.parts.append(
+                    self._dispatch(pool, live, riders, dispatch.t0))
+                if len(rec.parts) == 1:
+                    self._flight.append(rec)
+                observability.launched()
+            part = rec.parts[-1]
+            for h, st, pf, _, finished in part.riders:
+                if finished:
+                    with observability.phase("go_live", "engine",
+                                             span_id=st.span_id):
+                        self._go_live(h, pf.prompt, pf.scfg)
+        if not rec.parts:
+            return None
+        rec.dispatched = self.dispatched = dispatch.t1
+        rec.kind = (any(p.riders for p in rec.parts), rec.kind[1])
+        if rec.state != "queued":
+            rec.start = rec.dispatched
+        self._piece_pending = 0
+        return rec
+
+    def _dispatch(self, pool: _BucketPool, live: list, riders: list,
+                  t0: float) -> _Part:
+        """Call the pool's decode program on what the host and the device
+        hold for it, then move the host's side on to the launch's end: the
+        rows' offered tokens and positions, the riders' cursors."""
+        plan = {}
+        part = _Part(pool, [], [], t0)
+        if self.eng.cfg.layer_plan:
+            # its program also counts what the live rows routed to
+            mask = np.zeros((pool.cap,), np.bool_)
+            mask[live] = True
+            if pool.live is None or not np.array_equal(pool.live[0], mask):
+                pool.live = (mask, jnp.asarray(mask))
+            plan["live"] = pool.live[1]
+            part.live_pos = pool.pos[live]  # before the launch moves them
+        if self.ride_t:
+            plan["ride"] = self._ride_operand(riders)
+        if self.ahead:
+            if pool.dev is None:
+                pool.dev = (jnp.asarray(pool.tokens), jnp.asarray(pool.keys))
+                pool.fix[:] = False
+            plan["fix"] = self._fix_operand(pool)
+            tokens, keys = pool.dev
+        else:
+            tokens, keys = jnp.asarray(pool.tokens), jnp.asarray(pool.keys)
+            pool.fix[:] = False
+        if pool.sampling is None:
+            pool.sampling = (jnp.asarray(pool.temps), jnp.asarray(pool.topps))
+        part.out, pool.cache, keys, part.ok, *rest = self.eng.batch_loop(
+            len(live))(
+            pool.cache, tokens, jnp.asarray(pool.pos), keys, *pool.sampling,
+            self.eng._poison_rows(pool.cap), n_steps=self.chunk, **plan)
+        if self.ahead:
+            pool.dev = (rest.pop(0), keys)
+        else:
+            part.keys = keys  # the host's copy is fetched as it is collected
+        part.picks = rest
+        for r in live:
+            st = self._slots[pool.rows[r]]
+            part.rows.append((r, pool.rows[r], st, st.offered))
+            st.offered += self.chunk
+        # mirror the in-program per-row pin across chunk boundaries
+        pool.pos = np.minimum(pool.pos + self.chunk,
+                              pool.ctx - 1).astype(np.int32)
+        for h, _, end in riders:
+            pf, st = self._prefills[h], self._slots[h]
+            finished = end >= len(pf.prompt) - 1
+            part.riders.append((h, st, pf, end - pf.cursor, finished))
+            pf.cursor = end
+            if finished:
+                del self._prefills[h]
+                st.prefilling = False
+        return part
+
+    def _fix_operand(self, pool: _BucketPool) -> jax.Array:
+        """``_decode_loop_batch``'s ``fix`` for the pool's next launch: the
+        rows flagged since its last take their host token and key, and the
+        flags clear. A launch that changes no row gets the same zeros every
+        time."""
+        if not pool.fix.any():
+            zeros = self._no_fix.get(pool.cap)
+            if zeros is None:  # a copy, not a program of its own to compile
+                zeros = self._no_fix[pool.cap] = jnp.asarray(
+                    np.zeros((pool.cap, 4), np.int32))
+            return zeros
+        lines = np.zeros((pool.cap, 4), np.int32)
+        lines[:, 0] = pool.fix
+        lines[:, 1] = pool.tokens
+        lines[:, 2:] = pool.keys.view(np.int32)
+        pool.fix[:] = False
+        return jnp.asarray(lines)
+
+    def collect(self) -> dict:
+        """Read the oldest launch in flight and account it: {handle: fresh
+        tokens} as ``step_chunk`` returns them ({} where nothing is in
+        flight). A row that finished (a stop, a quarantine), was cancelled
+        or was released after the launch was dispatched ran one chunk past
+        its end: its tokens are dropped, and a stop or a quarantine counts
+        in ``dllama_decode_overrun_rows_total``. ``rode`` lists what the
+        launch's riders did."""
+        self.rode = []
+        if not self._flight:
+            return {}
+        rec = self._flight.popleft()
+        fresh: dict = {}
+        for part in rec.parts:
+            pool = part.pool
+            with observability.phase("decode_wait", "engine",
+                                     "device") as wait:
+                ready = part.out.is_ready()
+                arr = np.asarray(part.out)  # [chunk, cap]
+            # reads issued after the program ended: the device idles unless
+            # a launch is queued behind this one
             with observability.phase("decode_fetch", "engine") as fetch:
-                okh = np.asarray(ok)  # [cap]
-                picked = np.asarray(picks[0]) if picks else None
-                pool.tokens = np.array(chunk[-1])  # np.array: writable copies
-                pool.keys = np.array(keys)
-                # mirror the in-program per-row pin across chunk boundaries
-                pool.pos = np.minimum(pool.pos + self.chunk,
-                                      ctx - 1).astype(np.int32)
+                okh = np.asarray(part.ok)  # [cap]
+                picked = np.asarray(part.picks[0]) if part.picks else None
+                if not self.ahead:
+                    # the host keeps the tokens and keys: a row that went
+                    # live since the dispatch keeps its own
+                    pool.dev = (part.out[-1], part.keys)
+                    self._pull(pool)
+            if part is rec.parts[-1]:  # the launch ends with its last
+                if ready:
+                    rec.bound = rec.bound or wait.t0
+                else:
+                    rec.end = wait.t1
             with observability.phase("account", "engine"):
-                self._observe_chunk(dispatch.t0, fetch.t1, len(live))
-                self._account_chunk(pool, live, arr, okh, fresh)
+                # a launch queued behind another began where that one ended
+                t0 = max(part.t0, self._last_end)
+                self._observe_chunk(t0, fetch.t1, len(part.rows))
+                self._account_chunk(part.rows, arr, okh, fresh)
                 if picked is not None:
                     self._account_picks(picked)
-                    self._account_ring(live_pos)
-                    self._account_latent(live_pos, ctx)
-                arrived = self._land_riders(riders, dispatch.t0, fetch.t1)
-            for h, pf in arrived:
-                self._go_live_phase(h, pf)
+                    self._account_ring(part.live_pos)
+                    self._account_latent(part.live_pos, pool.ctx)
+                self._mark_riders(part, t0, fetch.t1)
+                if part is rec.parts[-1]:
+                    self._time(rec)
+            self._last_end = wait.t1
         return fresh
+
+    def _time(self, rec: _Launch) -> None:
+        """Learn the launch's seconds on the device, per kind: where the
+        host waited for its end, the time from its start; where it had
+        ended unseen, the time to when it was known to have ended, if that
+        is under what the kind reads (so that a reading too long, a launch
+        that compiled, say, cannot keep the scheduler waiting past every
+        launch's end). Hand its end to the launch queued behind it, which
+        began there."""
+        if rec.start is not None:
+            seen = self._period.setdefault(
+                rec.kind, collections.deque(maxlen=9))
+            if rec.end is not None:
+                seen.append(rec.end - rec.start)
+            elif rec.bound is not None and (
+                    not seen or rec.bound - rec.start < np.median(seen)):
+                seen.append(rec.bound - rec.start)
+        if self._flight and self._flight[0].start is None \
+                and rec.end is not None:
+            nxt = self._flight[0]
+            nxt.start = max(nxt.dispatched, rec.end)
+
+    def due(self) -> Optional[tuple]:
+        """(when the newest launch in flight should end on
+        ``time.monotonic``, how far launches of its kind stray from that):
+        the median of the kind's last launches and their mean distance
+        from it. None while nothing is in flight or no launch of its kind
+        has been timed. The pipelined scheduler waits for arrivals until
+        then, less the time it takes to dispatch the next launch."""
+        if not self._flight:
+            return None
+        rec = self._flight[-1]
+        seen = self._period.get(rec.kind)
+        if not seen:
+            return None
+        mid = float(np.median(seen))
+        spread = float(np.mean(np.abs(np.asarray(seen) - mid)))
+        start = rec.start if rec.start is not None else rec.dispatched
+        return start + mid, spread
+
+    def _settle(self) -> None:
+        """Wait for the launches in flight to end; their results stay to
+        be collected. What must read them first (a row's export, the
+        session's close) calls this."""
+        for rec in self._flight:
+            for part in rec.parts:
+                jax.block_until_ready(part.out)
 
     def _plan_ride(self, pool) -> list:
         """The prompt tokens this launch of ``pool`` carries: [(handle,
@@ -3348,32 +3731,28 @@ class BatchSession:
                 step += 1
         return jnp.asarray(lines)
 
-    def _land_riders(self, riders: list, t0: float, t1: float) -> list:
-        """After the launch that carried them: advance the riders' cursors,
-        count their tokens and the launch's wall time as their prefill
-        chunk, note them in ``rode``. Returns the [(handle, pending)] whose
-        prefix is complete, taken off the pending list, for ``_go_live``."""
-        if not riders:
-            return []
+    def _mark_riders(self, part: _Part, t0: float, t1: float) -> None:
+        """After the launch that carried them: count their tokens and the
+        launch's wall time as their prefill chunk, note them in ``rode``
+        (marked from the launch's dispatch), and where a prefix is complete
+        observe the request's whole prefill time."""
+        if not part.riders:
+            return
         ms = (t1 - t0) * 1000.0
         self.prefill_ms += ms
-        arrived, tokens = [], 0
-        for h, _, end in riders:
-            pf, st = self._prefills[h], self._slots[h]
-            tokens += end - pf.cursor
-            pf.cursor = end
+        tokens = 0
+        for h, st, _, n, finished in part.riders:
+            tokens += n
             st.prefill_ms += ms
-            finished = end >= len(pf.prompt) - 1
-            self.rode.append((h, t0, t1, finished))
-            if finished:
-                del self._prefills[h]
-                st.prefilling = False
-                arrived.append((h, pf))
+            if self._slots.get(h) is not st:
+                continue  # cancelled and released while the launch ran
+            self.rode.append((h, part.t0, t1, finished))
+            if finished and self.eng._m_prefill is not None:
+                self.eng._m_prefill.observe(st.prefill_ms)
         if self.eng._m_prefill_chunk is not None:
             self.eng._m_prefill_chunk.observe(ms)
             self.eng._m_prefill_tokens.inc(tokens, how="ride")
             self.eng._m_ride_slots.inc(self.chunk * self.ride_t)
-        return arrived
 
     def _account_picks(self, picked) -> None:
         """One chunk's routing of a model with expert layers of which a
@@ -3442,13 +3821,20 @@ class BatchSession:
             self.eng._m_chunk.observe(chunk_ms)
             self.eng._m_live_rows.observe(live)
 
-    def _account_chunk(self, pool, live: list, arr, okh, fresh: dict) -> None:
+    def _account_chunk(self, rows: list, arr, okh, fresh: dict) -> None:
         """Per-row bookkeeping for one fused chunk's output — shared by the
         slab and paged dispatch paths (identical by design: the accounting
-        IS the bit-identity contract, only residency differs)."""
-        for r in live:
-            h = pool.rows[r]
-            st = self._slots[h]
+        IS the bit-identity contract, only residency differs). ``rows``:
+        (row, handle, its state, tokens offered it before the chunk), as
+        they stood at the dispatch."""
+        for r, h, st, before in rows:
+            if st.done or self._slots.get(h) is not st:
+                # finished, cancelled or released after the dispatch: it
+                # ran one chunk past its end, and its tokens are garbage
+                if st.finish in ("stop", "error") \
+                        and self.eng._m_overrun is not None:
+                    self.eng._m_overrun.inc()
+                continue
             if not okh[r]:
                 st.done = True
                 st.finish = "error"
@@ -3458,8 +3844,7 @@ class BatchSession:
                 continue
             # a context-exhausted row pinned at its last slot: tokens
             # past its room are garbage — generate_batch's accounting
-            keep = max(0, min(self.chunk, st.room - st.offered))
-            st.offered += self.chunk
+            keep = max(0, min(self.chunk, st.room - before))
             toks = [int(t) for t in arr[:keep, r]]
             take = min(len(toks), st.budget - st.emitted)
             for j in range(take):
@@ -3517,6 +3902,7 @@ class BatchSession:
                     jnp.asarray(g.keys), jnp.asarray(g.temps),
                     jnp.asarray(g.topps), self.eng._poison_rows(g.cap),
                     n_steps=self.chunk)
+                observability.launched()
             with observability.phase("decode_wait", "engine", "device"):
                 arr = np.asarray(chunk)  # [chunk, cap]
             with observability.phase("decode_fetch", "engine") as fetch:
@@ -3527,7 +3913,12 @@ class BatchSession:
                 g.pos = np.minimum(g.pos + self.chunk, W - 1).astype(np.int32)
             with observability.phase("account", "engine"):
                 self._observe_chunk(dispatch.t0, fetch.t1, len(live))
-                self._account_chunk(g, live, arr, okh, fresh)
+                rows = []
+                for r in live:
+                    st = self._slots[g.rows[r]]
+                    rows.append((r, g.rows[r], st, st.offered))
+                    st.offered += self.chunk
+                self._account_chunk(rows, arr, okh, fresh)
         return fresh
 
     def cancel(self, slot: int) -> None:
@@ -3551,8 +3942,7 @@ class BatchSession:
                 self._radix.unpublish(
                     [n for n in pf.pub_nodes
                      if n is not None and not n.ready], self._alloc)
-            for leaf in jax.tree.leaves(pf.cache):
-                leaf.delete()
+            _drop(pf.cache)
 
     def release(self, slot: int) -> None:
         """Free the row for the next admission and return its KV
@@ -3568,8 +3958,7 @@ class BatchSession:
                 self._radix.unpublish(
                     [n for n in pf.pub_nodes
                      if n is not None and not n.ready], self._alloc)
-            for leaf in jax.tree.leaves(pf.cache):
-                leaf.delete()
+            _drop(pf.cache)
         pool, row = self._where.pop(slot)
         pool.rows[row] = None
         if self.paged:
@@ -3603,13 +3992,14 @@ class BatchSession:
             if not self.paged:
                 for pool, _ in self._where.values():
                     self._budget.unplace(pool.ctx)
+        # what is in flight is read first (and its results dropped)
+        self._settle()
+        self._flight.clear()
         for pf in self._prefills.values():
-            for leaf in jax.tree.leaves(pf.cache):
-                leaf.delete()
+            _drop(pf.cache)
         for pool in self._pools.values():
-            for leaf in jax.tree.leaves(pool.cache):
-                leaf.delete()
-            pool.cache = None
+            _drop(pool.cache)
+            pool.cache = pool.dev = None
         if self.paged:
             # hand every page back before the arena dies: the allocator may
             # be the serving accountant's (KVBudget.attach_pages), and its

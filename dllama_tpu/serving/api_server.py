@@ -296,6 +296,11 @@ class Batcher:
     #: gap between a resident row's tokens does not grow
     RIDE_AUTO_WIDTH = 2
 
+    #: where a launch runs ahead of its collection, the scheduler stops
+    #: waiting for arrivals this long before the running launch is due to
+    #: end, beyond what its next dispatch takes and the launches' spread
+    AHEAD_SLACK_S = 0.002
+
     def __init__(self, state, window_ms: float = 15.0, max_batch: int = 8,
                  chunk: int = 8, prefill_chunk: int = -1,
                  kv_buckets: bool = True, kv_bucket_min: int = 0,
@@ -867,6 +872,46 @@ class Batcher:
                         self.state._m_ckpt_writes.inc(outcome="ok")
                         s.queue.put(("ckpt", snap))
 
+    def _await_ahead(self, sess, lead, stop_ids, waiting: list,
+                     slot_map: dict, preempted: list):
+        """The tail of a tick that left a launch in flight (its
+        ``ahead_wait`` leaf: engine layer, device side, for the scheduler
+        has nothing to do until that launch ends): wait on the arrival
+        queue, not on the device, admitting each arrival as it comes
+        (``arrivals`` and ``reap_admit`` leaves), until the launch is due
+        to end (``BatchSession.due``) less what the next tick takes to
+        dispatch the launch after it (``lead``: a running mean, from this
+        wait's end) and the launches' spread. So a request that arrives
+        while a launch runs joins the next launch, as it would if the
+        scheduler waited on the device. Returns when it stopped waiting
+        (None where nothing was in flight)."""
+        if not sess.in_flight:
+            return None
+        stop = None
+        while True:
+            with observability.phase("ahead_wait", "engine",
+                                     "device") as wait:
+                if stop is None:
+                    end, spread = sess.due() or (wait.t0, 0.0)
+                    stop = (end - 2.0 * spread - 1.5 * (lead or 0.0)
+                            - self.AHEAD_SLACK_S)
+                left = stop - time.monotonic()
+                try:
+                    s = self._arrivals.get(timeout=left) if left > 0 else None
+                except queue_mod.Empty:
+                    s = None
+            if s is None:
+                return wait.t1
+            with observability.phase("arrivals", "scheduler"):
+                waiting.append(s)
+                while True:
+                    try:
+                        waiting.append(self._arrivals.get_nowait())
+                    except queue_mod.Empty:
+                        break
+            with observability.phase("reap_admit", "scheduler"):
+                self._reap_admit(sess, stop_ids, waiting, slot_map, preempted)
+
     def _serve_continuous(self, batch: list) -> None:
         """THE continuous path: open a slot-pool session, admit ``batch``
         into free slots, and between every fused chunk (a) stream each live
@@ -887,7 +932,18 @@ class Batcher:
         prefill phases. ``prefill_step`` then finds something to do only
         for a prompt that has nothing decoding beside it (a burst at an
         idle pool): one standalone piece a tick, as on the paths that never
-        ride (a layer plan, ``--kv-pages``, ``--tp``)."""
+        ride (a layer plan, ``--kv-pages``, ``--tp``).
+
+        Where the session's launches run ahead (``BatchSession.ahead``: a
+        slot pool on one device) the tick keeps one launch queued behind
+        the running one: it admits, dispatches its piece and the next
+        launch (``step_ahead``), THEN reads, streams and releases the
+        launch before it, and waits for arrivals until the new one is due
+        to end (``_await_ahead``), so the host's work between programs
+        overlaps the device's. A row that stops or is quarantined in the
+        launch read runs one chunk in the launch already queued, whose
+        tokens are dropped. The paged and ``--tp`` sessions wait on each
+        launch as before."""
         st = self.state
         stop_ids = st.stop_token_ids()
         waiting = list(batch)
@@ -913,7 +969,10 @@ class Batcher:
                         self._keep_sess = sess
             with self._lock:
                 self._active_sess = sess
-            while waiting or slot_map or preempted:
+            ahead = sess.ahead
+            lead = woke = None
+            while waiting or slot_map or preempted or (
+                    ahead and sess.in_flight):
                 with observability.tick():
                     with observability.phase("reap_admit", "scheduler"):
                         self._reap_admit(sess, stop_ids, waiting, slot_map,
@@ -947,7 +1006,16 @@ class Batcher:
                                 requests=[s.trace.request_id
                                           for s in slot_map.values()
                                           if s.trace is not None][:8])
-                    fresh = sess.step_chunk()
+                    if ahead:
+                        # dispatch the next launch, then read the one
+                        # before it while the device runs the new one
+                        fresh = sess.step_ahead()
+                        if woke is not None and sess.dispatched > woke:
+                            x = sess.dispatched - woke
+                            lead = x if lead is None else lead + 0.25 * (
+                                x - lead)
+                    else:
+                        fresh = sess.step_chunk()
                     with observability.phase("stream_out", "scheduler"):
                         self._stream_out(sess, slot_map, fresh)
                     with observability.phase("arrivals", "scheduler"):
@@ -956,6 +1024,9 @@ class Batcher:
                                 waiting.append(self._arrivals.get_nowait())
                             except queue_mod.Empty:
                                 break
+                    if ahead:
+                        woke = self._await_ahead(sess, lead, stop_ids,
+                                                 waiting, slot_map, preempted)
         except Exception as e:  # noqa: BLE001 — every waiter gets a 500
             self._fail(list(slot_map.values()) + waiting + preempted, e)
             # a session that threw mid-window is suspect: never keep it

@@ -528,3 +528,57 @@ def test_a_uniform_models_attention_lowers_as_it_always_did():
     texts = [jax.jit(f).lower(q, k, v, p).as_text().replace(
         f"jit_{f.__name__}", "jit_fn") for f in (new, old)]
     assert texts[0] == texts[1] and "case" not in texts[0]
+
+
+# ---------------------------------------------------------------------------
+# launches that run ahead of their collection: pieces not waited for
+# ---------------------------------------------------------------------------
+
+def _preset(name: str) -> tuple:
+    """(config, float32 params, conf) of a tiny preset with a layer plan."""
+    if name == "tiny-mimo":
+        conf = load_conf()
+        params = dense_params(make_planes(conf), conf)
+    elif name == "tiny-command-a":
+        from tests import test_command_a as m
+        conf = m.load_conf()
+        params = m.dense_params(m.make_planes(conf), conf)
+    else:
+        from tests import test_deepseek_v3 as m
+        conf = m.load_conf()
+        params = m.dense_params(families.load(conf).make_planes(conf, 7), conf)
+    return families.load(conf).model_config(conf, conf["server"]), params, conf
+
+
+@pytest.mark.parametrize("preset", ["tiny-mimo", "tiny-command-a",
+                                    "tiny-gigachat"])
+def test_a_plan_served_ahead_streams_what_the_synchronous_loop_streams(
+        preset):
+    """A plan's prompts take standalone pieces, which a session that runs
+    ahead does not wait for: the piece, its insert and the decode launch
+    queue on the device in that order, and a row whose prefix ends in the
+    piece decodes in that launch (first tokens one tick later than in the
+    synchronous loop, never more: ``both``). Greedy and sampled rows, a
+    prompt past the ring, pools that grow and rows that migrate."""
+    from tests.test_continuous_batching import both
+
+    cfg, params, conf = _preset(preset)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(259, conf["vocab_size"], size=n).tolist()
+               for n in (5, 12, 30, 20)]
+    sampled = SamplerConfig(temperature=0.8, topp=0.9, seed=2**31 - 1)
+    greedy = SamplerConfig(temperature=0.0)
+    arrivals = [(0, prompts[0], 10, greedy, ()), (0, prompts[1], 9, sampled, ()),
+                (2, prompts[2], 8, greedy, ()), (3, prompts[3], 14, sampled, ())]
+    want, got, _, why, reg = both(
+        arrivals, cfg=cfg, params=params, max_batch=4, chunk=4,
+        bucket_kv=True, min_bucket=16, prefill_chunk=8)
+    assert got == want and set(why.values()) == {"length"}
+    assert all(len(got[i]) == a[2] for i, a in enumerate(arrivals))
+    snap = reg.snapshot()
+    pieces = snap["dllama_prefill_tokens_total"]["values"]
+    assert sum(v["value"] for v in pieces) == sum(len(p) - 1 for p in prompts)
+    ahead = {v["labels"]["state"]: v["value"]
+             for v in snap["dllama_decode_ahead_total"]["values"]}
+    assert ahead.get("queued", 0) > 0
+    assert snap["dllama_kv_migrations_total"]["values"][0]["value"] > 0

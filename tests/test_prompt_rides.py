@@ -487,3 +487,34 @@ def test_the_counters_say_how_the_prompt_tokens_reached_the_cache():
     pieces = sum(v["count"] for v in
                  reg.snapshot()["dllama_prefill_chunk_ms"]["values"])
     assert pieces == 3 + launches  # a launch that carries riders is a chunk
+
+
+# ---------------------------------------------------------------------------
+# launches that run ahead of their collection carry the same riders
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("model", list(MODELS))
+def test_riders_of_launches_that_run_ahead_emit_what_they_do_in_step(model):
+    """Prompts ride beside a resident row while the next launch is
+    dispatched before the one before it is read: the cursors advance and a
+    prefix that ends in mid-launch goes live as that launch is dispatched,
+    so every row emits what it does in the synchronous loop, one tick
+    later at most (``tests.test_continuous_batching.both``), and the ride
+    counters count the same tokens."""
+    from tests.test_continuous_batching import both
+
+    cfg, quant = MODELS[model]
+    params = llama.random_params(cfg, seed=1, dtype=np.float32)
+    if quant:
+        params = llama.quantize_params(params, "q40")
+    arrivals = [(0, SHORT, 30, S_RES, ()),
+                (1, LONG, 8, S_NEW, ()),       # prefix 22: ends mid-launch
+                (1, LONG[:9], 6, SamplerConfig(temperature=0.0), ()),
+                (3, LONG[3:20], 7, S_NEW, ())]
+    want, got, _, why, reg = both(arrivals, cfg=cfg, params=params,
+                                  max_batch=4, chunk=CHUNK,
+                                  prefill_chunk=CHUNK * T)
+    assert got == want and set(why.values()) == {"length"}
+    assert _value(reg, "dllama_prefill_tokens_total", how="ride") == sum(
+        len(p) - 1 for _, p, *_ in arrivals[1:])
+    assert _value(reg, "dllama_decode_ahead_total", state="queued") > 0

@@ -49,7 +49,16 @@ PHASES = {
     "stream_out": ("scheduler", "host"),
     "arrivals": ("scheduler", "host"),
 }
+#: a session whose launches run ahead (the slab pool): its pieces are not
+#: waited for, and a tick that leaves a launch in flight ends waiting for
+#: arrivals (``ahead_wait``), which is no scheduler or engine host work
+AHEAD_PHASES = dict({k: v for k, v in PHASES.items() if k != "prefill_wait"},
+                    ahead_wait=("engine", "device"))
 CHUNK = 4
+
+
+def phases_of(served) -> dict:
+    return AHEAD_PHASES if served["rides"] else PHASES
 
 
 def phase_seconds(family: str = FAMILY) -> dict:
@@ -69,6 +78,13 @@ def prefill_tokens() -> dict:
     snap = ob.default_registry().snapshot()
     values = snap.get("dllama_prefill_tokens_total", {}).get("values", [])
     return {v["labels"]["how"]: v["value"] for v in values}
+
+
+def ahead_states() -> dict:
+    """{state: launches} of ``dllama_decode_ahead_total``, now."""
+    snap = ob.default_registry().snapshot()
+    values = snap.get("dllama_decode_ahead_total", {}).get("values", [])
+    return {v["labels"]["state"]: v["value"] for v in values}
 
 
 def hist(name: str) -> tuple:
@@ -108,6 +124,7 @@ def test_leaf_seconds_land_under_their_labels_and_carry_the_tick():
     with ob.tick() as tk:
         with ob.phase("t_host", "scheduler") as a:
             time.sleep(0.01)
+            ob.launched()
         with ob.phase("t_dev", "engine", "device", span_id=7) as b:
             time.sleep(0.01)
         with ob.phase("t_host", "scheduler"):
@@ -120,7 +137,9 @@ def test_leaf_seconds_land_under_their_labels_and_carry_the_tick():
     assert a._span_args() == {"tick": tk.seq}
     assert b._span_args() == {"span_id": 7, "tick": tk.seq}
     assert tk.t0 <= a.t0 <= a.t1 <= b.t0 <= b.t1 <= tk.t1
-    # a device phase ran in it: the pass launched
+    # it dispatched device work: the pass launched
+    assert counter("dllama_ticks_total") == ticks + 1
+    ob.launched()  # outside a tick: nothing
     assert counter("dllama_ticks_total") == ticks + 1
 
 
@@ -132,8 +151,12 @@ def test_a_pass_without_a_device_phase_is_not_a_tick_that_launched():
             pass
     with ob.tick() as second:
         pass
+    # a pass that only waits for (or reads) a launch dispatched before it
+    with ob.tick() as third:
+        with ob.phase("t_wait", "engine", "device"):
+            pass
     assert counter("dllama_ticks_total") == ticks
-    assert second.seq > first.seq  # every pass still takes a number
+    assert third.seq > second.seq > first.seq  # every pass takes a number
 
 
 def test_an_exception_ends_the_span_and_propagates():
@@ -288,7 +311,8 @@ def served(pool, tmp_path_factory):
               "chunk": hist("dllama_decode_chunk_ms"),
               "live": hist("dllama_decode_live_rows"),
               "turn": hist("dllama_prefill_turn_wait_ms"),
-              "tokens": prefill_tokens()}
+              "tokens": prefill_tokens(), "ahead": ahead_states(),
+              "overrun": counter("dllama_decode_overrun_rows_total")}
     ob.configure_trace(str(path))
     try:
         results = pool.burst(5)
@@ -303,6 +327,9 @@ def served(pool, tmp_path_factory):
         "live": sub(hist("dllama_decode_live_rows"), before["live"]),
         "turn": sub(hist("dllama_prefill_turn_wait_ms"), before["turn"]),
         "tokens": delta(prefill_tokens(), before["tokens"]),
+        "ahead": delta(ahead_states(), before["ahead"]),
+        "overrun": (counter("dllama_decode_overrun_rows_total")
+                    - before["overrun"]),
         "rides": pool.state.batcher.kv_pages == 0,
     }
 
@@ -314,15 +341,21 @@ def scheduler_events(served, name=None):
 
 
 def test_every_phase_of_the_table_is_observed_and_no_other(served):
-    assert set(served["phases"]) == {(n, l, s) for n, (l, s) in PHASES.items()}
+    table = phases_of(served)
+    assert set(served["phases"]) == {(n, l, s) for n, (l, s) in table.items()}
     names = {e["name"] for e in scheduler_events(served)}
-    assert names == set(PHASES) | {"tick", "scheduler_window"}
+    assert names == set(table) | {"tick", "scheduler_window"}
+    # the wait for arrivals is the engine's device side, never the
+    # scheduler's or the engine's host work
+    waits = {k for k in served["phases"] if k[0] == "ahead_wait"}
+    assert waits == ({("ahead_wait", "engine", "device")} if served["rides"]
+                     else set())
 
 
 def test_the_phases_tile_the_loops_wall_time(served):
     ticks = sorted(scheduler_events(served, "tick"), key=lambda e: e["ts"])
     leaves = [e for e in scheduler_events(served)
-              if e["name"] in PHASES]
+              if e["name"] in phases_of(served)]
     in_ticks = sum(e["dur"] for e in ticks)
     in_leaves = sum(e["dur"] for e in leaves)
     assert in_leaves == pytest.approx(in_ticks, rel=0.02)
@@ -346,9 +379,26 @@ def test_the_phases_tile_the_loops_wall_time(served):
 
 
 def test_ticks_total_counts_the_passes_that_launched(served):
+    """A pass that dispatched a decode launch or a piece; so where launches
+    run ahead, the last pass of a window, which only reads the launch
+    before it, is not a tick: one tick a launch."""
     launched = {e["args"]["tick"] for e in scheduler_events(served)
-                if PHASES.get(e["name"], ("", ""))[1] == "device"}
+                if e["name"] in ("decode_dispatch", "prefill_dispatch")}
     assert served["ticks"] == len(launched) > 0
+    decoding = {e["args"]["tick"]
+                for e in scheduler_events(served, "decode_dispatch")}
+    n_launches, _ = served["chunk"]
+    assert len(decoding) == n_launches
+    if served["rides"]:
+        # every launch counted by what the launch before it was doing
+        assert sum(served["ahead"].values()) == n_launches
+        assert served["ahead"].get("queued", 0) > 0
+        assert served["ahead"].get("idle", 0) >= 1  # a window's first
+    else:
+        assert served["ahead"] == {}
+    # every request ends at its budget, which the host knows before it
+    # dispatches: no launch carries a row past its end
+    assert served["overrun"] == 0
 
 
 def test_live_rows_is_observed_at_every_launch_and_sums_the_rows_that_emitted(
@@ -377,7 +427,7 @@ def test_turn_wait_counts_every_request_and_grows_behind_others_pieces(served):
     # (the slab pool) only the first request finds nothing decoding and
     # takes standalone pieces; the four behind it have no prefill phase
     spans = {e["args"]["span_id"]
-             for e in scheduler_events(served, "prefill_wait")}
+             for e in scheduler_events(served, "prefill_dispatch")}
     staged = (list(served["results"].values())[:1] if served["rides"]
               else served["results"].values())
     assert spans == {tr.span_id for _, tr in staged}
@@ -388,12 +438,13 @@ def test_a_riding_tick_has_no_prefill_phase_and_marks_its_riders(served):
     A launch that carried one is its prefill chunk on the request's trace
     (``mark_prefill_chunk``: the launch's dispatch start to its fetch end,
     so the turn wait ends where the first tokens reach the device); the
-    tick that ends a prompt goes live after ``account``, and no tick of a
-    rider has a prefill phase (the leaves still tile every tick: the test
-    above). The paged pool never rides: each chunk mark is a piece."""
+    tick whose launch ends a prompt goes live right after that launch's
+    dispatch (it decodes in the next), and no tick of a rider has a
+    prefill phase (the leaves still tile every tick: the test above). The
+    paged pool never rides: each chunk mark is a piece."""
     by_tick: dict = {}
     for e in scheduler_events(served):
-        if e["name"] in PHASES:
+        if e["name"] in phases_of(served):
             by_tick.setdefault(e["args"]["tick"], []).append(e)
     launches = {e["ts"] for e in scheduler_events(served, "decode_dispatch")}
     pieces = {e["ts"] for e in scheduler_events(served, "prefill_dispatch")}
@@ -416,8 +467,9 @@ def test_a_riding_tick_has_no_prefill_phase_and_marks_its_riders(served):
     for t in riding:
         names = [e["name"] for e in sorted(by_tick[t], key=lambda e: e["ts"])]
         assert not any(n.startswith("prefill_") for n in names)
-        assert names.index("account") < names.index("go_live") \
-            < names.index("stream_out")
+        at = names.index("go_live")
+        assert names[at - 1] == "decode_dispatch" \
+            and at < names.index("stream_out")
     if served["rides"]:
         assert served["tokens"] == {
             "piece": prompts[0], "ride": sum(prompts.values()) - prompts[0]}
@@ -427,8 +479,16 @@ def test_a_riding_tick_has_no_prefill_phase_and_marks_its_riders(served):
 
 def test_decode_chunk_ms_is_its_dispatch_wait_and_fetch(served):
     n, chunk_ms = served["chunk"]
-    parts = sum(v for (name, _, _), v in served["phases"].items()
-                if name in ("decode_dispatch", "decode_wait", "decode_fetch"))
+    seconds = lambda *names: sum(  # noqa: E731
+        v for (name, _, _), v in served["phases"].items() if name in names)
+    if served["rides"]:
+        # a launch queued behind another begins where that one ended: its
+        # time runs from there (or its dispatch) to its fetch's end, so it
+        # holds its wait and fetch and lies inside the loop's wall time
+        assert 1000.0 * seconds("decode_wait", "decode_fetch") <= chunk_ms \
+            <= 1000.0 * sum(served["phases"].values())
+        return
+    parts = seconds("decode_dispatch", "decode_wait", "decode_fetch")
     # the same clock reads: what differs is the two hand-overs between the
     # three spans of a launch, microseconds each on an idle machine
     assert 0.0 <= chunk_ms - 1000.0 * parts <= 0.2 * n + 0.01 * chunk_ms
@@ -463,11 +523,12 @@ def test_profiler_host_plane_holds_the_leaf_spans_inside_their_tick(
     mine = [evs for evs in lines if any(n == "tick" for n, *_ in evs)]
     assert len(mine) == 1
     ticks = {st["tick"]: (a, b) for n, a, b, st in mine[0] if n == "tick"}
-    leaves = [(n, a, b, st) for n, a, b, st in mine[0] if n in PHASES]
-    assert {n for n, *_ in leaves} == set(PHASES)
+    table = AHEAD_PHASES if pool.state.batcher.kv_pages == 0 else PHASES
+    leaves = [(n, a, b, st) for n, a, b, st in mine[0] if n in table]
+    assert {n for n, *_ in leaves} == set(table)
     for n, a, b, st in leaves:
         t0, t1 = ticks[st["tick"]]
         assert t0 <= a and b <= t1, (n, st)
     assert any(n == "scheduler_window" for n, *_ in mine[0])
-    waits = [st for n, _, _, st in leaves if n == "prefill_wait"]
-    assert all(st["span_id"] > 0 for st in waits)
+    pieces = [st for n, _, _, st in leaves if n == "prefill_dispatch"]
+    assert pieces and all(st["span_id"] > 0 for st in pieces)
